@@ -1,7 +1,7 @@
 """Candidate-variant timing: sorted access, 2D row layout, matmul cumsum.
 
-Slope method (KS wide apart, best-of-5) to beat the ~±60ms relay fetch
-noise. Digest folds both the scan outputs and the final table so no
+Slope method (KS wide apart, best-of-5) to beat fetch noise. Digest
+folds both the scan outputs and the final table so no
 component can be DCE'd.
 """
 

@@ -53,7 +53,6 @@ _JIT_CALLEES = {
     "pmap",
     "jax.shard_map",
     "shard_map",
-    "jax.experimental.shard_map.shard_map",
 }
 
 _PARTIAL_CALLEES = {"functools.partial", "partial"}

@@ -71,7 +71,7 @@ def snapshot_engine(engine) -> tuple:
     """Copy one bank's state: (state dict, entries).  The state dict
     is ``{"counts": ...}`` for fixed-window banks and one named row
     per kernel state array for algorithm banks (sliding-window's
-    window/curr/prev, GCRA's tat_sec/tat_frac — see
+    window/curr/prev, GCRA's tat_anchor/tat_cells — see
     models/registry.py state_rows).  This is the only part that needs
     exclusive access to the engine; serialization and disk I/O happen
     afterwards on the caller's thread."""
@@ -215,6 +215,17 @@ def restore_engine(
                     for name in z.files
                     if name.startswith("state_")
                 }
+            rows = getattr(engine.model, "state_rows", None)
+            if "counts" not in state and rows and set(state) != set(rows):
+                logger.warning(
+                    "checkpoint %s: state rows %s != the %s kernel's %s "
+                    "(an older layout of its state), skipping",
+                    path,
+                    sorted(state),
+                    engine_algo,
+                    sorted(rows),
+                )
+                return False
             blob = bytes(z["key_blob"])
             keys = []
             off = 0

@@ -33,7 +33,7 @@ import numpy as np
 
 from ..observability.launches import OUTCOME_FAULT, OUTCOME_OK
 from ..utils.time import REAL_MONOTONIC
-from .engine import HostDecisions
+from .engine import CallWatch, HostDecisions
 
 
 @dataclass(frozen=True)
@@ -248,8 +248,12 @@ def _slice(d: HostDecisions, lo: int, hi: int) -> HostDecisions:
     )
 
 
-def submit_items(engine, items: List[WorkItem]):
+def submit_items(
+    engine, items: List[WorkItem], watch: Optional[CallWatch] = None
+):
     """Assemble one engine batch from `items` and LAUNCH it (no wait).
+    `watch` is the calling dispatcher thread's CallWatch (None for
+    inline runs and the host fallback engines, which nothing watches).
 
     Must be called from the single thread that owns `engine`'s
     SlotTable.  Returns the engine token for complete_items, or None
@@ -287,7 +291,10 @@ def submit_items(engine, items: List[WorkItem]):
             for it in items:
                 it.event.set()
             return None
-        token = engine.submit_packed(now, blob, meta)
+        if watch is None:
+            token = engine.submit_packed(now, blob, meta)
+        else:
+            token = engine.submit_packed(now, blob, meta, watch)
         if traces:
             # Stamped AFTER submit_packed returns: "launch" means the
             # device step is in flight — host-side assign/dedup/
@@ -308,16 +315,25 @@ def submit_items(engine, items: List[WorkItem]):
 _SUBMIT_FAILED = object()  # device-step launch failure (vs None = empty)
 
 
-def complete_items(engine, items: List[WorkItem], token) -> bool:
+def complete_items(
+    engine,
+    items: List[WorkItem],
+    token,
+    watch: Optional[CallWatch] = None,
+) -> bool:
     """Wait for a submit_items launch, scatter decisions, signal
     waiters.  Thread-agnostic (touches no engine state).  Returns
-    False when the device step failed (launch or readback)."""
+    False when the device step failed (launch or readback).  `watch`
+    as in submit_items."""
     if token is None:
         return True  # empty batch
     if token is _SUBMIT_FAILED:
         return False  # submit already errored the items
     try:
-        decisions = engine.step_complete(token)
+        if watch is None:
+            decisions = engine.step_complete(token)
+        else:
+            decisions = engine.step_complete(token, watch)
     except BaseException as e:
         for it in items:
             it.fail(e)
@@ -439,23 +455,18 @@ class BatchDispatcher:
         self._consecutive_failures = 0
         self._reported_unhealthy = False
         self._dead: Optional[BaseException] = None
-        # Watchdog liveness stamps (backends/fault_domain.py): the
-        # collector marks when a device LAUNCH begins, the completer
-        # when a readback WAIT begins; each clears its own stamp when
-        # the call returns.  Single-writer plain attributes read
-        # lock-free by the watchdog thread — a stamp older than
-        # KERNEL_DEADLINE_S means the device call is stuck (hung
-        # kernel, dead tunnel) and the bank should be quarantined.
+        # Watchdog liveness (backends/fault_domain.py): one CallWatch
+        # per dispatcher thread, handed to the engine with every call.
+        # The engine marks exactly its device interactions on it — the
+        # collector's kernel launches, the completer's readback waits
+        # — and leaves it unarmed while a first-seen kernel shape
+        # compiles, so KERNEL_DEADLINE_S only ever times device calls
+        # of shapes proven to finish (engine.CallWatch; stuck_age).
         # `stamp_clock` is the injectable MonotonicClock seam so
         # hang-detection tests run on synthetic time.
         self._stamp_now = (stamp_clock or REAL_MONOTONIC).now
-        self._launch_busy_since: Optional[float] = None
-        self._complete_busy_since: Optional[float] = None
-        # Successful device-step completions: the watchdog arms the
-        # kernel deadline only after the first one, so first-batch XLA
-        # compilation (seconds to tens of seconds on big meshes) never
-        # reads as a hang.
-        self.completed_launches = 0
+        self._launch_watch = CallWatch(self._stamp_now)
+        self._complete_watch = CallWatch(self._stamp_now)
         # Intake is a plain list + condition variable, drained by the
         # collector in ONE swap per wakeup: queue.Queue pays a lock
         # acquisition per get (~0.8 ms per 1024-item batch on the
@@ -551,13 +562,17 @@ class BatchDispatcher:
         if token.error is not None:
             raise token.error
 
-    def stuck_age(self, now: float) -> float:
-        """Seconds the oldest in-progress device call (launch or
-        readback wait) has been running, 0.0 when idle.  Lock-free
-        reads of the single-writer stamps; `now` must come from the
-        same clock as `stamp_clock`."""
+    def stuck_age(self, now: Optional[float] = None) -> float:
+        """Seconds the oldest in-progress device call of an
+        already-proven kernel shape (launch or readback wait) has been
+        running; 0.0 when there is none.  The one definition of "a
+        device call is stuck" — the watchdog and the RPC waits both
+        compare it to KERNEL_DEADLINE_S.  `now` defaults to (and must
+        come from) `stamp_clock`."""
+        if now is None:
+            now = self._stamp_now()
         age = 0.0
-        for since in (self._launch_busy_since, self._complete_busy_since):
+        for since in (self._launch_watch.since, self._complete_watch.since):
             if since is not None and now - since > age:
                 age = now - since
         return age
@@ -689,11 +704,7 @@ class BatchDispatcher:
                     corr = it.corr
             if oldest:
                 queue_wait = t0 - oldest
-        self._launch_busy_since = self._stamp_now()
-        try:
-            token = submit_items(self.engine, batch)
-        finally:
-            self._launch_busy_since = None
+        token = submit_items(self.engine, batch, self._launch_watch)
         if token is _SUBMIT_FAILED:
             if lr is not None:
                 lr.record(
@@ -872,11 +883,9 @@ class BatchDispatcher:
                 else:
                     lr = self.launches
                     t0 = time.monotonic_ns() if lr is not None else 0
-                    self._complete_busy_since = self._stamp_now()
-                    try:
-                        ok = complete_items(self.engine, payload, token)
-                    finally:
-                        self._complete_busy_since = None
+                    ok = complete_items(
+                        self.engine, payload, token, self._complete_watch
+                    )
                     if lr is not None:
                         try:
                             meta = self._launch_meta.popleft()
@@ -897,8 +906,6 @@ class BatchDispatcher:
                             OUTCOME_OK if ok else OUTCOME_FAULT,
                             meta[5],
                         )
-                    if ok:
-                        self.completed_launches += 1
                     with self._state_lock:
                         self._inflight -= 1
                     self._note_step(ok)

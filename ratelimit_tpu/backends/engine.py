@@ -8,13 +8,17 @@ analog, reference fixed_cache_impl.go:77-87).
 
 from __future__ import annotations
 
+import contextlib
+import logging
 from dataclasses import dataclass
-from typing import List, Optional, Sequence
+from typing import Callable, List, Optional, Sequence
 
 import jax
 import numpy as np
 
 from ..models.fixed_window import DeviceBatch, FixedWindowModel
+
+logger = logging.getLogger("ratelimit.engine")
 
 # Pad batches up to one of these sizes so XLA compiles a handful of
 # shapes instead of one per batch length (SURVEY.md section 2 SP row:
@@ -52,6 +56,44 @@ class HostDecisions:
     set_local_cache: np.ndarray
 
 
+class CallWatch:
+    """The kernel watchdog's view of one thread's device calls: when
+    the call in progress began, or None while there is none — or while
+    it runs a kernel shape that has never completed, whose first call
+    is XLA compilation (seconds on a TPU), not a hang.  The engine
+    brackets exactly its device interactions with begin/end
+    (CounterEngine._device_call), so host work — slot assignment, a
+    table rehash, the decide pass — never runs on the deadline's
+    clock.  One writer (the thread making the calls); read lock-free
+    by the watchdog and by waiting RPCs (BatchDispatcher.stuck_age)."""
+
+    __slots__ = ("since", "_now")
+
+    def __init__(self, now: Callable[[], float]):
+        self.since: Optional[float] = None
+        self._now = now
+
+    def begin(self, armed: bool) -> None:
+        self.since = self._now() if armed else None  # tpu-lint: disable=shared-state -- one CallWatch per dispatcher thread: single writer, lock-free readers
+
+    def end(self) -> None:
+        self.since = None  # tpu-lint: disable=shared-state -- same single-writer stamp
+
+
+def device_report() -> dict:
+    """Where this process's kernels run, as JAX reports it — the
+    where-it-ran half of the runner's start line and /debug/faults.
+    JAX falls back to CPU with only a warning when an accelerator
+    fails to initialise, so the SETTING (BACKEND_TYPE=tpu) proves
+    nothing; this does."""
+    devices = jax.devices()
+    return {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
+    }
+
+
 def _pick_table_cls(native: Optional[bool]):
     """Slot-table implementation choice: C++ (one FFI call per batch)
     with automatic fallback to the Python oracle."""
@@ -65,6 +107,10 @@ def _pick_table_cls(native: Optional[bool]):
         return native_slot_table.NativeSlotTable
     if native is True:
         raise RuntimeError("native slot table requested but unavailable")
+    logger.warning(
+        "native slot table unavailable: this bank runs on the Python "
+        "table (same decisions, several times the host cost per batch)"
+    )
     return SlotTable
 
 
@@ -424,6 +470,30 @@ class CounterEngine:
         # GROUP so one rolled-over key counts once per batch, however
         # many lanes repeat it.  Monotonic; exported as a counter.
         self.stat_window_rollovers = 0
+        # Kernel shapes — (bucket, readback dtype) and the like — one
+        # of whose launches has been read back: compiled, loaded and
+        # known to finish.  Only calls of these arm the kernel
+        # deadline (_device_call); step_complete grows the set.
+        self._proven_shapes: set = set()
+
+    def placement(self) -> dict:
+        """Where this bank lives (runner start line, /debug/faults):
+        slot-table implementation, the devices holding its state, and
+        how many kernel shapes it has compiled and completed.  Safe
+        from any thread: one attribute read each, and an array's
+        sharding outlives its donation."""
+        return {
+            "slot_table": (
+                "native"
+                if hasattr(self.slot_table, "assign_dedup_packed")
+                else "python"
+            ),
+            "state_devices": sorted(
+                f"{d.platform}:{d.id}"
+                for d in self._counts.sharding.device_set
+            ),
+            "shapes_compiled": len(self._proven_shapes),
+        }
 
     # -- host-side key handling -----------------------------------------
 
@@ -489,8 +559,12 @@ class CounterEngine:
                 if batch.dividers is None
                 else batch.dividers[start:end],
             )
-            afters_dev, reassemble = self._device_submit(dedup, now)
-            chunks.append((afters_dev, start, count, dedup, reassemble))
+            afters_dev, reassemble, shape = self._device_submit(
+                dedup, now, None
+            )
+            chunks.append(
+                (afters_dev, start, count, dedup, reassemble, shape)
+            )
             # Engine stats are plain ints on purpose: the engine has a
             # single toucher (the dispatcher collector owns it; inline
             # mode serializes via tpu_cache._inline_locks) and the
@@ -501,9 +575,16 @@ class CounterEngine:
         self.stat_dedup_groups = sum(len(c[3].uniq_slots) for c in chunks)  # tpu-lint: disable=shared-state -- collector-owned engine
         return (batch.hits, batch.limits, batch.shadow, chunks, now)
 
-    def submit_packed(self, now: int, key_blob, meta: np.ndarray):
+    def submit_packed(
+        self,
+        now: int,
+        key_blob,
+        meta: np.ndarray,
+        watch: Optional[CallWatch] = None,
+    ):
         """Serving fast path: assign slots AND dedup in one native call
-        per chunk, then launch the device step (no wait).
+        per chunk, then launch the device step (no wait).  `watch`, the
+        dispatcher's, sees each launch begin and end (_device_call).
 
         Keys arrive pre-encoded as a length-prefixed utf-8 blob and
         per-lane scalars as one LANE_DTYPE record array (both built on
@@ -594,8 +675,12 @@ class CounterEngine:
                 table.end_batch()
         # Phase 2 — launch the device step per chunk.
         for start, count, dedup in dedups:
-            afters_dev, reassemble = self._device_submit(dedup, now)
-            chunks.append((afters_dev, start, count, dedup, reassemble))
+            afters_dev, reassemble, shape = self._device_submit(
+                dedup, now, watch
+            )
+            chunks.append(
+                (afters_dev, start, count, dedup, reassemble, shape)
+            )
             self.stat_window_rollovers += int(np.count_nonzero(dedup.fresh))
         self.stat_live_keys = len(table)
         self.stat_evictions = table.evictions
@@ -604,17 +689,22 @@ class CounterEngine:
         )
         return (hits, limits, shadow, chunks, now)
 
-    def step_complete(self, token) -> HostDecisions:
+    def step_complete(
+        self, token, watch: Optional[CallWatch] = None
+    ) -> HostDecisions:
         """Block on the readback for a step_submit token and run the
-        host threshold state machine.  Thread-agnostic (touches no
-        engine state)."""
+        host threshold state machine.  Thread-agnostic (the only
+        engine state it touches is the proven-shape set, which it
+        grows).  `watch` sees each readback wait begin and end."""
         hits, limits, shadow, chunks, now = token
         if not chunks:
             empty = np.zeros(0, dtype=np.int32)
             return HostDecisions(*([empty] * 8), empty.astype(bool))
         outs: List[HostDecisions] = []
-        for afters_dev, start, count, dedup, reassemble in chunks:
-            fetched = jax.device_get(afters_dev)
+        for afters_dev, start, count, dedup, reassemble, shape in chunks:
+            with self._device_call(watch, shape):
+                fetched = jax.device_get(afters_dev)
+            self._proven_shapes.add(shape)  # tpu-lint: disable=shared-state -- set.add/`in` are GIL-atomic; a racing reader only sees a shape as cold once more
             if reassemble is not None:
                 fetched = reassemble(np.asarray(fetched))
             end = start + count
@@ -649,6 +739,21 @@ class CounterEngine:
             )
         )
 
+    @contextlib.contextmanager
+    def _device_call(self, watch: Optional[CallWatch], shape: tuple):
+        """Bracket one device interaction — the launch of kernel
+        `shape`, or the readback of one — for the kernel watchdog:
+        KERNEL_DEADLINE_S's clock runs only inside, and only when the
+        shape has completed before (see CallWatch)."""
+        if watch is None:
+            yield
+            return
+        watch.begin(shape in self._proven_shapes)
+        try:
+            yield
+        finally:
+            watch.end()
+
     def _decide_generic(
         self,
         fetched: np.ndarray,
@@ -662,12 +767,16 @@ class CounterEngine:
             self.model, fetched, hits_u32, limits_u32, shadow, dedup, now
         )
 
-    def _device_submit(self, dedup: _Dedup, now: int = 0):
+    def _device_submit(
+        self, dedup: _Dedup, now: int, watch: Optional[CallWatch]
+    ):
         """Launch the device step for one deduped chunk; returns
-        (device afters handle, reassemble-fn or None).  `reassemble`,
-        when set, maps the fetched device array to one (possibly
-        saturated) `after` per unique slot — the sharded engine uses it
-        to unroute per-bank results."""
+        (device afters handle, reassemble-fn or None, shape).
+        `reassemble`, when set, maps the fetched device array to one
+        (possibly saturated) `after` per unique slot — the sharded
+        engine uses it to unroute per-bank results.  `shape` names the
+        compiled program the chunk ran: its bucket plus whatever else
+        selects one (_device_call's key)."""
         g = len(dedup.uniq_slots)
         padded = self._bucket(g)
         ns = self.model.num_slots
@@ -694,12 +803,14 @@ class CounterEngine:
                 pk[2, g:] = 1
                 pk[3, g:] = 0
                 pk[4, g:] = 1
-            self._counts, out_dev = self.model.step_serve_packed(
-                self._counts,
-                jax.numpy.asarray(pk),
-                jax.numpy.asarray(now, dtype=jax.numpy.int32),
-            )
-            return out_dev, None
+            shape = (padded,)
+            with self._device_call(watch, shape):
+                self._counts, out_dev = self.model.step_serve_packed(
+                    self._counts,
+                    jax.numpy.asarray(pk),
+                    jax.numpy.asarray(now, dtype=jax.numpy.int32),
+                )
+            return out_dev, None, shape
         # Dtype choice uses the UNWRAPPED uint64 totals; totals past
         # u32 max are CLAMPED for the device (not wrapped), matching
         # the saturating counter arithmetic — the device stores u32
@@ -737,10 +848,14 @@ class CounterEngine:
                 pk[1, g:] = 0
                 pk[2, g:] = 1
                 pk[3, g:] = 0
-            self._counts, afters_dev = self.model.step_counters_unique_packed(
-                self._counts, dt, jax.numpy.asarray(pk)
-            )
-            return afters_dev, None
+            shape = (padded, dt)
+            with self._device_call(watch, shape):
+                self._counts, afters_dev = (
+                    self.model.step_counters_unique_packed(
+                        self._counts, dt, jax.numpy.asarray(pk)
+                    )
+                )
+            return afters_dev, None, shape
 
         # Unpacked unique path (models with step_counters_unique but
         # no packed entry): five separate leaves.  There is NO modular
@@ -765,15 +880,19 @@ class CounterEngine:
             fresh=jax.numpy.asarray(fr),
             shadow=jax.numpy.asarray(sh),
         )
-        if dt:
-            self._counts, afters_dev = self.model.step_counters_unique_compact(
-                self._counts, dt, device_batch
-            )
-        else:
-            self._counts, afters_dev = self.model.step_counters_unique(
-                self._counts, device_batch
-            )
-        return afters_dev, None
+        shape = (padded, dt)
+        with self._device_call(watch, shape):
+            if dt:
+                self._counts, afters_dev = (
+                    self.model.step_counters_unique_compact(
+                        self._counts, dt, device_batch
+                    )
+                )
+            else:
+                self._counts, afters_dev = self.model.step_counters_unique(
+                    self._counts, device_batch
+                )
+        return afters_dev, None, shape
 
     def reset(self) -> None:
         """Drop all counters and key assignments (tests)."""

@@ -80,9 +80,8 @@ def warmup_engine(engine) -> None:
 
     Module-level so the fault-domain supervisor can warm a freshly
     rebuilt engine OFF the serving path before probing/re-admitting it
-    (a cold engine's first post-swap batch would otherwise pay XLA
-    compilation against the armed kernel deadline and read as a second
-    hang)."""
+    (a cold engine's first post-swap batches would otherwise stall
+    their RPCs for each shape's XLA compilation)."""
     from .engine import HostBatch
 
     for bucket in engine.buckets:
@@ -1010,16 +1009,17 @@ class TpuRateLimitCache:
         deadline: Optional[float] = None,
     ) -> List[DescriptorStatus]:
         """The device half: submit every bank's WorkItem, wait —
-        bounded by KERNEL_DEADLINE_S and the caller's remaining RPC
+        bounded by the dispatch timeout and the caller's remaining RPC
         deadline (`deadline`, absolute time.monotonic seconds) — then
         fill the non-engine categories.
 
         Quarantined banks never reach the device: their items answer
         from the DEVICE_FAILURE_MODE fallback (fault_domain
-        .run_fallback).  A wait that trips the kernel deadline records
-        a hang fault (quarantining the bank) and answers the same way;
-        a wait cut short by the CALLER's deadline answers per the
-        failure mode WITHOUT faulting the bank.  With no fault domain
+        .run_fallback).  A wait that finds its bank's device call
+        stuck past KERNEL_DEADLINE_S records a hang fault
+        (quarantining the bank) and answers the same way; a wait cut
+        short by the CALLER's deadline answers per the failure mode
+        WITHOUT faulting the bank.  With no fault domain
         (kernel_deadline_s=0) device errors raise CacheError exactly
         as before."""
         n_lanes = len(self.lanes)
@@ -1040,7 +1040,7 @@ class TpuRateLimitCache:
             if self.launches is not None and self.flight is not None
             else 0
         )
-        pending: List[tuple] = []  # (bank, engine, item) awaiting wait
+        pending: List[tuple] = []  # (bank, dispatcher|None, item) to wait on
         done: List[WorkItem] = []  # answered items (events recyclable)
         # Hot-loop hoist (tpu-lint hot-path-cost): the bound method
         # once, not one attribute probe per answered item.
@@ -1063,8 +1063,7 @@ class TpuRateLimitCache:
                     "submit": time.perf_counter(),
                 }
             if fd is not None:
-                if fd.is_quarantined(bank):
-                    fd.run_fallback(bank, item)
+                if fd.is_quarantined(bank) and fd.run_fallback(bank, item):
                     self._note_fallback()
                     done_append(item)
                     continue
@@ -1086,27 +1085,15 @@ class TpuRateLimitCache:
                 from .fault_domain import classify_fault
 
                 fd.record_fault(bank, classify_fault(e), e)
-                clone = self._clone_item(item)
-                fd.run_fallback(bank, clone)
-                self._note_fallback()
-                done_append(clone)
+                done_append(self._fall_back(fd, bank, item, deadline))
                 continue
-            pending.append((bank, engine, item))
+            pending.append((bank, d, item))
         for bank, engine, item in inline:
             with self._inline_locks[id(engine)]:
                 run_items(engine, [item])
-            pending.append((bank, engine, item))
-        kd = fd.kernel_deadline_s if fd is not None else None
-        for bank, engine, item in pending:
+            pending.append((bank, None, item))
+        for bank, d, item in pending:
             timeout = self.dispatch_timeout_s
-            if kd is not None:
-                d = self._dispatchers.get(id(engine))
-                if d is not None and d.completed_launches > 0:
-                    # Compile grace: until a bank completes its first
-                    # launch, XLA compilation owns the clock and the
-                    # generous dispatch timeout applies; afterwards
-                    # every launch is bounded by the kernel deadline.
-                    timeout = min(timeout, kd)
             caller_bound = False
             if deadline is not None:
                 remaining = deadline - time.monotonic()
@@ -1114,9 +1101,20 @@ class TpuRateLimitCache:
                     timeout = max(0.0, remaining)
                     caller_bound = True
             try:
-                item.wait(timeout)
+                if fd is not None and d is not None:
+                    # Watched wait: wake every KERNEL_DEADLINE_S and
+                    # ask the dispatcher whether its device call is
+                    # stuck, so the first request to hit a hang also
+                    # reports it.  A slow but MOVING bank (compiling a
+                    # new shape, deep queue, snapshot on the collector)
+                    # is not a hang: it keeps the dispatch timeout.
+                    self._wait_watched(item, d, timeout, fd)
+                else:
+                    item.wait(timeout)
             except TimeoutError as e:
-                if caller_bound:
+                from .fault_domain import FAULT_HANG, KernelDeadlineExceeded
+
+                if caller_bound and not isinstance(e, KernelDeadlineExceeded):
                     # The CALLER's deadline expired first: answer per
                     # DEVICE_FAILURE_MODE without faulting the bank —
                     # it may be healthy, just slower than this RPC can
@@ -1125,13 +1123,8 @@ class TpuRateLimitCache:
                     done_append(self._answer_failure_mode(item))
                     continue
                 if fd is not None:
-                    from .fault_domain import FAULT_HANG
-
                     fd.record_fault(bank, FAULT_HANG, e)
-                    clone = self._clone_item(item)
-                    fd.run_fallback(bank, clone)
-                    self._note_fallback()
-                    done_append(clone)
+                    done_append(self._fall_back(fd, bank, item, deadline))
                     continue
                 raise _engine_failure(e) from e
             except Exception as e:
@@ -1139,10 +1132,7 @@ class TpuRateLimitCache:
                     from .fault_domain import classify_fault
 
                     fd.record_fault(bank, classify_fault(e), e)
-                    clone = self._clone_item(item)
-                    fd.run_fallback(bank, clone)
-                    self._note_fallback()
-                    done_append(clone)
+                    done_append(self._fall_back(fd, bank, item, deadline))
                     continue
                 raise _engine_failure(e) from e
             done_append(item)
@@ -1193,6 +1183,70 @@ class TpuRateLimitCache:
                     duration_until_reset=duration,
                 )
         return statuses  # type: ignore[return-value]
+
+    def _fall_back(
+        self, fd, bank: int, item: WorkItem, deadline: Optional[float]
+    ) -> WorkItem:
+        """Answer a just-faulted bank's item from the failure-mode
+        fallback, on a clone (the original's event may still be
+        signalled by a stuck completer); returns the answered clone.
+
+        Should the bank have been re-admitted while this request
+        queued for the mirror, the restarted device bank gets ONE try
+        under the same bounds as the main wait — the caller's
+        `deadline`, the dispatch timeout, the watchdog — and a fault
+        there is recorded like any other.  Whatever then remains
+        unanswered is answered per DEVICE_FAILURE_MODE: nothing
+        escapes from here as an error."""
+        clone = self._clone_item(item)
+        if fd.run_fallback(bank, clone):
+            self._note_fallback()
+            return clone
+        d = self._dispatchers.get(id(fd.engine_at(bank)))
+        timeout = self.dispatch_timeout_s
+        caller_bound = False
+        if deadline is not None:
+            remaining = deadline - time.monotonic()
+            if remaining < timeout:
+                timeout = max(0.0, remaining)
+                caller_bound = True
+        try:
+            if d is None:  # a restart always installs a dispatcher
+                raise RuntimeError(f"bank {bank} re-admitted, no dispatcher")
+            d.submit(clone)
+            self._wait_watched(clone, d, timeout, fd)
+            return clone
+        except Exception as e:
+            from .fault_domain import KernelDeadlineExceeded, classify_fault
+
+            caller_expired = (
+                caller_bound
+                and isinstance(e, TimeoutError)
+                and not isinstance(e, KernelDeadlineExceeded)
+            )
+            if not caller_expired:  # the bank's fault, not the caller's hurry
+                fd.record_fault(bank, classify_fault(e), e)
+                again = self._clone_item(item)
+                if fd.run_fallback(bank, again):
+                    self._note_fallback()
+                    return again
+        return self._answer_failure_mode(item)
+
+    @staticmethod
+    def _wait_watched(item: WorkItem, d, timeout: float, fd) -> None:
+        """``item.wait(timeout)`` that also watches dispatcher `d`:
+        raises KernelDeadlineExceeded as soon as d's in-progress
+        device call has been stuck past the kernel deadline
+        (BatchDispatcher.stuck_age — the watchdog's own test)."""
+        kd = fd.kernel_deadline_s
+        end = time.monotonic() + timeout
+        while not item.event.wait(min(kd, max(0.0, end - time.monotonic()))):
+            if time.monotonic() >= end:
+                break
+            stuck = d.stuck_age()
+            if stuck > kd:
+                raise fd.hang_error(stuck)
+        item.wait(0.0)  # answered: apply / raise its error; else TimeoutError
 
     def bind_health(self, health) -> None:
         """Wire backend liveness into the health checker: dispatcher

@@ -234,9 +234,13 @@ def import_into_cache(cache, sections: List[dict], now: Optional[int] = None) ->
             totals["dropped"] += len(keys)
             continue
         for eng, idxs in targets:
-            if getattr(eng, "algorithm", "fixed_window") != algo:
-                # Kernel state is not interchangeable (the checkpoint
-                # restore guard, applied to handoff).
+            rows = getattr(getattr(eng, "model", None), "state_rows", None)
+            if getattr(eng, "algorithm", "fixed_window") != algo or (
+                rows and "counts" not in state and set(state) != set(rows)
+            ):
+                # Kernel state is not interchangeable — another kernel,
+                # or another version's layout of this one (the
+                # checkpoint restore guards, applied to handoff).
                 totals["dropped"] += len(idxs)
                 continue
             sub_state = {

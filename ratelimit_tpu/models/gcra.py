@@ -13,19 +13,34 @@ This is the continuous-refill policy: capacity returns one cell per
 ``T`` seconds instead of all at once at a window edge, so there is no
 boundary burst at all.
 
-Per-slot state is one 64-bit TAT stored as two uint32 rows —
+Everything is exact integer arithmetic — no float, no rounding, on
+the device and in the numpy oracle alike.  ``now`` is whole unix
+seconds and every TAT is some earlier ``now`` plus a whole number of
+emission intervals, so per-slot state is two uint32 rows
 
-    row 0: tat_sec    unix seconds
-    row 1: tat_frac   fractional second in 2^-32 units
+    row 0: tat_anchor   unix seconds the cell count is measured from
+    row 1: tat_cells    TAT = tat_anchor + tat_cells * divider / limit
 
-— which keeps the kernel x32-clean (no jax_enable_x64, no f64 on
-TPU).  Device math runs in float32 on the RELATIVE value
-``TAT - now``, which the state ages into [0, ~divider] whenever the
-key is live, so f32 precision applies to a window-bounded quantity,
-not an absolute unix timestamp.  For limits where ``divider/limit``
-is f32-exact (every practical per-unit config) the arithmetic is
-exact; at extreme rates (limit ~1e9/unit) budget rounding is ~1 part
-in 2^24, biased toward stricter limiting.
+and with ``e = now - tat_anchor`` elapsed seconds the cells still
+held against the limit are
+
+    used = ceil((TAT - now)+ / T) = (tat_cells - floor(e * limit / divider))+
+
+— one exact ``floor(a * b / d)`` (ops.floor_muldiv, 32-bit
+only, no x64 on the device), never a division by ``limit``.  The f32
+form this replaced differed from its own numpy oracle by one cell on
+11% of lanes for limits that do not divide the window, and a TPU's f32
+divide is not correctly rounded on top of that (measured on a v5e,
+PERF.md PR 21): the host mirror the fault domain falls back to must
+grant exactly what the device grants.
+
+An update re-anchors by whole windows (``divider`` seconds = ``limit``
+cells), so ``e < divider`` and ``tat_cells <= 2 * limit`` hold at
+every write; a slot whose anchor is two windows old is therefore empty
+whatever it holds — which also bounds how long cells counted under a
+since-lowered limit can outlive a config reload.  Consumed CELLS carry
+over a limit change, like a fixed-window count.  Limits are clamped to
+2**31 - 1 (the budget readback is int32).
 
 Batch semantics over duplicate lanes (the engine dedups same-key
 lanes to one slot): admission is cell-granular against the group's
@@ -55,13 +70,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops.muldiv import floor_muldiv
 from .registry import ALGO_GCRA
 
-_FRAC_UNIT = float(2.0**-32)
-_FRAC_SCALE = float(2.0**32)
-#: Largest float32 strictly below 2^32 — the frac-store clamp.
-_FRAC_MAX = float(np.nextafter(np.float32(_FRAC_SCALE), np.float32(0)))
-_B_MAX = float(2**31 - 128)  # i32-safe budget clamp (f32-representable)
+_L_MAX = 2**31 - 1  # limit clamp: budgets read back as int32
+_U32_MAX = 0xFFFFFFFF
 
 
 class GcraModel:
@@ -72,14 +85,14 @@ class GcraModel:
     #: module docstring); the owning engine uses refresh-on-touch
     #: expiry.
     windowed_keys = False
-    state_rows = ("tat_sec", "tat_frac")
+    state_rows = ("tat_anchor", "tat_cells")
 
     def __init__(self, num_slots: int, near_ratio: float = 0.8):
         self.num_slots = int(num_slots)
         self.near_ratio = float(near_ratio)
 
     def init_state(self) -> jax.Array:
-        """Fresh state: every TAT at 0 (i.e. the distant past: any
+        """Fresh state: every anchor at 0 (i.e. the distant past: any
         key's first sighting has full burst capacity)."""
         return jnp.zeros((2, self.num_slots), dtype=jnp.uint32)
 
@@ -94,53 +107,54 @@ class GcraModel:
         """
         slots = packed[0]
         hits = jax.lax.bitcast_convert_type(packed[1], jnp.uint32)
-        limits = jax.lax.bitcast_convert_type(packed[2], jnp.uint32)
+        limits = jnp.minimum(
+            jax.lax.bitcast_convert_type(packed[2], jnp.uint32),
+            jnp.uint32(_L_MAX),
+        )
         fresh = packed[3] != 0
         divider = jax.lax.bitcast_convert_type(packed[4], jnp.uint32)
         now_u = now.astype(jnp.uint32)
 
-        sec = state[0].at[slots].get(mode="fill", fill_value=0)
-        frac = state[1].at[slots].get(mode="fill", fill_value=0)
-        sec = jnp.where(fresh, jnp.uint32(0), sec)
-        frac = jnp.where(fresh, jnp.uint32(0), frac)
+        anchor = state[0].at[slots].get(mode="fill", fill_value=0)
+        cells = state[1].at[slots].get(mode="fill", fill_value=0)
+        cells = jnp.where(fresh, jnp.uint32(0), cells)
 
-        # Signed relative seconds via two's-complement wraparound:
-        # |TAT - now| < 2^31 always (TAT <= now + divider + burst, and
-        # TAT=0 for fresh/idle keys gives -now, well inside i32).
-        rel = jax.lax.bitcast_convert_type(sec - now_u, jnp.int32)
-        d = rel.astype(jnp.float32) + frac.astype(jnp.float32) * jnp.float32(
-            _FRAC_UNIT
-        )
-        v = jnp.maximum(d, jnp.float32(0.0))  # (TAT - now)+, in seconds
-
-        limf = limits.astype(jnp.float32)
-        divf = divider.astype(jnp.float32)
-        t_emit = divf / limf  # inf when limit == 0 (rejects below)
-        tau = divf - t_emit
-        b_f = jnp.floor((tau - v) / t_emit) + jnp.float32(1.0)
-        b_f = jnp.where(limits > jnp.uint32(0), b_f, jnp.float32(0.0))
-        b_f = jnp.clip(b_f, jnp.float32(0.0), jnp.float32(_B_MAX))
-
-        adm = jnp.minimum(hits.astype(jnp.float32), b_f)  # cells admitted
-        upd = adm > jnp.float32(0.0)
-        # Mask T out of the no-update lanes so limit==0 (T=inf) can't
-        # turn 0-cell advances into NaNs.
-        new_d = v + adm * jnp.where(upd, t_emit, jnp.float32(0.0))
-        floor_d = jnp.floor(new_d)
-        new_sec = now_u + floor_d.astype(jnp.uint32)
-        new_frac = jnp.minimum(
-            (new_d - floor_d) * jnp.float32(_FRAC_SCALE),
-            jnp.float32(_FRAC_MAX),
+        # Elapsed seconds through the signed view, so a clock that
+        # stepped back reads as 0 elapsed (the strict side), not 2^32.
+        e = jnp.maximum(
+            jax.lax.bitcast_convert_type(now_u - anchor, jnp.int32),
+            jnp.int32(0),
         ).astype(jnp.uint32)
+        k = e // divider  # whole windows elapsed: k * limit cells back
+        whole = jnp.where(k == jnp.uint32(1), limits, jnp.uint32(0))
+        refilled = whole + floor_muldiv(limits, e - k * divider, divider)
+        # TAT <= now.  cells <= 2 * limit, so two whole windows empty
+        # the bucket and k * limit never has to be formed.
+        idle = (k >= jnp.uint32(2)) | (cells <= refilled)
+        used = jnp.where(idle, jnp.uint32(0), cells - refilled)
+        budget = jnp.where(limits > used, limits - used, jnp.uint32(0))
 
-        sec_out = jnp.where(upd, new_sec, sec)
-        frac_out = jnp.where(upd, new_frac, frac)
+        adm = jnp.minimum(hits, budget)  # cells admitted
+        upd = adm > jnp.uint32(0)
+        # max(TAT, now) + adm * T, re-anchored by whole windows only.
+        base = jnp.where(idle, jnp.uint32(0), cells - whole)
+        new_cells = base + adm  # saturating, like every counter here
+        new_cells = jnp.where(
+            new_cells < base, jnp.uint32(_U32_MAX), new_cells
+        )
+        new_anchor = jnp.where(idle, now_u, anchor + k * divider)
+
         state = state.at[:, slots].set(
-            jnp.stack([sec_out, frac_out]),
+            jnp.stack(
+                [
+                    jnp.where(upd, new_anchor, anchor),
+                    jnp.where(upd, new_cells, cells),
+                ]
+            ),
             mode="drop",
             unique_indices=True,
         )
-        return state, b_f.astype(jnp.int32)
+        return state, budget.astype(jnp.int32)
 
     # -- host halves (backends/engine.py generic protocol) --------------
 
@@ -180,36 +194,29 @@ class GcraModel:
         now: int,
     ) -> np.ndarray:
         """Numpy oracle of step_serve_packed over unique in-table
-        slots (tests/bench verification); mutates ``state`` in place
-        and returns the per-slot budgets.  Same f32 ops in the same
-        order as the kernel."""
+        slots (tests/bench verification, the fault domain's host
+        mirror); mutates ``state`` in place and returns the per-slot
+        budgets.  The refill is the plain 64-bit multiply-then-divide
+        the kernel's 32-bit arithmetic must equal."""
         now_u = np.uint32(now)
-        sec = state[0, slots].copy()
-        frac = state[1, slots].copy()
-        fresh = fresh.astype(bool)
-        sec[fresh] = 0
-        frac[fresh] = 0
-        rel = (sec - now_u).view(np.int32)
-        d = rel.astype(np.float32) + frac.astype(np.float32) * np.float32(
-            _FRAC_UNIT
-        )
-        v = np.maximum(d, np.float32(0.0))
-        limits = limits.astype(np.uint32)
-        with np.errstate(divide="ignore", invalid="ignore"):
-            t_emit = divider.astype(np.float32) / limits.astype(np.float32)
-            tau = divider.astype(np.float32) - t_emit
-            b_f = np.floor((tau - v) / t_emit) + np.float32(1.0)
-        b_f = np.where(limits > 0, b_f, np.float32(0.0))
-        b_f = np.clip(b_f, np.float32(0.0), np.float32(_B_MAX))
-        adm = np.minimum(hits.astype(np.float32), b_f)
+        anchor = state[0, slots].copy()
+        cells = state[1, slots].astype(np.uint64)
+        cells[fresh.astype(bool)] = 0
+        limits = np.minimum(limits.astype(np.uint64), np.uint64(_L_MAX))
+        divider = divider.astype(np.uint64)
+        e = np.maximum((now_u - anchor).view(np.int32), 0).astype(np.uint64)
+        k = e // divider
+        idle = (k >= 2) | (cells <= e * limits // divider)
+        used = np.where(idle, 0, cells - e * limits // divider)
+        budget = np.where(limits > used, limits - used, 0)
+        adm = np.minimum(hits.astype(np.uint64), budget)
         upd = adm > 0
-        new_d = v + adm * np.where(upd, t_emit, np.float32(0.0))
-        floor_d = np.floor(new_d)
-        new_sec = (now_u + floor_d.astype(np.uint32)).astype(np.uint32)
-        new_frac = np.minimum(
-            (new_d - floor_d) * np.float32(_FRAC_SCALE),
-            np.float32(_FRAC_MAX),
-        ).astype(np.uint32)
-        state[0, slots] = np.where(upd, new_sec, sec)
-        state[1, slots] = np.where(upd, new_frac, frac)
-        return b_f.astype(np.int32)
+        new_cells = np.minimum(
+            np.where(idle, 0, cells - k * limits) + adm, np.uint64(_U32_MAX)
+        )
+        new_anchor = np.where(
+            idle, now_u, anchor + (k * divider).astype(np.uint32)
+        )
+        state[0, slots] = np.where(upd, new_anchor, anchor)
+        state[1, slots] = np.where(upd, new_cells, cells).astype(np.uint32)
+        return budget.astype(np.int32)

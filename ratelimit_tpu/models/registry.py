@@ -97,7 +97,7 @@ ALGORITHMS = {
         name=ALGO_GCRA,
         algo_id=2,
         windowed_keys=False,
-        state_rows=("tat_sec", "tat_frac"),
+        state_rows=("tat_anchor", "tat_cells"),
         make_model=_make_gcra,
     ),
 }

@@ -7,7 +7,11 @@ the current window:
 
     effective(now) = floor(prev * (divider - (now - w)) / divider) + curr
 
-where ``w = now - now % divider`` is the current window start.  The
+where ``w = now - now % divider`` is the current window start — in
+exact integer arithmetic (``ops.floor_muldiv``): a TPU's f32 divide is
+not correctly rounded, and one ulp across the floor() is one admission
+more or fewer than the host oracle and the fault domain's host mirror
+would grant (measured on a v5e, PR 21).  The
 estimate assumes the previous window's traffic was uniform; its error
 is bounded by one window's worth of skew, and — unlike fixed windows —
 it can never admit 2x the configured rate across a boundary (the decay
@@ -47,6 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops.muldiv import floor_muldiv
 from .registry import ALGO_SLIDING_WINDOW
 
 
@@ -98,12 +103,7 @@ class SlidingWindowModel:
         base = jnp.where(same, curr, jnp.uint32(0))
 
         elapsed = now_u - w  # in [0, divider)
-        frac = (divider - elapsed).astype(jnp.float32) / divider.astype(
-            jnp.float32
-        )
-        wprev = jnp.floor(new_prev.astype(jnp.float32) * frac).astype(
-            jnp.uint32
-        )
+        wprev = floor_muldiv(new_prev, divider - elapsed, divider)
 
         # SATURATING add, mirroring the fixed-window counter domain
         # (models/fixed_window.py update_unique): one u32 add wraps at
@@ -163,9 +163,11 @@ class SlidingWindowModel:
         now: int,
     ) -> Tuple[np.ndarray, np.ndarray]:
         """Numpy oracle of step_serve_packed over unique in-table
-        slots (tests/bench verification); mutates ``state`` in place
-        and returns (wprev, after).  Float math is the same f32 ops in
-        the same order as the kernel."""
+        slots (tests/bench verification, the fault domain's host
+        mirror); mutates ``state`` in place and returns (wprev,
+        after).  The weighted-prev term is the plain 64-bit
+        multiply-then-divide the kernel's 32-bit ``floor_muldiv``
+        must equal."""
         win = state[0, slots].copy()
         curr = state[1, slots].copy()
         prev = state[2, slots].copy()
@@ -180,10 +182,11 @@ class SlidingWindowModel:
         )
         base = np.where(same, curr, 0).astype(np.uint32)
         elapsed = now_u - w
-        frac = (divider - elapsed).astype(np.float32) / divider.astype(
-            np.float32
-        )
-        wprev = np.floor(new_prev.astype(np.float32) * frac).astype(np.uint32)
+        wprev = (
+            new_prev.astype(np.uint64)
+            * (divider - elapsed).astype(np.uint64)
+            // divider.astype(np.uint64)
+        ).astype(np.uint32)
         after = np.minimum(
             base.astype(np.uint64) + hits.astype(np.uint64),
             np.uint64(0xFFFFFFFF),

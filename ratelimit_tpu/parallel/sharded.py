@@ -37,10 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map  # jax >= 0.5
-except ImportError:  # pre-promotion releases keep it experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..backends.engine import CounterEngine
 from ..models.fixed_window import DeviceBatch, DeviceDecisions, decision_block
@@ -337,7 +334,7 @@ class ShardedCounterEngine(CounterEngine):
     (round-1 VERDICT weak #4: the replicated design did full-batch
     work on every chip)."""
 
-    def _device_submit(self, dedup, now: int = 0):
+    def _device_submit(self, dedup, now, watch):
         # `now` is the generic-algorithm batch clock; the sharded
         # engine serves fixed-window only (see CounterEngine).
         m = self.model
@@ -394,9 +391,11 @@ class ShardedCounterEngine(CounterEngine):
             dt = ""
         # Plain numpy input: uncommitted, so the jit places it per the
         # routed sharding without a cross-device reshard.
-        self._counts, afters_dev = m.step_counters_unique_routed_packed(
-            self._counts, dt, pk
-        )
+        shape = (cap, dt)
+        with self._device_call(watch, shape):
+            self._counts, afters_dev = m.step_counters_unique_routed_packed(
+                self._counts, dt, pk
+            )
 
         def reassemble(fetched: np.ndarray) -> np.ndarray:
             out = np.zeros(g, dtype=np.uint32)
@@ -407,7 +406,7 @@ class ShardedCounterEngine(CounterEngine):
             out[~valid] = totals32[~valid]
             return out
 
-        return afters_dev, reassemble
+        return afters_dev, reassemble, shape
 
     def __init__(
         self,

@@ -19,7 +19,7 @@ from typing import Optional
 
 from .config.runtime import RuntimeLoader
 from .service import RateLimitService
-from .settings import Settings, new_settings
+from .settings import Settings, configure_compile_cache, new_settings
 from .stats.manager import Manager
 from .stats.statsd import StatsdExporter
 from .utils.time import RealTimeSource
@@ -247,18 +247,11 @@ class Runner:
 
         install_thread_excepthook()
 
-        if s.tpu_compile_cache_dir:
+        if s.backend_type.lower() != "memory":
             # Must land before the first jit compile (engine creation
-            # below): restarts and fleet replicas sharing the dir skip
-            # recompiling every (bucket, dtype) serving kernel.
-            import jax
-
-            jax.config.update(
-                "jax_compilation_cache_dir", s.tpu_compile_cache_dir
-            )
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0.0
-            )
+            # below): restarts skip recompiling every (bucket, dtype)
+            # serving kernel.
+            configure_compile_cache()
 
         from .server.health import HealthChecker
         from .server.grpc_server import create_grpc_server
@@ -595,11 +588,34 @@ class Runner:
             gc.freeze()
 
         logger.warning(
-            "ratelimit serving: http=%s grpc=%s debug=%s backend=%s",
+            "ratelimit serving: http=%s grpc=%s debug=%s backend=%s%s",
             self.http_server.bound_port,
             self.grpc_server.bound_port,
             self.debug_server.bound_port,
             s.backend_type,
+            self._where_it_runs(),
+        )
+
+    def _where_it_runs(self) -> str:
+        """Start-line suffix saying where the counters REALLY live:
+        the platform JAX initialised (not the BACKEND_TYPE setting) and
+        each bank's slot-table implementation.  Empty for the host-only
+        memory backend."""
+        if not hasattr(self.cache, "engines"):
+            return ""
+        from .backends.checkpoint import bank_roles
+        from .backends.engine import device_report
+
+        dev = device_report()
+        tables = ",".join(
+            f"{role}:{engine.placement()['slot_table']}"
+            for role, engine in zip(
+                bank_roles(self.cache), self.cache.engines()
+            )
+        )
+        return (
+            f" platform={dev['platform']} device_kind={dev['device_kind']!r}"
+            f" devices={dev['device_count']} slot_tables={tables}"
         )
 
     def run(self) -> None:

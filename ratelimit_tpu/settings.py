@@ -81,6 +81,37 @@ class SettingsError(Exception):
     the reference, settings.go:110-119)."""
 
 
+#: Home of the persistent XLA compilation cache when the environment
+#: does not place it: a fixed path inside the checkout.  The path is
+#: part of the cache key, so it must never move between runs (no
+#: tempfile, pid or timestamp).
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
+)
+
+
+def compile_cache_dir() -> str:
+    """Where the persistent XLA compilation cache lives: where
+    JAX_COMPILATION_CACHE_DIR says, otherwise COMPILE_CACHE_DIR."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or COMPILE_CACHE_DIR
+
+
+def configure_compile_cache() -> str:
+    """Place JAX's persistent compilation cache for an entry point that
+    jits (runner, bench.py); call before the first compile.  Where
+    JAX_COMPILATION_CACHE_DIR is set JAX already honours it and no
+    directory is set in code.  Returns compile_cache_dir().  Every
+    serving kernel is cached, however fast it compiled: a restart then
+    reads them back instead of paying each (bucket, dtype) shape again
+    under live traffic."""
+    import jax
+
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return compile_cache_dir()
+
+
 @dataclass
 class Settings:
     # Server listen addresses (settings.go:15-20).
@@ -204,8 +235,9 @@ class Settings:
     tpu_warmup: bool = False
     # Device-path fault domain (backends/fault_domain.py;
     # docs/RESILIENCE.md).  KERNEL_DEADLINE_S bounds every kernel
-    # launch once a bank has completed its first one (first-batch XLA
-    # compilation keeps the generous dispatch timeout): a launch stuck
+    # launch whose (bucket, dtype) shape has completed before (a
+    # first-seen shape is XLA compilation and keeps the generous
+    # dispatch timeout): a launch stuck
     # past it trips the watchdog, quarantines the bank, and re-routes
     # its lanes per DEVICE_FAILURE_MODE — `host` (default) serves them
     # from a numpy mirror that keeps counting, `allow`/`deny` answer
@@ -224,11 +256,6 @@ class Settings:
     # reference delegates to Redis durability; empty = disabled).
     tpu_checkpoint_dir: str = ""
     tpu_checkpoint_interval_s: float = 30.0
-    # Persistent XLA compilation cache: restarts (and every replica of
-    # a fleet sharing the dir) skip recompiling the serving kernels —
-    # warmup drops from ~minutes of compiles to cache reads.  Empty =
-    # disabled.
-    tpu_compile_cache_dir: str = ""
 
     # Pluggable limiter-algorithm banks (models/registry.py;
     # docs/ALGORITHMS.md): comma list of non-default algorithms to
@@ -459,7 +486,6 @@ def new_settings() -> Settings:
         ),
         tpu_checkpoint_dir=_env_str("TPU_CHECKPOINT_DIR", ""),
         tpu_checkpoint_interval_s=_env_float("TPU_CHECKPOINT_INTERVAL_S", 30.0),
-        tpu_compile_cache_dir=_env_str("TPU_COMPILE_CACHE_DIR", ""),
         tpu_algorithm_banks=_env_str(
             "TPU_ALGORITHM_BANKS", "sliding_window,gcra"
         ),

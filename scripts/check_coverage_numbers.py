@@ -35,30 +35,6 @@ def _load(name: str):
 # line wraps can't hide a claim.
 CHECKS = [
     (
-        "wire C1 median p99",
-        r"wire-surface p99: median ([0-9.]+)ms at C1",
-        "closed_loop_p99.json",
-        lambda d: d["wire_closed_loop"]["rows"][0]["p99_ms"],
-    ),
-    (
-        "wire C1 best run",
-        r"best quiet-box run ([0-9.]+)ms",
-        "closed_loop_p99.json",
-        lambda d: min(d["wire_closed_loop"]["rows"][0]["p99_spread_ms"]),
-    ),
-    (
-        "wire C1 p50",
-        r"wire p50 ([0-9.]+)ms",
-        "closed_loop_p99.json",
-        lambda d: d["wire_closed_loop"]["rows"][0]["p50_ms"],
-    ),
-    (
-        "in-process C1 p99",
-        r"in-process closed-loop C1 p99 ([0-9.]+)ms",
-        "closed_loop_p99.json",
-        lambda d: d["closed_loop"][0]["p99_ms"],
-    ),
-    (
         "lane-implied throughput at 8 lanes",
         r"implied ([0-9.]+)M decisions/s at 8 lanes",
         "host_lanes.json",

@@ -127,8 +127,7 @@ def _packed(slots, hits, limits, fresh, divider, padded, ns):
 def test_sliding_kernel_matches_numpy_oracle():
     """Randomized multi-step parity: the jitted sliding-window
     kernel's state and readback must match reference_step exactly
-    (the f32 ops here — one divide, one multiply, one floor — have no
-    fusion ambiguity)."""
+    (both weigh the previous window in exact integer arithmetic)."""
     import jax.numpy as jnp
 
     ns = 256
@@ -160,50 +159,87 @@ def test_sliding_kernel_matches_numpy_oracle():
         now += int(rng.integers(0, 45))
 
 
-def test_gcra_kernel_matches_numpy_oracle_within_one_cell():
-    """GCRA parity with the compiler's latitude acknowledged: XLA may
-    fuse the TAT reconstruction (``rel + frac * 2^-32``) into an FMA,
-    a 1-ulp wobble that can move a budget across its floor() boundary
-    — so each step runs from the REFERENCE state and budgets/state
-    must agree within one emission cell (exactly, for the vast
-    majority of lanes)."""
+def test_floor_muldiv_is_the_exact_integer_floor():
+    """floor(a * b / d) over the whole uint32 range and every window
+    unit, against plain 64-bit arithmetic.  The f32 form this replaced
+    missed the exact floor in ~200 per million cases on the host and —
+    differently — on a TPU, whose f32 divide is not correctly rounded
+    (PERF.md, PR 21): one admission's difference between the device
+    and its host mirror."""
+    import jax
     import jax.numpy as jnp
 
-    ns = 256
+    from ratelimit_tpu.ops import floor_muldiv
+
+    rng = np.random.default_rng(11)
+    n = 1 << 16
+    divider = rng.choice(np.array([1, 60, 3600, 86400], np.uint32), n)
+    prev = rng.integers(0, 1 << 32, n, dtype=np.uint64).astype(np.uint32)
+    prev[:64] = 0xFFFFFFFF  # saturated counters
+    prev[64 : n // 2] = rng.integers(0, 5000, n // 2 - 64)  # everyday counts
+    rem = (rng.integers(0, 1 << 40, n) % (divider.astype(np.int64) + 1)).astype(
+        np.uint32
+    )  # 0..divider inclusive
+    got = np.asarray(
+        jax.jit(floor_muldiv)(
+            jnp.asarray(prev), jnp.asarray(rem), jnp.asarray(divider)
+        )
+    )
+    want = (
+        prev.astype(np.uint64) * rem.astype(np.uint64) // divider.astype(np.uint64)
+    ).astype(np.uint32)
+    np.testing.assert_array_equal(got, want)
+
+
+def test_gcra_kernel_is_exact_rational_gcra():
+    """Randomized multi-step parity, three ways: the jitted kernel's
+    budgets and state equal reference_step bit for bit, and both equal
+    GCRA carried in exact rationals (``fractions.Fraction`` TAT,
+    ``B = limit - ceil((TAT - now)+ / T)``) — for limits that do not
+    divide the window, every window unit, idle gaps from 0 s to days,
+    multi-cell hits and limits up to 2**31.  The f32 kernel this
+    replaced agreed with its own oracle on 89% of such lanes."""
+    import math
+    from fractions import Fraction
+
+    import jax.numpy as jnp
+
+    ns = 512
     model = get_algorithm("gcra").make_model(ns, 0.8)
+    state = model.init_state()
     ref = np.zeros((2, ns), np.uint32)
     rng = np.random.default_rng(7)
+    tat = [Fraction(0)] * ns
+    slot_limit = rng.integers(1, 2000, ns).astype(np.uint32)
+    slot_limit[:16] = rng.integers(1 << 20, 1 << 31, 16)
+    slot_divider = rng.choice(np.array([1, 60, 3600, 86400], np.uint32), ns)
     now = 1_700_000_000
     seen = set()
-    exact = total = 0
-    for step in range(30):
-        g = int(rng.integers(1, 9))
+    for step in range(60):
+        g = 64
         slots = rng.choice(ns, size=g, replace=False).astype(np.int32)
-        hits = rng.integers(1, 5, g).astype(np.uint32)
-        limits = rng.integers(1, 30, g).astype(np.uint32)
-        divider = np.full(g, 60, np.uint32)
+        hits = rng.integers(0, 5, g).astype(np.uint32)
+        if step % 5 == 0:
+            hits = hits * rng.integers(1, 300, g).astype(np.uint32)
+        limits, divider = slot_limit[slots], slot_divider[slots]
         fresh = np.array([s not in seen for s in slots], bool)
         seen.update(int(s) for s in slots)
         state, out = model.step_serve_packed(
-            jnp.asarray(ref.copy()),
-            _packed(slots, hits, limits, fresh, divider, 8, ns),
+            state, _packed(slots, hits, limits, fresh, divider, g, ns),
             jnp.asarray(now, jnp.int32),
         )
-        dev_state = np.asarray(state)
-        ref_out = model.reference_step(
+        budgets = model.reference_step(
             ref, slots, hits, limits, fresh, divider, now
         )
-        b_dev = np.asarray(out)[:g].astype(np.int64)
-        b_ref = ref_out.astype(np.int64)
-        assert np.abs(b_dev - b_ref).max(initial=0) <= 1, (step, b_dev, b_ref)
-        exact += int((b_dev == b_ref).sum())
-        total += g
-        # TAT seconds agree within 1s on every slot; resync from the
-        # oracle next step so wobble can't accumulate.
-        sec_delta = (dev_state[0] - ref[0]).view(np.int32)
-        assert np.abs(sec_delta).max(initial=0) <= 1
-        now += int(rng.integers(0, 45))
-    assert exact >= total * 0.9, (exact, total)
+        np.testing.assert_array_equal(np.asarray(out), budgets)
+        np.testing.assert_array_equal(np.asarray(state), ref)
+        for s, h, lim, d, b in zip(slots, hits, limits, divider, budgets):
+            t_emit = Fraction(int(d), int(lim))
+            used = math.ceil(max(tat[s] - now, 0) / t_emit)
+            assert b == max(int(lim) - used, 0), (step, s, lim, d)
+            if min(int(h), b) > 0:
+                tat[s] = max(tat[s], Fraction(now)) + min(int(h), b) * t_emit
+        now += int(rng.choice([0, 0, 1, 1, 2, 7, 61, 977, 4000, 90000, 200000]))
 
 
 # -- the boundary-burst scenario --------------------------------------
@@ -544,6 +580,24 @@ def test_checkpoint_roundtrip_algorithm_state(tmp_path):
             model=get_algorithm(other).make_model(1 << 10, 0.8),
         )
         assert not restore_engine(wrong, path, role="algo_" + name)
+
+    # Same kernel, older state layout (GCRA kept its TAT as seconds +
+    # a 2^-32 fraction before PR 21): refused, not misread.
+    from ratelimit_tpu.backends.checkpoint import write_snapshot
+
+    bank = cache.algorithm_banks["gcra"]
+    old = {
+        "tat_sec": np.zeros(1 << 10, np.uint32),
+        "tat_frac": np.zeros(1 << 10, np.uint32),
+    }
+    path = str(tmp_path / "gcra_old_layout.npz")
+    write_snapshot(
+        path, 1 << 10, old, bank.slot_table.entries(), "algo_gcra", "gcra"
+    )
+    fresh = CounterEngine(
+        buckets=(8, 32), model=get_algorithm("gcra").make_model(1 << 10, 0.8)
+    )
+    assert not restore_engine(fresh, path, role="algo_gcra")
 
 
 def test_checkpoint_roles_include_algorithm_banks(tmp_path):

@@ -260,13 +260,13 @@ def test_eager_idle_launches_lone_item_but_coalesces_under_load():
     batches = []
 
     class _GatedEngine(CounterEngine):
-        def step_complete(self, token):
+        def step_complete(self, token, *watch):
             release.wait(10)
-            return super().step_complete(token)
+            return super().step_complete(token, *watch)
 
-        def submit_packed(self, now, blob, meta):
+        def submit_packed(self, now, blob, meta, *watch):
             batches.append(len(meta))
-            return super().submit_packed(now, blob, meta)
+            return super().submit_packed(now, blob, meta, *watch)
 
     engine = _GatedEngine(num_slots=256, buckets=(8, 32))
     # Generous window: only eager-idle could launch item A quickly.

@@ -21,6 +21,7 @@ from ratelimit_tpu.backends.fault_domain import (
     FAULT_DEVICE_LOST,
     FAULT_EXCEPTION,
     FAULT_HANG,
+    KernelDeadlineExceeded,
     classify_fault,
 )
 from ratelimit_tpu.backends.tpu_cache import TpuRateLimitCache
@@ -90,6 +91,13 @@ def test_classify_fault_taxonomy():
     wrapped = RuntimeError("batch dispatcher is dead")
     wrapped.__cause__ = DeviceLostError("lane0")
     assert classify_fault(wrapped) == FAULT_DEVICE_LOST
+    # The watchdog's own verdict is a TimeoutError subclass: a hang.
+    assert classify_fault(KernelDeadlineExceeded("stuck 1s")) == FAULT_HANG
+    # Socket vocabulary is no device's: with direct PJRT there is no
+    # link between host and chip that could reset.
+    assert classify_fault(ConnectionResetError("connection reset by peer")) == (
+        FAULT_EXCEPTION
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -196,6 +204,196 @@ def test_watchdog_tick_detects_hang_without_traffic():
         cache.close()
 
 
+class _GatedKernelEngine(CounterEngine):
+    """CounterEngine whose jitted serving call can be held open from
+    the test — the stand-in for a kernel call that takes longer than
+    the deadline (XLA compilation when the shape is new, a wedged
+    device when it is not)."""
+
+    def __init__(self, *a, **kw):
+        super().__init__(*a, **kw)
+        self.gate = threading.Event()
+        self.gate.set()
+        self.entered = threading.Event()
+        real = self.model.step_counters_unique_packed
+
+        def gated(*args):
+            self.entered.set()
+            assert self.gate.wait(30)
+            return real(*args)
+
+        self.model.step_counters_unique_packed = gated
+
+
+def _hold_one_request(cache, rule, engine, req):
+    """Start `req` on a thread and return once its kernel call is
+    parked at the gate."""
+    got = {}
+    engine.entered.clear()
+    engine.gate.clear()
+    limits = [rule] * len(req.descriptors)
+    t = threading.Thread(
+        target=lambda: got.update(status=cache.do_limit(req, limits)[0])
+    )
+    t.start()
+    assert engine.entered.wait(10)
+    return t, got
+
+
+def test_deadline_spares_first_seen_shape_and_binds_repeat_shape():
+    """The kernel deadline is per SHAPE, not per bank: a first-seen
+    (bucket, dtype) shape that outlasts it is compilation and must not
+    quarantine — however many other shapes the bank has completed —
+    while the same overrun on a shape that has completed before is a
+    hang.  Synthetic stamp clock: no real time passes."""
+    from ratelimit_tpu.utils.time import FakeMonotonicClock
+
+    clock = FakeMonotonicClock()
+    engine = _GatedKernelEngine(num_slots=256, buckets=(8, 32))
+    cache = TpuRateLimitCache(
+        engine,
+        time_source=PinnedTimeSource(1234),
+        batch_window_us=100,
+        kernel_deadline_s=0.25,
+        fault_clock=clock,
+        fault_interval_s=0,  # tick() manually
+        fault_snapshot_interval_s=1000.0,
+    )
+    mgr = Manager()
+    rule = _rule(mgr)
+    fd = cache.fault_domain
+    small = _req()  # 1 lane -> bucket 8
+    big = RateLimitRequest(  # 9 distinct keys -> bucket 32
+        "d", [Descriptor.of(("k", f"v{i}")) for i in range(9)], 1
+    )
+    try:
+        fd.snapshot_now()  # keep tick() from queueing one behind the gate
+        # First-seen shape #1, held far past the deadline: not a hang.
+        t, got = _hold_one_request(cache, rule, engine, small)
+        clock.advance(10.0)
+        fd.tick()
+        assert not fd.is_quarantined(0)
+        engine.gate.set()
+        t.join(10)
+        assert got["status"].code is Code.OK
+        assert engine.placement()["shapes_compiled"] == 1
+
+        # First-seen shape #2 on a bank that HAS completed launches
+        # (the old per-bank grace would have called this a hang).
+        t, got = _hold_one_request(cache, rule, engine, big)
+        clock.advance(10.0)
+        fd.tick()
+        assert not fd.is_quarantined(0)
+        engine.gate.set()
+        t.join(10)
+        assert got["status"].code is Code.OK
+        assert engine.placement()["shapes_compiled"] == 2
+        assert fd.stat_faults[FAULT_HANG] == 0
+
+        # Repeat of shape #1, same overrun: a hang.
+        t, got = _hold_one_request(cache, rule, engine, small)
+        clock.advance(10.0)
+        fd.tick()
+        assert fd.is_quarantined(0)
+        assert fd.stat_faults[FAULT_HANG] == 1
+        engine.gate.set()
+        t.join(10)
+        assert not t.is_alive()
+        assert got["status"].code is Code.OK  # answered by the mirror
+    finally:
+        engine.gate.set()
+        cache.close()
+
+
+def test_host_work_inside_a_launch_is_not_on_the_deadline_clock():
+    """Slot assignment runs inside the same engine call as the kernel
+    launch, and at a million keys one native-table rehash outlasts the
+    deadline (chip_smoke found the bank quarantining itself there).
+    Only the device call itself is timed: host work holding a launch
+    of a PROVEN shape for ten deadlines is not a hang."""
+    from ratelimit_tpu.utils.time import FakeMonotonicClock
+
+    gate, entered = threading.Event(), threading.Event()
+    gate.set()
+
+    class _SlowHostEngine(CounterEngine):
+        def submit_packed(self, now, blob, meta, *watch):
+            entered.set()
+            assert gate.wait(30)  # the rehash
+            return super().submit_packed(now, blob, meta, *watch)
+
+    clock = FakeMonotonicClock()
+    cache = TpuRateLimitCache(
+        _SlowHostEngine(num_slots=256, buckets=(8,)),
+        time_source=PinnedTimeSource(1234),
+        batch_window_us=100,
+        kernel_deadline_s=0.25,
+        fault_clock=clock,
+        fault_interval_s=0,
+        fault_snapshot_interval_s=1000.0,
+    )
+    mgr = Manager()
+    rule = _rule(mgr)
+    fd = cache.fault_domain
+    try:
+        fd.snapshot_now()
+        assert cache.do_limit(_req(), [rule])[0].code is Code.OK  # proven
+        got = {}
+        entered.clear()
+        gate.clear()
+        t = threading.Thread(
+            target=lambda: got.update(status=cache.do_limit(_req(), [rule])[0])
+        )
+        t.start()
+        assert entered.wait(10)
+        clock.advance(2.5)
+        fd.tick()
+        assert not fd.is_quarantined(0)
+        gate.set()
+        t.join(10)
+        assert got["status"].code is Code.OK
+        assert fd.stat_faults[FAULT_HANG] == 0
+    finally:
+        gate.set()
+        cache.close()
+
+
+def test_slow_collector_is_not_a_device_hang():
+    """A bank whose collector is busy with host work (a snapshot of a
+    million-key table, a deep queue) makes RPCs wait longer than the
+    kernel deadline without any device call being stuck: the RPCs
+    keep waiting (dispatch timeout), nothing is quarantined."""
+    cache = make_cache(deadline=0.1)
+    mgr = Manager()
+    rule = _rule(mgr)
+    try:
+        assert cache.do_limit(_req(), [rule])[0].code is Code.OK
+        cache.fault_domain.snapshot_now()
+        release = threading.Event()
+        d = next(iter(cache._dispatchers.values()))
+        blocker = threading.Thread(
+            target=lambda: d.run_on_thread(lambda: release.wait(10))
+        )
+        blocker.start()
+        got = {}
+        t = threading.Thread(
+            target=lambda: got.update(status=cache.do_limit(_req(), [rule])[0])
+        )
+        t.start()
+        time.sleep(0.5)  # five deadlines
+        cache.fault_domain.tick()
+        assert t.is_alive()  # still waiting, not failed over
+        assert not cache.fault_domain.is_quarantined(0)
+        release.set()
+        t.join(10)
+        blocker.join(10)
+        assert got["status"].code is Code.OK
+        assert cache.fault_domain.stat_faults[FAULT_HANG] == 0
+        assert cache.fault_domain.stat_fallback_decisions == 0
+    finally:
+        cache.close()
+
+
 # ---------------------------------------------------------------------------
 # failure modes
 # ---------------------------------------------------------------------------
@@ -274,6 +472,101 @@ def test_warm_restart_restores_counters_no_window_restart():
         for _ in range(20):
             admitted += cache.do_limit(_req(), [rule])[0].code is Code.OK
         assert admitted == 20
+    finally:
+        inj.heal()
+        cache.close()
+
+
+def test_request_that_waited_out_a_restart_is_answered_by_the_device():
+    """A restart holds the bank's fallback lock for the whole counter
+    import (seconds at a million keys) and every request for the bank
+    queues behind it; when the lock opens the mirror is gone.  Those
+    requests must be answered by the re-admitted device bank — they
+    used to crash on the missing mirror (chip_smoke found it)."""
+    inj = DeviceFaultInjector()
+    cache = make_cache(inj, deadline=0.2)
+    mgr = Manager()
+    rule = _rule(mgr)
+    fd = cache.fault_domain
+    try:
+        assert cache.do_limit(_req(), [rule])[0].code is Code.OK
+        inj.raise_error("lane0")
+        assert cache.do_limit(_req(), [rule])[0].code is Code.OK  # mirror
+        assert fd.is_quarantined(0)
+        inj.heal()
+        rec = fd._records[0]
+        importing, release = threading.Event(), threading.Event()
+        real_export = rec.fallback.export_keys
+
+        def slow_export(*a, **kw):  # runs under the fallback lock
+            importing.set()
+            assert release.wait(30)
+            return real_export(*a, **kw)
+
+        rec.fallback.export_keys = slow_export
+        time.sleep(0.06)  # past the 0.05 s restart backoff
+        restart = threading.Thread(target=fd.tick)
+        restart.start()
+        assert importing.wait(20)
+        got = {}
+        t = threading.Thread(
+            target=lambda: got.update(status=cache.do_limit(_req(), [rule])[0])
+        )
+        t.start()
+        time.sleep(0.2)
+        assert t.is_alive()  # parked on the fallback lock
+        release.set()
+        restart.join(20)
+        t.join(10)
+        assert not t.is_alive() and not restart.is_alive()
+        assert not fd.is_quarantined(0)
+        assert got["status"].code is Code.OK
+        # One episode, one restart: the waiting request did not fault
+        # the fresh bank on its way through.
+        assert sum(fd.stat_faults.values()) == 1
+        assert fd.stat_restarts == 1
+    finally:
+        inj.heal()
+        cache.close()
+
+
+def test_fall_back_on_a_readmitted_bank_is_bounded_and_never_raises():
+    """The leg of _fall_back a request takes when its bank was
+    re-admitted under it (no mirror left): the device bank gets one
+    try inside the caller's deadline; a fault there is recorded and
+    answered by the new mirror; nothing escapes as an error."""
+    inj = DeviceFaultInjector()
+    cache = make_cache(inj, deadline=0.2)
+    rule = _rule(Manager())
+    fd = cache.fault_domain
+
+    def fall_back(deadline):
+        items, statuses, *_ = cache._prepare(_req(), [rule])
+        bank, _engine, item = items[0]
+        cache._fall_back(fd, bank, item, deadline)
+        return statuses[0]
+
+    try:
+        # A closed bank has no mirror: the device answers.
+        assert fall_back(None).code is Code.OK
+        assert sum(fd.stat_faults.values()) == 0
+        assert fd.stat_fallback_decisions == 0
+        # The caller is out of time and the bank does not answer: the
+        # failure-mode answer, at once, and no fault on the bank.
+        inj.hang("lane0")
+        t0 = time.monotonic()
+        assert fall_back(time.monotonic() - 1.0).code is Code.OK
+        assert time.monotonic() - t0 < 5.0  # not the 120 s dispatch timeout
+        assert sum(fd.stat_faults.values()) == 0
+        assert cache.stat_deadline_answers == 1
+        inj.heal()
+        # The re-admitted bank fails: a fault like any other, and the
+        # mirror it leaves behind answers.
+        inj.raise_error("lane0")
+        assert fall_back(None).code is Code.OK
+        assert fd.is_quarantined(0)
+        assert fd.stat_faults[FAULT_EXCEPTION] == 1
+        assert fd.stat_fallback_decisions == 1
     finally:
         inj.heal()
         cache.close()
@@ -431,7 +724,15 @@ def test_fault_counters_and_debug_summary():
         assert gauges["ratelimit.tpu.fault.quarantined_banks"] == 1
         summary = cache.fault_domain.summary()
         assert summary["failure_mode"] == "host"
+        # Where it ran, as JAX reports it (not as BACKEND_TYPE says).
+        assert summary["device"] == {
+            "platform": "cpu",
+            "device_kind": "cpu",
+            "device_count": 8,
+        }
         bank = summary["banks"][0]
+        assert bank["slot_table"] in ("native", "python")
+        assert bank["state_devices"] == ["cpu:0"]
         assert bank["state"] == "quarantined"
         assert bank["fault_kind"] == "exception"
         assert bank["mirror_live_keys"] >= 0

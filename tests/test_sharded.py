@@ -127,7 +127,7 @@ def test_routed_engine_divides_work_per_bank():
     )
     token = se.step_submit(hb)
     _hits, _limits, _shadow, chunks, _now = token
-    afters_dev, _start, _count, _dedup, reassemble = chunks[0]
+    afters_dev, _start, _count, _dedup, reassemble, _shape = chunks[0]
     # 256 uniform lanes over 8 banks -> ~32/bank -> cap bucket 128
     # at worst; the full-batch (replicated) design would be 256 wide.
     assert afters_dev.shape[0] == 8

@@ -177,48 +177,6 @@ def test_sharded_write_behind_backend(tmp_path_factory):
         r.stop()
 
 
-def test_compile_cache_dir_populated(tmp_path_factory, monkeypatch):
-    """TPU_COMPILE_CACHE_DIR persists compiled serving kernels so
-    restarts skip XLA recompilation."""
-    import jax
-
-    cache_dir = str(tmp_path_factory.mktemp("xla-cache"))
-    prev_min_compile = jax.config.jax_persistent_cache_min_compile_time_secs
-    # Order-independence: earlier tests may have compiled the same
-    # kernel shapes, and in-memory jit cache hits never reach the
-    # persistent cache — force a fresh compile after the dir is set.
-    jax.clear_caches()
-    r = _make_runner(
-        tmp_path_factory,
-        "cc-runtime",
-        backend_type="tpu",
-        tpu_batch_buckets=[8],
-        tpu_compile_cache_dir=cache_dir,
-    )
-    r.start()
-    # If an earlier test already initialized the persistent cache
-    # module (with no dir), the runner's config update is not picked
-    # up until the cache resets; production processes set the dir
-    # before any jit so they never need this.
-    from jax.experimental.compilation_cache import compilation_cache as _cc
-
-    _cc.reset_cache()
-    try:
-        resp = _call(r, _request([("limited", "cc")]))
-        assert resp.overall_code == rls_pb2.RateLimitResponse.OK
-        import os
-
-        entries = os.listdir(cache_dir)
-        assert entries, "compile cache dir is empty after serving"
-    finally:
-        r.stop()
-        # Don't leak the config changes into other tests.
-        jax.config.update("jax_compilation_cache_dir", None)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", prev_min_compile
-        )
-
-
 def test_sharded_dual_bank_per_second(tmp_path_factory):
     """BACKEND_TYPE=tpu-sharded + TPU_PER_SECOND=true: BOTH banks are
     bank-sharded mesh engines (the dual-Redis analog composed with the
