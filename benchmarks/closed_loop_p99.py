@@ -177,31 +177,33 @@ def staged_closed_loop(cache, workers: int = 4, n_traced: int = 400):
                     "set_local_cache",
                 ):
                     getattr(decisions, f).tolist()
-                applied_at["t"] = time.perf_counter()
+                applied_at["t"] = time.monotonic_ns()
 
-            trace = {"submit": time.perf_counter()}
             item = WorkItem(
                 now=1_700_000_000,
                 lanes=(),
                 pack=LanePack(key_blob=b"".join(enc), meta=meta),
                 apply=apply,
                 defer_apply=True,
-                trace=trace,
             )
             d.submit(item)
             item.wait(30)
-            t_end = applied_at.get("t", time.perf_counter())
+            t_end = applied_at.get("t", time.monotonic_ns())
+            # The pipeline's own always-on stamps (dispatcher
+            # .LaunchStamps, monotonic_ns) — the same ones serving's
+            # request legs and tracer spans are built from.
+            submit, at = item.submit_ns, item.launch
             with lock:
                 stages["intake_to_launch"].append(
-                    trace["launch"] - trace["submit"]
+                    (at.launched_ns - submit) / 1e9
                 )
                 stages["launch_to_complete"].append(
-                    trace["complete"] - trace["launch"]
+                    (at.signal_ns - at.launched_ns) / 1e9
                 )
                 stages["complete_to_applied"].append(
-                    t_end - trace["complete"]
+                    (t_end - at.signal_ns) / 1e9
                 )
-                stages["total"].append(t_end - trace["submit"])
+                stages["total"].append((t_end - submit) / 1e9)
 
     threads = [
         threading.Thread(target=worker, args=(w,)) for w in range(workers)

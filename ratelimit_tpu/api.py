@@ -86,12 +86,21 @@ class RateLimitRequest:
     bounded by it — ``min(KERNEL_DEADLINE_S, remaining)`` — and a wait
     cut short answers per DEVICE_FAILURE_MODE instead of blocking past
     the caller's deadline (backends/tpu_cache.py ``_execute``).  None
-    means the caller set no deadline."""
+    means the caller set no deadline.
+
+    ``legs`` is process-internal too, and travels the other way: the
+    backend leaves ``(submitted_ns, signal_ns, woke_ns)`` there
+    (``time.monotonic_ns``: everything queued for the device; the
+    completer signalled the launch the answer waited for; the waiting
+    thread ran again) and the transport turns them into the per-request
+    leg histograms (server/grpc_server.py ``observe_legs``).  None from
+    a backend that stamps nothing."""
 
     domain: str
     descriptors: Sequence[Descriptor]
     hits_addend: int = 0
     deadline: Optional[float] = None
+    legs: Optional[tuple] = None
 
 
 @dataclass(frozen=True, slots=True)

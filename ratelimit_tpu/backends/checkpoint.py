@@ -30,6 +30,9 @@ from typing import Optional
 
 import numpy as np
 
+from ..observability import spans as _spans
+from ..observability.spans import SPANS
+
 logger = logging.getLogger("ratelimit.checkpoint")
 
 FORMAT_VERSION = 1
@@ -98,11 +101,13 @@ def write_snapshot(
     dict."""
     if not isinstance(state, dict):
         state = {"counts": state}
-    key_bytes = [e[0].encode("utf-8") for e in entries]
-    key_lens = np.array([len(b) for b in key_bytes], dtype=np.int64)
-    key_blob = np.frombuffer(b"".join(key_bytes), dtype=np.uint8)
-    slots = np.array([e[1] for e in entries], dtype=np.int64)
-    expiries = np.array([e[2] for e in entries], dtype=np.int64)
+    with SPANS.span(_spans.BG_CHECKPOINT_SERIALIZE):
+        # Pure Python over every live key: holds the GIL throughout.
+        key_bytes = [e[0].encode("utf-8") for e in entries]
+        key_lens = np.array([len(b) for b in key_bytes], dtype=np.int64)
+        key_blob = np.frombuffer(b"".join(key_bytes), dtype=np.uint8)
+        slots = np.array([e[1] for e in entries], dtype=np.int64)
+        expiries = np.array([e[2] for e in entries], dtype=np.int64)
     tmp = f"{path}.tmp.{os.getpid()}"
     meta = json.dumps(
         {
@@ -119,17 +124,18 @@ def write_snapshot(
         # Fixed-window snapshots keep the historical layout so
         # pre-algorithm checkpoints and new ones are interchangeable.
         arrays = {"counts": state["counts"]}
-    with open(tmp, "wb") as f:
-        np.savez_compressed(
-            f,
-            meta=np.frombuffer(meta.encode(), dtype=np.uint8),
-            key_lens=key_lens,
-            key_blob=key_blob,
-            slots=slots,
-            expiries=expiries,
-            **arrays,
-        )
-    os.replace(tmp, path)
+    with SPANS.span(_spans.BG_CHECKPOINT_WRITE):
+        with open(tmp, "wb") as f:
+            np.savez_compressed(
+                f,
+                meta=np.frombuffer(meta.encode(), dtype=np.uint8),
+                key_lens=key_lens,
+                key_blob=key_blob,
+                slots=slots,
+                expiries=expiries,
+                **arrays,
+            )
+        os.replace(tmp, path)
 
 
 def save_engine(engine, path: str, role: str = "") -> None:
@@ -311,41 +317,41 @@ class CheckpointManager:
         roles = self._bank_roles()
         fd = getattr(self.cache, "fault_domain", None)
         for idx, engine in enumerate(self.cache.engines()):
-            if fd is not None and fd.is_quarantined(idx):
-                snap = fd.mirror_snapshot(idx)
-                if snap is None:
-                    continue  # no mirror: the last snapshot stands
-                state, entries = snap
-                write_snapshot(
-                    self._bank_path(idx),
-                    engine.model.num_slots,
-                    state,
-                    entries,
-                    roles[idx],
-                    getattr(engine, "algorithm", "fixed_window"),
-                )
-                continue
+            # One bank, one piece of background work with a duration
+            # (rl.bg.checkpoint; children grab / serialize / write).
+            with SPANS.background(_spans.BG_CHECKPOINT, idx):
+                self._checkpoint_bank(idx, engine, roles[idx], fd)
+
+    def _checkpoint_bank(self, idx: int, engine, role: str, fd) -> None:
+        if fd is not None and fd.is_quarantined(idx):
+            snap = fd.mirror_snapshot(idx)
+            if snap is None:
+                return  # no mirror: the last snapshot stands
+            state, entries = snap
+        else:
             grabbed = {}
 
-            def grab(e=engine, out=grabbed):
-                out["state"], out["entries"] = snapshot_engine(e)
+            def grab():
+                grabbed["state"], grabbed["entries"] = snapshot_engine(engine)
 
             try:
-                self.cache.run_exclusive(engine, grab)
+                with SPANS.span(_spans.BG_CHECKPOINT_GRAB, idx):
+                    self.cache.run_exclusive(engine, grab)
             except Exception:
                 # The bank faulted between the quarantine check and
                 # the snapshot token (dead dispatcher): skip it this
                 # round; the fault domain's mirror covers the next.
                 logger.exception("bank %d snapshot skipped", idx)
-                continue
-            write_snapshot(
-                self._bank_path(idx),
-                engine.model.num_slots,
-                grabbed["state"],
-                grabbed["entries"],
-                roles[idx],
-                getattr(engine, "algorithm", "fixed_window"),
-            )
+                return
+            state, entries = grabbed["state"], grabbed["entries"]
+        write_snapshot(
+            self._bank_path(idx),
+            engine.model.num_slots,
+            state,
+            entries,
+            role,
+            getattr(engine, "algorithm", "fixed_window"),
+        )
 
     def start(self) -> None:
         if self._thread is not None:

@@ -31,7 +31,9 @@ from typing import Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..observability import spans as _spans
 from ..observability.launches import OUTCOME_FAULT, OUTCOME_OK
+from ..observability.spans import SPANS
 from ..utils.time import REAL_MONOTONIC
 from .engine import CallWatch, HostDecisions
 
@@ -114,6 +116,26 @@ class LanePack:
         return LanePack(key_blob=b"".join(enc), meta=meta)
 
 
+class LaunchStamps:
+    """One launch's instants (``time.monotonic_ns``), shared by every
+    item that rode it: one object a launch, one attribute store an
+    item.  ``launched_ns``: submit_packed has returned, the device step
+    is in flight (assign, dedup, pack and transfer lie before it).
+    ``signal_ns``: readback and decide are done, the completer is about
+    to set the items' events.  0 = that stage never happened (a failed
+    step).  Always on: the request legs (tpu_cache._execute), the
+    request tracer's dispatch/kernel/wake spans and the closed-loop
+    harness all read this one set of stamps."""
+
+    __slots__ = ("bank", "launch_id", "launched_ns", "signal_ns")
+
+    def __init__(self, bank: int = -1, launch_id: int = -1):
+        self.bank = bank
+        self.launch_id = launch_id
+        self.launched_ns = 0
+        self.signal_ns = 0
+
+
 @dataclass
 class WorkItem:
     """One request's engine-bound lanes + completion callback.
@@ -142,22 +164,16 @@ class WorkItem:
     # keep the default: their apply still runs on the completer.
     defer_apply: bool = False
     result: Optional[tuple] = None  # (HostDecisions, lo, hi)
-    # Optional per-stage timestamp sink: when set, the pipeline stamps
-    # perf_counter() at "launch" (collector hands the batch to the
-    # device) and "complete" (readback+decide done, waiter signalled).
-    # The submitter owns "submit"/"applied".  Powers the closed-loop
-    # latency harness (benchmarks/closed_loop_p99.py) and, in serving,
-    # the request tracer: tpu_cache sets it on SAMPLED requests and
-    # converts the stamps to dispatch/kernel spans after wait()
-    # (observability/trace.py).  None on the unsampled hot path.
-    trace: Optional[dict] = None
-    # Launch-recorder stamps (observability/launches.py), set only
-    # when a recorder is attached to the receiving dispatcher:
-    # `submit_ns` is monotonic_ns at intake (the queue-wait baseline);
+    # The item's passage, in time.monotonic_ns: `submit_ns` at intake
+    # (BatchDispatcher.submit — the queue-wait baseline), `launch` the
+    # stamps of the launch it rode (set by submit_items), `woke_ns`
+    # when its waiter came back from the completion event (wait()).
     # `corr` carries the request's cross-hop correlation id so the
-    # launch record can name its longest-queued rider.  Both stay 0 on
-    # the recorder-off path.
+    # launch record can name its longest-queued rider (0 when the
+    # flight ring or the launch recorder is off).
     submit_ns: int = 0
+    launch: Optional[LaunchStamps] = None
+    woke_ns: int = 0
     corr: int = 0
     event: threading.Event = field(default_factory=threading.Event)
     error: Optional[BaseException] = None
@@ -193,6 +209,7 @@ class WorkItem:
             raise TimeoutError(
                 f"batch dispatcher did not answer within {timeout}s"
             )
+        self.woke_ns = time.monotonic_ns()
         if self.error is not None:
             raise self.error
         if self.defer_apply and self.result is not None:
@@ -249,11 +266,16 @@ def _slice(d: HostDecisions, lo: int, hi: int) -> HostDecisions:
 
 
 def submit_items(
-    engine, items: List[WorkItem], watch: Optional[CallWatch] = None
+    engine,
+    items: List[WorkItem],
+    watch: Optional[CallWatch] = None,
+    stamps: Optional[LaunchStamps] = None,
 ):
     """Assemble one engine batch from `items` and LAUNCH it (no wait).
     `watch` is the calling dispatcher thread's CallWatch (None for
-    inline runs and the host fallback engines, which nothing watches).
+    inline runs and the host fallback engines, which nothing watches);
+    `stamps` the launch's LaunchStamps (a dispatcher passes one that
+    names its bank and launch id).
 
     Must be called from the single thread that owns `engine`'s
     SlotTable.  Returns the engine token for complete_items, or None
@@ -271,15 +293,15 @@ def submit_items(
         blobs = []
         metas = []
         now = None
-        traces = []
+        if stamps is None:
+            stamps = LaunchStamps()
         for it in items:
             p = it.get_pack()
             blobs.append(p.key_blob)
             metas.append(p.meta_u8)
             if now is None or it.now > now:
                 now = it.now
-            if it.trace is not None:
-                traces.append(it.trace)
+            it.launch = stamps
         if len(metas) == 1:
             blob, meta = blobs[0], items[0].pack.meta
         elif metas:
@@ -295,16 +317,11 @@ def submit_items(
             token = engine.submit_packed(now, blob, meta)
         else:
             token = engine.submit_packed(now, blob, meta, watch)
-        if traces:
-            # Stamped AFTER submit_packed returns: "launch" means the
-            # device step is in flight — host-side assign/dedup/
-            # transfer cost lands in intake->launch, so the
-            # launch->complete stage is purely the device leg +
-            # readback + decide (the part that moves to the chip on
-            # real hardware).
-            t_launch = time.perf_counter()
-            for tr in traces:
-                tr["launch"] = t_launch
+        # Stamped AFTER submit_packed returns: "launched" means the
+        # device step is in flight — host-side assign/dedup/transfer
+        # cost lands in intake->launched, so launched->signal is purely
+        # the device leg + readback + decide.
+        stamps.launched_ns = time.monotonic_ns()
         return token
     except BaseException as e:
         for it in items:
@@ -339,26 +356,33 @@ def complete_items(
             it.fail(e)
         return False
     off = 0
-    t_complete = None
-    for it in items:
-        n = it.n_lanes
-        end = off + n
-        if it.defer_apply:
-            # Park a reference + bounds; the waiting RPC thread does
-            # the slicing, list conversion and apply after event.set —
-            # the completer's serial leg is just signalling.
-            it.result = (decisions, off, end)
-        else:
-            try:
-                it.apply(_slice(decisions, off, end))
-            except BaseException as e:
-                it.error = e
-        off = end
-        if it.trace is not None:
-            if t_complete is None:
-                t_complete = time.perf_counter()
-            it.trace["complete"] = t_complete
-        it.event.set()
+    stamps = items[0].launch if items else None
+    if stamps is None:  # items that never went through submit_items
+        stamps = LaunchStamps()
+    with SPANS.span(_spans.COMPLETE_SIGNAL, stamps.bank, stamps.launch_id):
+        # One stamp a launch, before the first event is set: a waiter's
+        # wake-up leg (woke_ns - signal_ns) then holds the rest of this
+        # loop for the items behind the first.
+        t_signal = stamps.signal_ns = time.monotonic_ns()
+        for it in items:
+            n = it.n_lanes
+            end = off + n
+            if it.defer_apply:
+                # Park a reference + bounds; the waiting RPC thread
+                # does the slicing, list conversion and apply after
+                # event.set — the completer's serial leg is just
+                # signalling.
+                it.result = (decisions, off, end)
+            else:
+                try:
+                    it.apply(_slice(decisions, off, end))
+                except BaseException as e:
+                    it.error = e
+            off = end
+            it.event.set()
+    if watch is not None:
+        t_end = time.monotonic_ns()
+        watch.last_leg = (_spans.COMPLETE_SIGNAL, t_end, t_end - t_signal)
     return True
 
 
@@ -440,6 +464,10 @@ class BatchDispatcher:
         self.launch_bank = 0
         self.launch_algo = 0
         self._launch_meta: deque = deque()
+        # This dispatcher's own launch count (collector-only writer):
+        # rl.launch's stat and the launch record's launch_id, so a span
+        # in the trace and a record in the ring name the same launch.
+        self._launch_seq = 0
         # Proactive slot-table gc: without it, expired keys linger in
         # the table until the free list empties (Redis expires keys
         # lazily too, but also actively samples; fixed 10-key-space
@@ -539,10 +567,9 @@ class BatchDispatcher:
             return max(v, len(self._buf))
 
     def submit(self, item: WorkItem) -> None:
-        if self.launches is not None:
-            # Queue-wait baseline for the launch record; recorder-off
-            # submits pay one attribute load + branch.
-            item.submit_ns = time.monotonic_ns()
+        # Queue-wait baseline for the launch record and the request's
+        # prepare leg: one clock read an item, always.
+        item.submit_ns = time.monotonic_ns()
         self._enqueue(item)
 
     def flush(self) -> None:
@@ -576,6 +603,35 @@ class BatchDispatcher:
             if since is not None and now - since > age:
                 age = now - since
         return age
+
+    def watch_report(self, now: Optional[float] = None) -> List[dict]:
+        """Both dispatcher threads as a hang fault records them: how
+        long each has been inside a device call (the watchdog's own
+        stamps), and the last leg each COMPLETED — its span name, how
+        long it took, how long ago it ended.  A device call that ended
+        normally milliseconds ago on one thread, while the other has
+        been "in" one for seconds, reads very differently from two
+        threads that both stopped at the same instant."""
+        if now is None:
+            now = self._stamp_now()
+        now_ns = time.monotonic_ns()
+        report = []
+        for role, watch in (
+            ("collector", self._launch_watch),
+            ("completer", self._complete_watch),
+        ):
+            row: dict = {"thread": role}
+            since, leg = watch.since, watch.last_leg
+            if since is not None:
+                row["in_device_call_s"] = round(now - since, 3)
+            if leg is not None:
+                row["last_leg"] = leg[0]
+                row["last_leg_ms"] = round(leg[2] / 1e6, 3)
+                row["last_leg_ended_s_ago"] = round(
+                    (now_ns - leg[1]) / 1e9, 3
+                )
+            report.append(row)
+        return report
 
     def kill(self, exc: BaseException) -> None:
         """Abandon this dispatcher WITHOUT joining its threads: mark
@@ -614,17 +670,23 @@ class BatchDispatcher:
         # completer thread) swaps the list object under the cv, so a
         # hoisted alias could drain a buffer nobody owns anymore.
         buf_cv = self._buf_cv
+        span = SPANS.span
+        bank = self.launch_bank
 
         while True:
             with buf_cv:
                 while not self._buf:
                     if deadline is None:
-                        buf_cv.wait()  # idle: block for work
+                        with span(_spans.COLLECT_IDLE, bank):
+                            buf_cv.wait()  # idle: block for work
                     else:
                         timeout = deadline - time.monotonic()
-                        if timeout <= 0 or not buf_cv.wait(timeout):
-                            if not self._buf:
-                                return batch, tokens, stopping
+                        notified = False
+                        if timeout > 0:
+                            with span(_spans.COLLECT_WINDOW, bank):
+                                notified = buf_cv.wait(timeout)
+                        if not notified and not self._buf:
+                            return batch, tokens, stopping
                 drained = self._buf  # tpu-lint: disable=hot-path-cost -- self._buf is re-read at every use on purpose: _die() swaps the list object
                 self._buf = []
                 n_drained = len(drained)
@@ -679,6 +741,12 @@ class BatchDispatcher:
 
     def _launch(self, batch: List[WorkItem]) -> None:
         """Launch on the collector thread, hand to the completer."""
+        self._launch_seq += 1
+        launch_id = self._launch_seq
+        with SPANS.span(_spans.LAUNCH, self.launch_bank, launch_id):
+            self._launch_batch(batch, launch_id)
+
+    def _launch_batch(self, batch: List[WorkItem], launch_id: int) -> None:
         lanes_total = None
         if self.batch_lanes_hist is not None:
             # One observe per LAUNCH (not per item): a bisect + adds
@@ -704,7 +772,9 @@ class BatchDispatcher:
                     corr = it.corr
             if oldest:
                 queue_wait = t0 - oldest
-        token = submit_items(self.engine, batch, self._launch_watch)
+        engine = self.engine
+        stamps = LaunchStamps(self.launch_bank, launch_id)
+        token = submit_items(engine, batch, self._launch_watch, stamps)
         if token is _SUBMIT_FAILED:
             if lr is not None:
                 lr.record(
@@ -712,12 +782,15 @@ class BatchDispatcher:
                     self.launch_algo,
                     lanes_total,
                     len(batch),
-                    int(getattr(self.engine, "stat_dedup_groups", 0)),
+                    int(getattr(engine, "stat_dedup_groups", 0)),
                     queue_wait,
                     time.monotonic_ns() - t0,
                     0,
                     OUTCOME_FAULT,
                     corr,
+                    launch_id,
+                    int(getattr(engine, "stat_assign_ns", 0)),
+                    int(getattr(engine, "stat_device_submit_ns", 0)),
                 )
             self._note_step(False)
         elif token is not None:
@@ -726,15 +799,23 @@ class BatchDispatcher:
                 # per "batch" completion, and this append happens
                 # strictly before the matching _put_completion — one
                 # producer (collector), one consumer (completer), both
-                # FIFO, so entry k always meets its own batch.
+                # FIFO, so entry k always meets its own batch.  The
+                # last field is the instant the step was in flight
+                # (submit_items' own stamp): the completer's start
+                # minus it is handoff_ns.
+                t_launched = stamps.launched_ns
                 self._launch_meta.append(  # tpu-lint: disable=shared-state -- deque append/popleft are GIL-atomic; one FIFO producer (collector) and one FIFO consumer (completer)
                     (
                         lanes_total,
                         len(batch),
-                        int(getattr(self.engine, "stat_dedup_groups", 0)),
+                        int(getattr(engine, "stat_dedup_groups", 0)),
                         queue_wait,
-                        time.monotonic_ns() - t0,
+                        t_launched - t0,
                         corr,
+                        launch_id,
+                        int(getattr(engine, "stat_assign_ns", 0)),
+                        int(getattr(engine, "stat_device_submit_ns", 0)),
+                        t_launched,
                     )
                 )
             with self._state_lock:
@@ -852,7 +933,8 @@ class BatchDispatcher:
                     self._next_gc_monotonic = (
                         time.monotonic() + self.gc_interval_s
                     )
-                    self.engine.gc(self._last_item_now)
+                    with SPANS.span(_spans.GC, self.launch_bank):
+                        self.engine.gc(self._last_item_now)
                 for t in tokens:
                     if isinstance(t, _CallToken):
                         # Calls (checkpoints) run HERE — the collector
@@ -874,8 +956,10 @@ class BatchDispatcher:
 
     def _complete_loop(self) -> None:
         try:
+            span = SPANS.span
             while True:
-                kind, payload, token = self._completion_q.get()
+                with span(_spans.COMPLETE_IDLE, self.launch_bank):
+                    kind, payload, token = self._completion_q.get()
                 if kind == "stop":
                     return
                 if kind == "token":
@@ -883,8 +967,9 @@ class BatchDispatcher:
                 else:
                     lr = self.launches
                     t0 = time.monotonic_ns() if lr is not None else 0
+                    engine = self.engine
                     ok = complete_items(
-                        self.engine, payload, token, self._complete_watch
+                        engine, payload, token, self._complete_watch
                     )
                     if lr is not None:
                         try:
@@ -893,7 +978,7 @@ class BatchDispatcher:
                             # Recorder attached between this batch's
                             # launch and its completion: no front-half
                             # measurements, still one record.
-                            meta = (0, len(payload), 0, 0, 0, 0)
+                            meta = (0, len(payload), 0, 0, 0, 0, 0, 0, 0, t0)
                         lr.record(
                             self.launch_bank,
                             self.launch_algo,
@@ -905,6 +990,12 @@ class BatchDispatcher:
                             time.monotonic_ns() - t0,
                             OUTCOME_OK if ok else OUTCOME_FAULT,
                             meta[5],
+                            meta[6],
+                            meta[7],
+                            meta[8],
+                            t0 - meta[9],
+                            int(getattr(engine, "stat_readback_ns", 0)),
+                            int(getattr(engine, "stat_decide_ns", 0)),
                         )
                     with self._state_lock:
                         self._inflight -= 1
@@ -912,12 +1003,16 @@ class BatchDispatcher:
         except BaseException as e:  # noqa: BLE001 — liveness boundary
             self._die(e)
 
-    @staticmethod
-    def _run_call(t: "_CallToken") -> None:
-        try:
-            t.fn()
-        except BaseException as e:
-            t.error = e
+    def _run_call(self, t: "_CallToken") -> None:
+        # Background work ON the collector: snapshot and checkpoint
+        # grabs come through here, and nothing launches while they run
+        # — so each leaves its duration in the journal, and stands in
+        # the open-work map a hang fault copies (observability/spans).
+        with SPANS.background(_spans.CALL_TOKEN, self.launch_bank):
+            try:
+                t.fn()
+            except BaseException as e:
+                t.error = e
         t.event.set()
 
     def _drain(self) -> None:

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import contextlib
 import logging
+import time
 from dataclasses import dataclass
 from typing import Callable, List, Optional, Sequence
 
@@ -17,6 +18,8 @@ import jax
 import numpy as np
 
 from ..models.fixed_window import DeviceBatch, FixedWindowModel
+from ..observability import spans as _spans
+from ..observability.spans import SPANS
 
 logger = logging.getLogger("ratelimit.engine")
 
@@ -65,12 +68,18 @@ class CallWatch:
     (CounterEngine._device_call), so host work — slot assignment, a
     table rehash, the decide pass — never runs on the deadline's
     clock.  One writer (the thread making the calls); read lock-free
-    by the watchdog and by waiting RPCs (BatchDispatcher.stuck_age)."""
+    by the watchdog and by waiting RPCs (BatchDispatcher.stuck_age).
 
-    __slots__ = ("since", "_now")
+    ``last_leg`` is the thread's last COMPLETED leg — (span name,
+    monotonic_ns it ended, its duration in ns) — which a hang fault
+    copies beside the open background work: what the thread had just
+    done when its device call stopped returning."""
+
+    __slots__ = ("since", "last_leg", "_now")
 
     def __init__(self, now: Callable[[], float]):
         self.since: Optional[float] = None
+        self.last_leg: Optional[tuple] = None
         self._now = now
 
     def begin(self, armed: bool) -> None:
@@ -464,6 +473,16 @@ class CounterEngine:
         # discipline — written at the end of each submit, read by the
         # dispatcher collector immediately after submit returns).
         self.stat_dedup_groups = 0
+        # The legs of the LAST submit and the LAST completion, in ns,
+        # measured where they happen (the launch record's assign_ns /
+        # device_submit_ns / readback_ns / decide_ns): the first two
+        # written by the submitting thread and read by it right after
+        # submit_packed returns, the last two by the completing thread
+        # likewise after step_complete.
+        self.stat_assign_ns = 0
+        self.stat_device_submit_ns = 0
+        self.stat_readback_ns = 0
+        self.stat_decide_ns = 0
         # Fresh slot sightings = window rollovers: a key entering a
         # new window is a new cache key whose first batch appearance
         # carries fresh=1 (the lazy-expiry seam).  Counted per dedup
@@ -626,53 +645,62 @@ class CounterEngine:
         # assign-whole-batch-then-step ordering; a mid-batch failure
         # after partial commits would double-count on client retry).
         dedups: List[tuple] = []
+        self.stat_device_submit_ns = 0  # tpu-lint: disable=shared-state -- collector-owned engine
+        t_assign = time.monotonic_ns()
         try:
-            if fused:
-                for start in range(0, n, self.max_batch):
-                    count = min(n - start, self.max_batch)
-                    end = start + count
-                    bl = (
-                        blob_arr[offs[start] : offs[end]]
-                        if multi_fused
-                        else blob_arr
-                    )
-                    inv, uniq, totals, prefix, freshg, limitmax = (
-                        table.assign_dedup_packed(
-                            bl,
-                            key_lens[start:end],
-                            now,
-                            expiries[start:end],
+            with SPANS.span(_spans.LAUNCH_ASSIGN):
+                if fused:
+                    for start in range(0, n, self.max_batch):
+                        count = min(n - start, self.max_batch)
+                        end = start + count
+                        bl = (
+                            blob_arr[offs[start] : offs[end]]
+                            if multi_fused
+                            else blob_arr
+                        )
+                        inv, uniq, totals, prefix, freshg, limitmax = (
+                            table.assign_dedup_packed(
+                                bl,
+                                key_lens[start:end],
+                                now,
+                                expiries[start:end],
+                                hits[start:end],
+                                limits[start:end],
+                            )
+                        )
+                        dedup = _Dedup(
+                            uniq_slots=uniq,
+                            inv=inv,
+                            totals=totals,
+                            prefix=prefix,
+                            fresh=freshg,
+                            limit_max=limitmax,
+                        )
+                        dedups.append((start, count, dedup))
+                else:
+                    keys = _decode_keys(key_blob, key_lens)
+                    slots64, fresh = table.assign_batch(keys, now, expiries)
+                    slots = slots64.astype(np.int32)
+                    for start in range(0, n, self.max_batch):
+                        count = min(n - start, self.max_batch)
+                        end = start + count
+                        dedup = _dedup_chunk(
+                            slots[start:end],
                             hits[start:end],
                             limits[start:end],
+                            fresh[start:end],
+                            None if dividers is None else dividers[start:end],
                         )
-                    )
-                    dedup = _Dedup(
-                        uniq_slots=uniq,
-                        inv=inv,
-                        totals=totals,
-                        prefix=prefix,
-                        fresh=freshg,
-                        limit_max=limitmax,
-                    )
-                    dedups.append((start, count, dedup))
-            else:
-                keys = _decode_keys(key_blob, key_lens)
-                slots64, fresh = table.assign_batch(keys, now, expiries)
-                slots = slots64.astype(np.int32)
-                for start in range(0, n, self.max_batch):
-                    count = min(n - start, self.max_batch)
-                    end = start + count
-                    dedup = _dedup_chunk(
-                        slots[start:end],
-                        hits[start:end],
-                        limits[start:end],
-                        fresh[start:end],
-                        None if dividers is None else dividers[start:end],
-                    )
-                    dedups.append((start, count, dedup))
+                        dedups.append((start, count, dedup))
         finally:
             if multi_fused:
                 table.end_batch()
+        t_assigned = time.monotonic_ns()
+        self.stat_assign_ns = t_assigned - t_assign  # tpu-lint: disable=shared-state -- collector-owned engine
+        if watch is not None:
+            watch.last_leg = (
+                _spans.LAUNCH_ASSIGN, t_assigned, t_assigned - t_assign
+            )
         # Phase 2 — launch the device step per chunk.
         for start, count, dedup in dedups:
             afters_dev, reassemble, shape = self._device_submit(
@@ -697,20 +725,24 @@ class CounterEngine:
         engine state it touches is the proven-shape set, which it
         grows).  `watch` sees each readback wait begin and end."""
         hits, limits, shadow, chunks, now = token
+        self.stat_readback_ns = 0  # tpu-lint: disable=shared-state -- one completing thread per engine; the submitting thread never touches these two
+        decide_ns = 0
         if not chunks:
+            self.stat_decide_ns = 0  # tpu-lint: disable=shared-state -- same single completing thread
             empty = np.zeros(0, dtype=np.int32)
             return HostDecisions(*([empty] * 8), empty.astype(bool))
         outs: List[HostDecisions] = []
         for afters_dev, start, count, dedup, reassemble, shape in chunks:
-            with self._device_call(watch, shape):
+            with self._device_call(watch, shape, _spans.COMPLETE_READBACK):
                 fetched = jax.device_get(afters_dev)
             self._proven_shapes.add(shape)  # tpu-lint: disable=shared-state -- set.add/`in` are GIL-atomic; a racing reader only sees a shape as cold once more
             if reassemble is not None:
                 fetched = reassemble(np.asarray(fetched))
             end = start + count
-            if self._generic:
-                outs.append(
-                    self._decide_generic(
+            t_decide = time.monotonic_ns()
+            with SPANS.span(_spans.COMPLETE_DECIDE):
+                if self._generic:
+                    out = self._decide_generic(
                         np.asarray(fetched),
                         hits[start:end],
                         limits[start:end],
@@ -718,18 +750,23 @@ class CounterEngine:
                         dedup,
                         now,
                     )
+                else:
+                    out = _decide_host(
+                        fetched,
+                        hits[start:end],
+                        limits[start:end],
+                        shadow[start:end],
+                        self.model.near_ratio,
+                        dedup,
+                    )
+            outs.append(out)
+            t_decided = time.monotonic_ns()
+            decide_ns += t_decided - t_decide
+            if watch is not None:
+                watch.last_leg = (
+                    _spans.COMPLETE_DECIDE, t_decided, t_decided - t_decide
                 )
-                continue
-            outs.append(
-                _decide_host(
-                    fetched,
-                    hits[start:end],
-                    limits[start:end],
-                    shadow[start:end],
-                    self.model.near_ratio,
-                    dedup,
-                )
-            )
+        self.stat_decide_ns = decide_ns  # tpu-lint: disable=shared-state -- same single completing thread
         if len(outs) == 1:
             return outs[0]
         return HostDecisions(
@@ -740,19 +777,35 @@ class CounterEngine:
         )
 
     @contextlib.contextmanager
-    def _device_call(self, watch: Optional[CallWatch], shape: tuple):
+    def _device_call(
+        self,
+        watch: Optional[CallWatch],
+        shape: tuple,
+        leg: str = _spans.LAUNCH_DEVICE_CALL,
+    ):
         """Bracket one device interaction — the launch of kernel
-        `shape`, or the readback of one — for the kernel watchdog:
+        `shape` (`leg` rl.launch.device_call), or the readback of one
+        (rl.complete.readback) — for the kernel watchdog:
         KERNEL_DEADLINE_S's clock runs only inside, and only when the
-        shape has completed before (see CallWatch)."""
-        if watch is None:
-            yield
-            return
-        watch.begin(shape in self._proven_shapes)
+        shape has completed before (see CallWatch).  The same bracket
+        is the span of that name and the launch record's
+        device_submit_ns / readback_ns: the watchdog's clock, the trace
+        and the record time one interval."""
+        t0 = time.monotonic_ns()
+        if watch is not None:
+            watch.begin(shape in self._proven_shapes)
         try:
-            yield
+            with SPANS.span(leg):
+                yield
         finally:
-            watch.end()
+            t1 = time.monotonic_ns()
+            if watch is not None:
+                watch.end()
+                watch.last_leg = (leg, t1, t1 - t0)
+            if leg is _spans.COMPLETE_READBACK:
+                self.stat_readback_ns += t1 - t0  # tpu-lint: disable=shared-state -- the completing thread's own field (step_complete)
+            else:
+                self.stat_device_submit_ns += t1 - t0  # tpu-lint: disable=shared-state -- the submitting thread's own field
 
     def _decide_generic(
         self,
@@ -788,21 +841,24 @@ class CounterEngine:
             # state layout, kernel math and host reconstruction.
             # Padding uses DISTINCT out-of-table slots with divider=1,
             # limit=1, hits=0 so pad lanes are inert.
-            pk = np.empty((5, padded), dtype=np.int32)
-            pk[0, :g] = dedup.uniq_slots
-            pk[1, :g] = dedup.totals_u32().view(np.int32)
-            pk[2, :g] = dedup.limit_max.view(np.int32)
-            pk[3, :g] = dedup.fresh
-            if dedup.divider_max is not None:
-                pk[4, :g] = dedup.divider_max.view(np.int32)
-            else:
-                pk[4, :g] = 1
-            if padded > g:
-                pk[0, g:] = np.arange(ns, ns + (padded - g), dtype=np.int64)
-                pk[1, g:] = 0
-                pk[2, g:] = 1
-                pk[3, g:] = 0
-                pk[4, g:] = 1
+            with SPANS.span(_spans.LAUNCH_PACK):
+                pk = np.empty((5, padded), dtype=np.int32)
+                pk[0, :g] = dedup.uniq_slots
+                pk[1, :g] = dedup.totals_u32().view(np.int32)
+                pk[2, :g] = dedup.limit_max.view(np.int32)
+                pk[3, :g] = dedup.fresh
+                if dedup.divider_max is not None:
+                    pk[4, :g] = dedup.divider_max.view(np.int32)
+                else:
+                    pk[4, :g] = 1
+                if padded > g:
+                    pk[0, g:] = np.arange(
+                        ns, ns + (padded - g), dtype=np.int64
+                    )
+                    pk[1, g:] = 0
+                    pk[2, g:] = 1
+                    pk[3, g:] = 0
+                    pk[4, g:] = 1
             shape = (padded,)
             with self._device_call(watch, shape):
                 self._counts, out_dev = self.model.step_serve_packed(
@@ -838,16 +894,19 @@ class CounterEngine:
             # (u32 bit-pattern), limits (u32 bit-pattern), fresh.
             # Padding uses DISTINCT out-of-table slots (num_slots + i)
             # so the unique_indices scatter promise holds.
-            pk = np.empty((4, padded), dtype=np.int32)
-            pk[0, :g] = dedup.uniq_slots
-            pk[1, :g] = dedup.totals_u32().view(np.int32)
-            pk[2, :g] = dedup.limit_max.view(np.int32)
-            pk[3, :g] = dedup.fresh
-            if padded > g:
-                pk[0, g:] = np.arange(ns, ns + (padded - g), dtype=np.int64)
-                pk[1, g:] = 0
-                pk[2, g:] = 1
-                pk[3, g:] = 0
+            with SPANS.span(_spans.LAUNCH_PACK):
+                pk = np.empty((4, padded), dtype=np.int32)
+                pk[0, :g] = dedup.uniq_slots
+                pk[1, :g] = dedup.totals_u32().view(np.int32)
+                pk[2, :g] = dedup.limit_max.view(np.int32)
+                pk[3, :g] = dedup.fresh
+                if padded > g:
+                    pk[0, g:] = np.arange(
+                        ns, ns + (padded - g), dtype=np.int64
+                    )
+                    pk[1, g:] = 0
+                    pk[2, g:] = 1
+                    pk[3, g:] = 0
             shape = (padded, dt)
             with self._device_call(watch, shape):
                 self._counts, afters_dev = (

@@ -57,6 +57,7 @@ from typing import Callable, List, Optional
 import numpy as np
 
 from ..observability.launches import OUTCOME_FALLBACK
+from ..observability.spans import BG_SNAPSHOT, BG_WATCHDOG_TICK, SPANS
 from ..utils.time import REAL_MONOTONIC, MonotonicClock
 from .host_engine import STATIC_ALLOW, STATIC_DENY, HostEngine
 
@@ -144,6 +145,7 @@ class BankRecord:
         "next_snapshot",
         "fault_kind",
         "fault_error",
+        "last_hang",
         "quarantined_at",
         "next_restart",
         "backoff_s",
@@ -164,6 +166,12 @@ class BankRecord:
         self.next_snapshot = 0.0
         self.fault_kind: Optional[str] = None
         self.fault_error: Optional[str] = None
+        # The bank's LAST hang fault with what it found beside it
+        # (record_fault): {"error", "at_mono_ns", "during": the open
+        # background work and both dispatcher threads' device-call
+        # state}.  Kept after re-admission — a restart takes two
+        # seconds, an operator longer; the journal holds every one.
+        self.last_hang: Optional[dict] = None
         self.quarantined_at: Optional[float] = None
         self.next_restart = 0.0
         self.backoff_s = 0.0
@@ -338,6 +346,18 @@ class DeviceFaultDomain:
         schedule the supervised restart."""
         rec = self._records[bank]
         engine = self._engines[bank]
+        during = None
+        if kind == FAULT_HANG:
+            # Taken FIRST, while whatever the device call is waiting
+            # behind may still be open.  The step takes microseconds on
+            # the device; a call "stuck" for seconds beside an open
+            # snapshot, capture or call token says the host was late,
+            # not the device.
+            at_ns = time.monotonic_ns()
+            during = {"background": SPANS.open_work(at_ns)}
+            d = self.cache._dispatchers.get(id(engine))
+            if d is not None:
+                during["threads"] = d.watch_report()
         with self._lock:
             if rec.state != "closed":
                 return
@@ -361,6 +381,12 @@ class DeviceFaultDomain:
                 rec.fallback = host
             rec.fault_kind = kind
             rec.fault_error = repr(exc) if exc is not None else None
+            if during is not None:
+                rec.last_hang = {
+                    "error": rec.fault_error,
+                    "at_mono_ns": at_ns,
+                    "during": during,
+                }
             rec.quarantined_at = now
             rec.backoff_s = self.restart_backoff_s
             rec.next_restart = now + rec.backoff_s
@@ -378,6 +404,7 @@ class DeviceFaultDomain:
                     kind=kind,
                     error=rec.fault_error,
                     failure_mode=self.failure_mode,
+                    **({"during": during} if during is not None else {}),
                 )
             rec.state = "quarantined"
         d = self.cache._dispatchers.get(id(engine))
@@ -502,9 +529,13 @@ class DeviceFaultDomain:
             grabbed["snap"] = snapshot_engine(engine)
 
         try:
-            d.run_on_thread(
-                grab, timeout=max(1.0, 4.0 * self.kernel_deadline_s)
-            )
+            # From asking for the token to holding the copy: the wait
+            # behind the collector's queue AND the grab on it (which is
+            # rl.call_token there).
+            with SPANS.background(BG_SNAPSHOT, bank):
+                d.run_on_thread(
+                    grab, timeout=max(1.0, 4.0 * self.kernel_deadline_s)
+                )
         except TimeoutError:
             logger.warning(
                 "bank %d: snapshot token not served in time (queue "
@@ -708,7 +739,8 @@ class DeviceFaultDomain:
     def _loop(self) -> None:
         while not self._stop.wait(self.interval_s):
             try:
-                self.tick()
+                with SPANS.background(BG_WATCHDOG_TICK):
+                    self.tick()
             except Exception:
                 logger.exception("device-supervisor tick failed")
 
@@ -749,6 +781,8 @@ class DeviceFaultDomain:
                 "has_snapshot": rec.snapshot is not None,
                 **self._engines[rec.bank].placement(),
             }
+            if rec.last_hang is not None:
+                b["last_hang"] = rec.last_hang
             if rec.state != "closed":
                 b["fault_kind"] = rec.fault_kind
                 b["fault_error"] = rec.fault_error
@@ -773,5 +807,8 @@ class DeviceFaultDomain:
             "probe_failures": self.stat_probe_failures,
             "snapshots": self.stat_snapshots,
             "quarantined_banks": self.quarantined_count(),
+            # What runs beside serving (observability/spans.py): open
+            # now, and per activity the time and count since start.
+            "background": SPANS.summary(),
             "banks": banks,
         }

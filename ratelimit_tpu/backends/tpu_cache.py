@@ -899,6 +899,7 @@ class TpuRateLimitCache:
             limits, items, statuses, categories, hits_addend, now,
             len(request.descriptors),
             deadline=request.deadline,
+            request=request,
         )
 
     def do_limit_resolved(self, request: RateLimitRequest, config):
@@ -925,6 +926,7 @@ class TpuRateLimitCache:
             limits, items, statuses, categories, hits_addend, now,
             len(request.descriptors),
             deadline=request.deadline,
+            request=request,
         )
         if hot is not None:
             self._note_hotkey_outcomes(hot, statuses, limits, hits_addend)
@@ -1007,11 +1009,13 @@ class TpuRateLimitCache:
         now: int,
         n: int,
         deadline: Optional[float] = None,
+        request: Optional[RateLimitRequest] = None,
     ) -> List[DescriptorStatus]:
         """The device half: submit every bank's WorkItem, wait —
         bounded by the dispatch timeout and the caller's remaining RPC
         deadline (`deadline`, absolute time.monotonic seconds) — then
-        fill the non-engine categories.
+        fill the non-engine categories.  `request`, when given, takes
+        the request's legs away with it (`RateLimitRequest.legs`).
 
         Quarantined banks never reach the device: their items answer
         from the DEVICE_FAILURE_MODE fallback (fault_domain
@@ -1023,13 +1027,11 @@ class TpuRateLimitCache:
         (kernel_deadline_s=0) device errors raise CacheError exactly
         as before."""
         n_lanes = len(self.lanes)
-        # When this request's trace is recording, stamp each item's
-        # dispatcher passage (submit here; launch/complete on the
-        # dispatcher threads via the WorkItem trace seam) and convert
-        # the stamps to spans after wait() — see _record_item_spans.
+        # When this request's trace is recording, the items' always-on
+        # stamps (submit here; launched/signal on the dispatcher
+        # threads; woke in wait()) become spans after the waits — see
+        # _record_item_spans.
         span = TRACER.current()
-        labels = self._bank_labels
-        n_labels = len(labels)
         fd = self.fault_domain
         # One thread-local read per REQUEST (not per item): the launch
         # recorder joins a slow launch back to the request rings via
@@ -1050,18 +1052,6 @@ class TpuRateLimitCache:
         # overlap (the reference likewise pipelines both Redis clients
         # before the first PipeDo, fixed_cache_impl.go:77-95).
         for bank, engine, item in prep_items:
-            if span is not None:
-                item.trace = {
-                    # Banks past the static label table (override
-                    # banks) format their label in _bank_label — off
-                    # this loop body, and only on that rare leg.
-                    "bank": (
-                        labels[bank]
-                        if bank < n_labels
-                        else self._bank_label(bank)
-                    ),
-                    "submit": time.perf_counter(),
-                }
             if fd is not None:
                 if fd.is_quarantined(bank) and fd.run_fallback(bank, item):
                     self._note_fallback()
@@ -1089,9 +1079,13 @@ class TpuRateLimitCache:
                 continue
             pending.append((bank, d, item))
         for bank, engine, item in inline:
+            item.submit_ns = time.monotonic_ns()
             with self._inline_locks[id(engine)]:
                 run_items(engine, [item])
             pending.append((bank, None, item))
+        # prepare_ms ends here: everything this request sends to a
+        # device bank is queued.
+        submitted_ns = time.monotonic_ns()
         for bank, d, item in pending:
             timeout = self.dispatch_timeout_s
             caller_bound = False
@@ -1150,8 +1144,22 @@ class TpuRateLimitCache:
                 # op (no check-then-act; see _pool_event); the 1024
                 # bound is advisory — an overshoot wastes an Event.
                 pool.append(item.event)  # tpu-lint: disable=shared-state -- GIL-atomic list ops; pop is EAFP in _pool_event
+        if request is not None:
+            # The request's legs for the handler's histograms
+            # (ServerReporter.observe_legs): everything queued, then —
+            # of the item whose launch was signalled LAST, the one the
+            # answer waited for — the completer's signal and the
+            # moment this thread was running again.  0, 0 where no
+            # item rode a launch (fallback answers, cache-only
+            # requests).
+            signal_ns = woke_ns = 0
+            for _bank, _engine, item in prep_items:
+                stamps = item.launch
+                if stamps is not None and stamps.signal_ns > signal_ns:
+                    signal_ns, woke_ns = stamps.signal_ns, item.woke_ns
+            request.legs = (submitted_ns, signal_ns, woke_ns)
         if span is not None:
-            self._record_item_spans(span, [it for _, _, it in prep_items])
+            self._record_item_spans(span, prep_items)
 
         # Non-engine categories.
         reset_cache: dict = {}
@@ -1544,34 +1552,55 @@ class TpuRateLimitCache:
         if fl is not None:
             fl.note_fallback()
 
-    @staticmethod
-    def _record_item_spans(span, items: List[WorkItem]) -> None:
-        """Turn each item's (submit, launch, complete) perf_counter
-        stamps into two child spans — ``backend.dispatch`` (intake
-        queue + collect + batch assembly, host-side) and
-        ``kernel.step`` (device launch through readback+decide) — on
-        the waiting RPC thread, after the completion event's
-        happens-before edge made the dispatcher threads' stamps
-        visible.  Failed steps leave stamps missing; record what
-        exists."""
-        for item in items:
-            tr = item.trace
-            if tr is None:
+    def _record_item_spans(self, span, prep_items) -> None:
+        """Turn each item's always-on stamps (submit, launched, signal,
+        woke — time.monotonic_ns, see dispatcher.LaunchStamps) into
+        child spans — ``backend.dispatch`` (intake queue + collect +
+        batch assembly, host-side), ``kernel.step`` (device launch
+        through readback+decide) and ``wake`` (the completer's signal
+        until this thread ran again) — on the waiting RPC thread, after
+        the completion event's happens-before edge made the dispatcher
+        threads' stamps visible.  Failed steps leave stamps at 0;
+        record what exists."""
+        # Spans live on perf_counter; the stamps are CLOCK_MONOTONIC.
+        # One offset, read here, moves them across.
+        off = time.perf_counter() - time.monotonic_ns() * 1e-9
+        labels = self._bank_labels
+        record_span = TRACER.record_span
+        for bank, _engine, item in prep_items:
+            stamps = item.launch
+            if stamps is None or not stamps.launched_ns or not item.submit_ns:
                 continue
-            launch = tr.get("launch")
-            complete = tr.get("complete")
-            attrs = {"bank": tr["bank"], "lanes": item.n_lanes}
-            if launch is not None:
-                TRACER.record_span(
-                    "backend.dispatch",
-                    tr["submit"],
-                    launch,
-                    attrs=attrs,
-                    parent=span,
+            attrs = {
+                # Banks past the static label table (override banks)
+                # format their label in _bank_label.
+                "bank": (
+                    labels[bank]
+                    if bank < len(labels)
+                    else self._bank_label(bank)
+                ),
+                "lanes": item.n_lanes,
+            }
+            launched = stamps.launched_ns * 1e-9 + off
+            record_span(
+                "backend.dispatch",
+                item.submit_ns * 1e-9 + off,
+                launched,
+                attrs=attrs,
+                parent=span,
+            )
+            if stamps.signal_ns:
+                signal = stamps.signal_ns * 1e-9 + off
+                record_span(
+                    "kernel.step", launched, signal, attrs=attrs, parent=span
                 )
-                if complete is not None:
-                    TRACER.record_span(
-                        "kernel.step", launch, complete, attrs=attrs, parent=span
+                if item.woke_ns:
+                    record_span(
+                        "wake",
+                        signal,
+                        item.woke_ns * 1e-9 + off,
+                        attrs=attrs,
+                        parent=span,
                     )
 
     def _make_item(

@@ -18,6 +18,9 @@
                   dispatch timeline (``GET /debug/launches``).
 - ``timeseries``: in-process bounded time-series store — capacity /
                   latency history (``GET /debug/timeseries``).
+- ``spans``:      the program's ``rl.*`` spans in the profiler's own
+                  trace (``GET /debug/xla_trace``), and the durations
+                  background work leaves (``SPANS``).
 """
 
 from .detectors import (

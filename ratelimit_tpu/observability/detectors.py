@@ -44,6 +44,7 @@ from typing import Callable, Dict, List, Optional
 
 from ..stats.manager import StatsStore
 from ..utils.time import MonotonicClock, REAL_MONOTONIC
+from .spans import BG_DETECTOR_TICK, BG_INCIDENT_CAPTURE, SPANS
 
 logger = logging.getLogger("ratelimit.detectors")
 
@@ -342,7 +343,14 @@ class AnomalyDetectors:
         return captured
 
     def _capture(self, detector: str, reason: str) -> dict:
-        """Snapshot the black box NOW, on the sampler thread."""
+        """Snapshot the black box NOW, on the sampler thread.  Copying
+        every counter holds the GIL against the serving threads for as
+        long as it takes, so the capture is background work with a
+        duration (rl.bg.incident_capture)."""
+        with SPANS.background(BG_INCIDENT_CAPTURE):
+            return self._capture_now(detector, reason)
+
+    def _capture_now(self, detector: str, reason: str) -> dict:
         seq = next(self._seq)
         incident = {
             "id": f"incident-{seq:06d}-{detector}",
@@ -463,6 +471,7 @@ class AnomalyDetectors:
     def _loop(self) -> None:
         while not self._stop.wait(self.interval_s):
             try:
-                self.tick()
+                with SPANS.background(BG_DETECTOR_TICK):
+                    self.tick()
             except Exception:
                 logger.exception("anomaly sampler tick failed")

@@ -20,7 +20,13 @@ existing lifecycle seams:
   the proxy's ReplicaRouter / RouterHolder (cluster/{router,proxy}.py);
 - ``config_reload`` — RateLimitService adopting a new config
   generation (service/ratelimit.py);
-- ``incident`` — AnomalyDetectors captures (observability/detectors.py).
+- ``incident`` — AnomalyDetectors captures (observability/detectors.py);
+- ``background_work`` — one per background activity when it ENDS
+  (observability/spans.py ``SPANS.background``): ``what`` (the span
+  name: rl.bg.snapshot, rl.bg.checkpoint, rl.bg.incident_capture,
+  rl.call_token; a periodic tick only when it ran 10 ms or more),
+  ``bank``, ``thread``, ``start_mono_ns``, ``duration_ms`` — what ran
+  beside serving, for how long, on which thread.
 
 Emission is COLD-path by construction: every seam above is a state
 *transition* (quarantine entry, floor move, circuit open), never a
@@ -76,6 +82,7 @@ EVENT_TYPES = (
     "replica_readmit",
     "config_reload",
     "incident",
+    "background_work",
 )
 
 _KNOWN = frozenset(EVENT_TYPES)

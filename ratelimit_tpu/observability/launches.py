@@ -22,7 +22,12 @@ phase durations —
 - ``complete_ns``    readback wait + decide + scatter
   (complete_items duration on the completer thread);
 
-plus the outcome (ok / fault / fallback) and the correlation id of the
+the legs inside and between them, each measured where it happens —
+``assign_ns`` and ``device_submit_ns`` (inside launch_ns),
+``handoff_ns`` (launched -> the completer takes it up), ``readback_ns``
+and ``decide_ns`` (inside complete_ns) — the dispatcher's own
+``launch_id`` (the stat of the ``rl.launch`` span, observability/
+spans.py), plus the outcome (ok / fault / fallback) and the correlation id of the
 SLOWEST (longest-queued) item, so one grep joins a slow launch to the
 request rings and trace spans that rode it.
 
@@ -36,8 +41,9 @@ claim is ``next(itertools.count())`` (GIL-atomic), and validity is a
 seq-window check at read time — a slot is live iff its seq lies in
 ``(hwm - size, hwm]``.  Stamping runs on the dispatcher's collector /
 completer threads (never the RPC threads) at most once per LAUNCH, so
-the per-request amortized cost is launch-cost / items-per-batch; the
-measured number lives in benchmarks/results/launches_overhead.json.
+the per-request amortized cost is launch-cost / items-per-batch.  What
+one record costs on the chip's host: not measured (PERF.md section 5
+has what recorder, spans and leg counters cost together, end to end).
 
 ``LAUNCH_RECORDER_SIZE=0`` disables recording entirely: the runner
 builds no recorder, dispatchers keep ``launches=None``, and the
@@ -80,6 +86,18 @@ LAUNCH_DTYPE = np.dtype(
         ("complete_ns", np.int64),  # readback wait + decide + scatter
         ("outcome", np.int64),  # OUTCOME_OK / _FAULT / _FALLBACK
         ("corr", np.int64),  # corr id of the longest-queued item
+        # The legs inside launch_ns and complete_ns, measured where
+        # they happen and handed back by the engine (stat_*_ns) the way
+        # it hands back stat_dedup_groups; launch_ns and complete_ns
+        # keep their meanings, so launch_ns - assign_ns -
+        # device_submit_ns is concatenation + the numpy pack, and
+        # complete_ns - readback_ns - decide_ns is scatter + signalling.
+        ("launch_id", np.int64),  # the dispatcher's own count; rl.launch's stat
+        ("assign_ns", np.int64),  # native slot assign + dedup (collector)
+        ("device_submit_ns", np.int64),  # in the collector's device-call bracket
+        ("handoff_ns", np.int64),  # launch done -> the completer takes it up
+        ("readback_ns", np.int64),  # in the completer's device-call bracket
+        ("decide_ns", np.int64),  # host threshold machine (completer)
     ]
 )
 
@@ -154,6 +172,12 @@ class LaunchRecorder:
             complete_ns: int,
             outcome: int,
             corr: int = 0,
+            launch_id: int = 0,
+            assign_ns: int = 0,
+            device_submit_ns: int = 0,
+            handoff_ns: int = 0,
+            readback_ns: int = 0,
+            decide_ns: int = 0,
         ) -> None:
             """Stamp one launch (collector / completer thread)."""
             i = next(counter)
@@ -172,6 +196,12 @@ class LaunchRecorder:
                 complete_ns,
                 outcome,
                 corr,
+                launch_id,
+                assign_ns,
+                device_submit_ns,
+                handoff_ns,
+                readback_ns,
+                decide_ns,
             )
             if algo in items_by_algo:
                 items_by_algo[algo] += items
@@ -218,7 +248,8 @@ class LaunchRecorder:
         for rec in live.tolist():
             (
                 seq, ts_ns, bank, algo, lanes, items, dedup, queue_wait,
-                launch, complete, outcome, corr,
+                launch, complete, outcome, corr, launch_id, assign,
+                device_submit, handoff, readback, decide,
             ) = rec
             d = {
                 "seq": seq,
@@ -232,6 +263,12 @@ class LaunchRecorder:
                 "launch_us": round(launch / 1e3, 1),
                 "complete_us": round(complete / 1e3, 1),
                 "outcome": _OUTCOME_NAMES.get(outcome, str(outcome)),
+                "launch_id": launch_id,
+                "assign_us": round(assign / 1e3, 1),
+                "device_submit_us": round(device_submit / 1e3, 1),
+                "handoff_us": round(handoff / 1e3, 1),
+                "readback_us": round(readback / 1e3, 1),
+                "decide_us": round(decide / 1e3, 1),
             }
             if corr:
                 # Longest-queued item's cross-hop id, hex16 like the

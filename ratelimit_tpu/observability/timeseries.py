@@ -44,6 +44,7 @@ import numpy as np
 
 from ..utils.time import MonotonicClock, REAL_MONOTONIC
 from .detectors import quantile_from_counts
+from .spans import BG_TSDB_TICK, SPANS
 
 __all__ = ["TimeSeriesStore", "make_timeseries", "register_default_series"]
 
@@ -239,7 +240,8 @@ class TimeSeriesStore:
         log = logging.getLogger("ratelimit.tsdb")
         while not self._stop.wait(self.interval_s):
             try:
-                self.tick()
+                with SPANS.background(BG_TSDB_TICK):
+                    self.tick()
             except Exception:
                 log.exception("tsdb sampler tick failed")
 

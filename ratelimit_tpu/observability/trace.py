@@ -406,9 +406,10 @@ class Tracer:
         parent: Optional[Span] = None,
     ) -> None:
         """Append a span from explicit perf_counter stamps — the
-        cross-thread seam: the dispatcher stamps launch/complete into
-        the WorkItem trace dict, and the waiting handler thread turns
-        them into spans here after wait()."""
+        cross-thread seam: the dispatcher threads leave their always-on
+        stamps on the WorkItem (dispatcher.LaunchStamps), and the
+        waiting handler thread turns them into spans here after wait()
+        (tpu_cache._record_item_spans)."""
         p = parent if parent is not None else self._current.get()
         if p is None or not p.recording:
             return

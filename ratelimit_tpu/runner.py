@@ -351,6 +351,13 @@ class Runner:
             fd = getattr(self.cache, "fault_domain", None)
             if fd is not None:
                 fd.events = self.events
+        # Background work (snapshot, checkpoint, incident capture, the
+        # collector's call tokens) leaves its durations in the same
+        # journal (observability/spans.py).
+        from .observability.spans import SPANS
+
+        SPANS.journal = self.events
+        SPANS.watch_gc()
         self.slo = SloEngine(
             self.stats_manager,
             target=s.slo_target,
@@ -681,7 +688,12 @@ class Runner:
             TRACER.clear_exporters()
             self._trace_jsonl.close()
             self._trace_jsonl = None
+        from .observability.spans import SPANS
+
+        SPANS.watch_gc(False)
         if self.events is not None:
+            if SPANS.journal is self.events:
+                SPANS.journal = None
             self.events.close()
         self._stopped.set()
 

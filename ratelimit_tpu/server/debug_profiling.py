@@ -13,10 +13,14 @@ the equivalents here are:
   profile: samples ``sys._current_frames()`` at ``hz`` (default 100)
   for N seconds and reports self/cumulative sample counts per
   function — the pprof-CPU analog, sampling like pprof does.
-- ``GET /debug/xla_trace?seconds=N``  captures a ``jax.profiler``
-  trace (device + host timelines) into the artifacts dir and returns
-  the path — the per-batch XLA trace SURVEY section 5 prescribes;
-  open it with TensorBoard or Perfetto.
+- ``GET /debug/xla_trace?seconds=N``  captures a LIGHT
+  ``jax.profiler`` trace into the artifacts dir and returns the path:
+  the device's planes plus the program's own ``rl.*`` spans on the
+  host threads' lines (observability/spans.py), Python tracer off, so
+  serving carries on at its rate while it runs.  Two ``rl.clock.<ns>``
+  marks join the trace to CLOCK_MONOTONIC; the reply says how long the
+  profiler's start and stop each blocked.  Open it with TensorBoard or
+  Perfetto.  For Python-level frames use ``/debug/profile``.
 
 All three run against the LIVE serving process with no restart, which
 is the entire point (round-2 verdict weak #5: the serving process had
@@ -25,6 +29,7 @@ zero live introspection for host-side bottlenecks).
 
 from __future__ import annotations
 
+import logging
 import os
 import sys
 import tempfile
@@ -36,6 +41,9 @@ from typing import Optional
 from urllib.parse import parse_qs, urlsplit
 
 from ..analysis.sanitizer import allow_blocking
+from ..observability.spans import SPANS
+
+logger = logging.getLogger("ratelimit.debug")
 
 
 def threadz_text() -> str:
@@ -187,8 +195,6 @@ def add_profiling_routes(
             h._reply(409, b"a trace capture is already running\n")
             return
         try:
-            import jax
-
             trace_dir = os.path.join(
                 artifacts, f"xla_trace_{time.time_ns()}"
             )
@@ -196,9 +202,17 @@ def add_profiling_routes(
             with allow_blocking(
                 "one-capture-at-a-time gate; contenders get 409"
             ):
-                jax.profiler.start_trace(trace_dir)
-                time.sleep(seconds)
-                jax.profiler.stop_trace()
+                blocked = SPANS.capture(trace_dir, seconds)
+            # WARNING on purpose: a capture is a rare operator action,
+            # and how long the profiler's stop held the process is
+            # what the next reader of this log wants to know.
+            logger.warning(
+                "xla_trace capture of %.1fs: start_trace blocked %s ms, "
+                "stop_trace blocked %s ms",
+                seconds,
+                blocked["start_trace_ms"],
+                blocked["stop_trace_ms"],
+            )
             files = []
             for root, _dirs, names in os.walk(trace_dir):
                 for name in names:
@@ -208,6 +222,8 @@ def add_profiling_routes(
                     )
             status, body = 200, (
                 f"trace written to {trace_dir}\n"
+                f"start_trace blocked {blocked['start_trace_ms']} ms, "
+                f"stop_trace blocked {blocked['stop_trace_ms']} ms\n"
                 + "\n".join(sorted(files))
                 + "\nopen with: tensorboard --logdir <dir>  (or Perfetto)\n"
             ).encode()
@@ -278,7 +294,8 @@ ENDPOINT_BLURBS = {
         "statistical CPU profile ?seconds=N (DEBUG_PROFILING=1)"
     ),
     "/debug/xla_trace": (
-        "jax.profiler trace capture ?seconds=N (DEBUG_PROFILING=1)"
+        "light jax.profiler capture ?seconds=N: device planes + "
+        "rl.* program spans (DEBUG_PROFILING=1)"
     ),
 }
 

@@ -53,6 +53,12 @@ class ServerReporter:
         self._phase_service = store.histogram(base + ".phase.service_ms")
         self._phase_serialize = store.histogram(base + ".phase.serialize_ms")
         self._response = store.histogram(base + ".response_ms")
+        # The request's legs outside and inside the service phase, each
+        # measured where it happens (observe_legs).
+        self._pool_wait = store.histogram(base + ".pool_wait_ms")
+        self._prepare = store.histogram(base + ".prepare_ms")
+        self._wake = store.histogram(base + ".wake_ms")
+        self._apply = store.histogram(base + ".apply_ms")
 
     def observe(self, method: str, elapsed_s: float) -> None:
         base = f"{self.scope}.{method}"
@@ -69,6 +75,33 @@ class ServerReporter:
         self._phase_serialize.observe((serialized - serviced) * 1e3)
         self._response.observe((serialized - recv) * 1e3)
 
+    def observe_legs(
+        self, submitted_ns: int, entry_ns: int, service_in_ns: int,
+        service_out_ns: int, legs: Optional[tuple],
+    ) -> None:
+        """One call a request, all stamps ``time.monotonic_ns``:
+        ``pool_wait_ms`` — gRPC core handed the RPC to the executor
+        (`submitted_ns`, _StampingExecutor; 0 = not through it) until
+        the handler started (`entry_ns`): the wait for an RPC thread,
+        the request message's arrival and its parse.  Then, of the
+        service phase (`service_in_ns` .. `service_out_ns`) and the
+        backend's `legs` (api.RateLimitRequest.legs): ``prepare_ms`` —
+        resolution and packing on this thread, until everything is
+        queued for the device; ``wake_ms`` — the completer's signal
+        until this thread ran again; ``apply_ms`` — from there to the
+        service's return: slicing and status assembly.  Between
+        prepare and wake lie the launch record's queue_wait, launch,
+        handoff and complete."""
+        if submitted_ns:
+            self._pool_wait.observe((entry_ns - submitted_ns) * 1e-6)
+        if legs is None:
+            return
+        queued_ns, signal_ns, woke_ns = legs
+        self._prepare.observe((queued_ns - service_in_ns) * 1e-6)
+        if signal_ns:
+            self._wake.observe((woke_ns - signal_ns) * 1e-6)
+            self._apply.observe((service_out_ns - woke_ns) * 1e-6)
+
 
 # Optional per-RPC stage-timestamp sink (the transport half of the
 # pipeline trace, r4 VERDICT next #2): when set via set_stage_sink, the
@@ -81,6 +114,26 @@ class ServerReporter:
 # perf_counter is ~40ns, so always stamping costs less than branching
 # did.
 _stage_sink = [None]
+
+
+_pool_local = threading.local()
+
+
+def _run_stamped(submitted_ns: int, fn, *args, **kwargs):
+    _pool_local.submitted_ns = submitted_ns
+    return fn(*args, **kwargs)
+
+
+class _StampingExecutor(futures.ThreadPoolExecutor):
+    """The RPC thread pool, leaving each call the instant it was
+    submitted (thread-local, read once by the handler): with 32
+    workers and more callers than that, the wait for a worker is most
+    of what a client sees, and nothing else measures it from inside."""
+
+    def submit(self, fn, /, *args, **kwargs):
+        return super().submit(
+            _run_stamped, time.monotonic_ns(), fn, *args, **kwargs
+        )
 
 
 def set_stage_sink(fn) -> None:
@@ -109,6 +162,11 @@ def _ratelimit_handler(
     corr_on = bool(corr_enabled) and flight is not None
 
     def should_rate_limit(request_pb, context):
+        entry_ns = time.monotonic_ns()
+        # Read once and cleared: a handler called outside the pool
+        # (tests) must not inherit the previous call's stamp.
+        submitted_ns = getattr(_pool_local, "submitted_ns", 0)
+        _pool_local.submitted_ns = 0
         start = time.perf_counter()
         # Trace intake: an inbound W3C traceparent (Envoy and any OTel
         # client send one as plain metadata) adopts the caller's trace
@@ -146,6 +204,7 @@ def _ratelimit_handler(
                 if remaining is not None:
                     request.deadline = time.monotonic() + remaining
                 t_decoded = time.perf_counter()
+                service_in_ns = time.monotonic_ns()
                 try:
                     response = service.should_rate_limit(request)
                 except (ServiceError, CacheError) as e:
@@ -157,6 +216,7 @@ def _ratelimit_handler(
                         # event for its domain (observability/slo.py).
                         slo.observe_error(request.domain)
                     context.abort(grpc.StatusCode.UNKNOWN, str(e))
+                service_out_ns = time.monotonic_ns()
                 t_serviced = time.perf_counter()
                 # Serialize HERE on the handler thread (the method is
                 # registered with an identity response_serializer): the
@@ -183,6 +243,10 @@ def _ratelimit_handler(
                 if reporter is not None:
                     reporter.observe_phases(
                         start, t_decoded, t_serviced, t_serialized
+                    )
+                    reporter.observe_legs(
+                        submitted_ns, entry_ns, service_in_ns,
+                        service_out_ns, request.legs,
                     )
                 # Decision flight recorder + per-domain SLO rollups,
                 # stamped HERE next to the per-phase histogram sink:
@@ -365,7 +429,7 @@ def create_grpc_server(
     ]
     reporter = ServerReporter(store) if store is not None else None
     server = grpc.server(
-        futures.ThreadPoolExecutor(
+        _StampingExecutor(
             max_workers=max_workers, thread_name_prefix="grpc-rpc"
         ),
         options=options,

@@ -1,0 +1,114 @@
+"""chipbench/host_spans.py — the reduction from a light profiler trace
+(device planes + the program's rl.* spans) to "what the host was doing
+in each idle gap of the device": its own selftest cases, run here so
+tier-1 guards the arithmetic every later PR's numbers rest on."""
+
+import pytest
+
+from chipbench import host_spans
+
+
+@pytest.mark.parametrize(
+    "case", [host_spans.selftest_split, host_spans.selftest_recorded]
+)
+def test_host_spans_selftest(case):
+    case()
+
+
+def test_cut_keeps_what_the_reductions_read():
+    planes = host_spans.load(host_spans.TESTDATA)
+    whole = host_spans.attribute(planes)
+    again = host_spans.attribute(host_spans.cut(planes, 0.0, 3600.0))
+    assert again == whole
+    assert whole["clock"]["marks"] == 2
+
+
+# ---------------------------------------------------------------------------
+# the per-layer metrics PR 24 added: readers that exist, fed by what the
+# program gained — in BENCHMARK.json only where silent on a program that
+# lacks it
+# ---------------------------------------------------------------------------
+
+import json  # noqa: E402
+import os  # noqa: E402
+import re  # noqa: E402
+
+from chipbench import layers  # noqa: E402
+from chipbench.deploy import load_json  # noqa: E402
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# In BENCHMARK.json: read with the readers layers.py has, silent on an
+# older program.
+PR24 = ("pool_wait_ms", "prepare_us", "apply_us", "wake_us", "snapshot_ms")
+# Files ready, entries waiting (chipbench/README-pr24.md): the launch
+# legs need a `launches` reader that skips records without the field.
+LAUNCH_LEGS = ("assign_us", "device_submit_us", "readback_us", "decide_us", "handoff_us")
+HELD = LAUNCH_LEGS + ("incident_stall_ms",)
+H = "ratelimit_server.ShouldRateLimit."
+
+
+def _obs(with_new: bool) -> dict:
+    """Observations as run.py gathers them: a program before PR 24 has
+    response_ms, launch.rate, snapshots and the old launch fields only."""
+    def stats(n):
+        hist = {H + "response_ms": {"count": 10 * n, "total_ms": 40.0 * n}}
+        if with_new:
+            for leg, ms in (("pool_wait_ms", 5.0), ("prepare_ms", 4.0), ("wake_ms", 3.0), ("apply_ms", 2.0)):
+                hist[H + leg] = {"count": 10 * n, "total_ms": ms * n}
+        return {"histograms": hist, "stats": {"ratelimit.tpu.launch.rate": 9 * n}}
+
+    def faults(n):
+        doc = {"snapshots": 3 * n}
+        if with_new:
+            doc["background"] = {"total_ms": {"rl.bg.snapshot": 70.0 * n, "rl.bg.incident_capture": 0.0}}
+        return doc
+
+    launches = [{"launch_us": 1000.0 + i, "complete_us": 800.0} for i in range(4)]
+    if with_new:
+        for i, rec in enumerate(launches):
+            rec.update({leg: 100.0 * (k + 1) + i for k, leg in enumerate(LAUNCH_LEGS)})
+    return {
+        "stats_a": stats(1), "stats_b": stats(3), "faults_a": faults(1), "faults_b": faults(2),
+        "launches": launches,
+    }
+
+
+@pytest.mark.parametrize("suffix", [".paced", ""])
+@pytest.mark.parametrize("name", PR24 + HELD)
+def test_pr24_metric_reads_what_the_program_gained(name, suffix):
+    spec = load_json("layer_metrics", name + suffix)
+    assert set(spec) == {"what", "reader"}
+    want = {
+        "pool_wait_ms": 0.5, "prepare_us": 400.0, "wake_us": 300.0, "apply_us": 200.0,
+        "assign_us": 101.5, "device_submit_us": 201.5, "readback_us": 301.5,
+        "decide_us": 401.5, "handoff_us": 501.5, "incident_stall_ms": 0.0, "snapshot_ms": 70.0,
+    }[name]
+    assert layers.read(spec["reader"], _obs(with_new=True)) == pytest.approx(want)
+    if name in LAUNCH_LEGS:
+        # Why their entries wait: today's reader raises on an older
+        # program's records, and the driver's traced run of the parent
+        # reads every metric BENCHMARK.json lists.
+        assert spec["reader"] == {"kind": "launches", "field": name, "reduce": "mean"}
+        with pytest.raises(KeyError):
+            layers.read(spec["reader"], _obs(with_new=False))
+    else:
+        assert layers.read(spec["reader"], _obs(with_new=False)) is None
+
+
+def test_pr24_entries_are_appended_and_the_rest_wait_outside_the_benchmark():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    names = [m["name"] for m in bench["per_layer"]]
+    assert names[-len(PR24):] == [n + ".paced" for n in PR24]
+    assert not {n + s for n in HELD for s in ("", ".paced")} & set(names)
+    for m in bench["per_layer"][-len(PR24):]:
+        assert m["moves"] == "p50_ms" and m["workloads"] == ["tenants-zipf.paced"]
+    with open(os.path.join(ROOT, "chipbench", "README-pr24.md")) as f:
+        (block,) = re.findall(r"<!-- merge: BENCHMARK.json -->\n```json\n(.*?)```", f.read(), re.S)
+    twins = json.loads(block)["per_layer"]
+    assert [t["name"] for t in twins] == list(PR24)
+    paced = {m["name"]: m for m in bench["per_layer"]}
+    for t in twins:
+        p = paced[t["name"] + ".paced"]
+        assert (t["unit"], t["better"], t["source"], t["layer"]) == (p["unit"], p["better"], p["source"], p["layer"])
+        assert t["moves"] == "decisions_per_s"

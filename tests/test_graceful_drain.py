@@ -78,11 +78,22 @@ def test_sigterm_drain_completes_inflight_and_snapshots(tmp_path):
             except grpc.RpcError as e:  # pragma: no cover - failure detail
                 results["error"] = e
 
+    # Let the RPC reach the dispatcher intake (it then parks in the
+    # 150 ms batch window), then stop mid-flight.  Wait for the
+    # intake itself, not for a fixed time: on a loaded host a thread
+    # start plus a connect can take longer than any sleep chosen here,
+    # and an RPC that arrives after stop() began is rightly refused.
+    submitted = threading.Event()
+    for d in r.cache._dispatchers.values():
+
+        def submit(item, submit=d.submit):
+            submitted.set()
+            submit(item)
+
+        d.submit = submit
     t = threading.Thread(target=rpc)
     t.start()
-    # Let the RPC reach the dispatcher intake (it then parks in the
-    # 150 ms batch window), then stop mid-flight.
-    time.sleep(0.05)
+    assert submitted.wait(20)
     r.stop()
     t.join(timeout=20)
     assert not t.is_alive()
