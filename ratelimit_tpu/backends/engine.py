@@ -483,6 +483,11 @@ class CounterEngine:
         self.stat_device_submit_ns = 0
         self.stat_readback_ns = 0
         self.stat_decide_ns = 0
+        # Launches whose device step had finished (is_ready(), asked
+        # once before the wait) when the completer took them up: over
+        # the launch count, the share of launches whose device trip
+        # the hand-off hid.  Monotonic; the completing thread's own.
+        self.stat_readback_ready = 0
         # Fresh slot sightings = window rollovers: a key entering a
         # new window is a new cache key whose first batch appearance
         # carries fresh=1 (the lazy-expiry seam).  Counted per dedup
@@ -732,18 +737,22 @@ class CounterEngine:
             empty = np.zeros(0, dtype=np.int32)
             return HostDecisions(*([empty] * 8), empty.astype(bool))
         outs: List[HostDecisions] = []
+        ready = True
         for afters_dev, start, count, dedup, reassemble, shape in chunks:
+            ready = ready and afters_dev.is_ready()
             with self._device_call(watch, shape, _spans.COMPLETE_READBACK):
-                fetched = jax.device_get(afters_dev)
+                # The one intended device sync of serving: the wait for
+                # the copy the launch asked for (_device_submit).
+                fetched = np.asarray(afters_dev)
             self._proven_shapes.add(shape)  # tpu-lint: disable=shared-state -- set.add/`in` are GIL-atomic; a racing reader only sees a shape as cold once more
             if reassemble is not None:
-                fetched = reassemble(np.asarray(fetched))
+                fetched = reassemble(fetched)
             end = start + count
             t_decide = time.monotonic_ns()
             with SPANS.span(_spans.COMPLETE_DECIDE):
                 if self._generic:
                     out = self._decide_generic(
-                        np.asarray(fetched),
+                        fetched,
                         hits[start:end],
                         limits[start:end],
                         shadow[start:end],
@@ -767,6 +776,7 @@ class CounterEngine:
                     _spans.COMPLETE_DECIDE, t_decided, t_decided - t_decide
                 )
         self.stat_decide_ns = decide_ns  # tpu-lint: disable=shared-state -- same single completing thread
+        self.stat_readback_ready += ready  # tpu-lint: disable=shared-state -- same single completing thread
         if len(outs) == 1:
             return outs[0]
         return HostDecisions(
@@ -861,11 +871,12 @@ class CounterEngine:
                     pk[4, g:] = 1
             shape = (padded,)
             with self._device_call(watch, shape):
+                # np.int32, not the Python int: a weak-typed scalar
+                # would be another jit signature.
                 self._counts, out_dev = self.model.step_serve_packed(
-                    self._counts,
-                    jax.numpy.asarray(pk),
-                    jax.numpy.asarray(now, dtype=jax.numpy.int32),
+                    self._counts, pk, np.int32(now)
                 )
+                out_dev.copy_to_host_async()
             return out_dev, None, shape
         # Dtype choice uses the UNWRAPPED uint64 totals; totals past
         # u32 max are CLAMPED for the device (not wrapped), matching
@@ -887,13 +898,14 @@ class CounterEngine:
         # FixedWindowModel.step_counters_compact for the exactness
         # argument).
         if hasattr(self.model, "step_counters_unique_packed"):
-            # Packed transfer: ONE (4, padded) int32 host->device copy
-            # instead of five (each jnp.asarray call costs ~250us of
-            # dispatch overhead regardless of size —
-            # benchmarks/results/host_path.json).  Rows: slots, hits
-            # (u32 bit-pattern), limits (u32 bit-pattern), fresh.
-            # Padding uses DISTINCT out-of-table slots (num_slots + i)
-            # so the unique_indices scatter promise holds.
+            # Packed transfer: ONE (4, padded) int32 host array, handed
+            # to the jitted step as numpy — the dispatch carries it to
+            # the counters' device; a device_put of its own first is a
+            # second trip through JAX's Python for every launch.  Rows:
+            # slots, hits (u32 bit-pattern), limits (u32 bit-pattern),
+            # fresh.  Padding uses DISTINCT out-of-table slots
+            # (num_slots + i) so the unique_indices scatter promise
+            # holds.
             with SPANS.span(_spans.LAUNCH_PACK):
                 pk = np.empty((4, padded), dtype=np.int32)
                 pk[0, :g] = dedup.uniq_slots
@@ -911,9 +923,10 @@ class CounterEngine:
             with self._device_call(watch, shape):
                 self._counts, afters_dev = (
                     self.model.step_counters_unique_packed(
-                        self._counts, dt, jax.numpy.asarray(pk)
+                        self._counts, dt, pk
                     )
                 )
+                afters_dev.copy_to_host_async()
             return afters_dev, None, shape
 
         # Unpacked unique path (models with step_counters_unique but
@@ -933,11 +946,7 @@ class CounterEngine:
         fr[:g] = dedup.fresh
 
         device_batch = DeviceBatch(
-            slots=jax.numpy.asarray(sl),
-            hits=jax.numpy.asarray(hi),
-            limits=jax.numpy.asarray(li),
-            fresh=jax.numpy.asarray(fr),
-            shadow=jax.numpy.asarray(sh),
+            slots=sl, hits=hi, limits=li, fresh=fr, shadow=sh
         )
         shape = (padded, dt)
         with self._device_call(watch, shape):
@@ -951,6 +960,7 @@ class CounterEngine:
                 self._counts, afters_dev = self.model.step_counters_unique(
                     self._counts, device_batch
                 )
+            afters_dev.copy_to_host_async()
         return afters_dev, None, shape
 
     def reset(self) -> None:

@@ -725,6 +725,7 @@ class DeviceFaultDomain:
     def start(self) -> None:
         if self._thread is not None or self.interval_s <= 0:
             return
+        self._stop.clear()  # start after a stop: TpuRateLimitCache.warmup
         self._thread = threading.Thread(
             target=self._loop, name="device-supervisor", daemon=True
         )

@@ -1426,6 +1426,12 @@ class TpuRateLimitCache:
                 base + ".window_rollovers",
                 lambda i=idx: self._engine_at(i).stat_window_rollovers,
             )
+            # Over ratelimit.tpu.launch.rate: the share of launches
+            # whose device trip the hand-off hid (engine.py).
+            store.counter_fn(
+                base + ".readback_ready",
+                lambda i=idx: self._engine_at(i).stat_readback_ready,
+            )
             store.gauge_fn(
                 base + ".num_slots",
                 lambda i=idx: self._engine_at(i).model.num_slots,
@@ -1509,9 +1515,22 @@ class TpuRateLimitCache:
     def warmup(self) -> None:
         """Pre-compile every (bucket, readback-dtype) kernel shape so
         the first real RPC never pays XLA compilation.  Call before
-        serving starts — it steps the engines directly."""
-        for engine in self.engines():
-            warmup_engine(engine)
+        serving starts — it steps the engines directly, from this
+        thread, so the fault domain's supervisor is paused meanwhile:
+        a step donates the counts, and a snapshot due in that moment
+        (the first falls due one watchdog tick after construction)
+        read them donated — `Array has been deleted` -> exception
+        fault -> restart (witnessed on the chip: PERF.md section 6,
+        PR 26).  Nothing is served yet, so nothing goes unwatched."""
+        fd = self.fault_domain
+        if fd is not None:
+            fd.stop()
+        try:
+            for engine in self.engines():
+                warmup_engine(engine)
+        finally:
+            if fd is not None:
+                fd.start()
 
     # -- internals -------------------------------------------------------
 

@@ -40,22 +40,29 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # In BENCHMARK.json: read with the readers layers.py has, silent on an
 # older program.
 PR24 = ("pool_wait_ms", "prepare_us", "apply_us", "wake_us", "snapshot_ms")
-# Files ready, entries waiting (chipbench/README-pr24.md): the launch
-# legs need a `launches` reader that skips records without the field.
+# The launch legs: the `launches` reader indexes rec[field], so their
+# `.paced` entries waited (chipbench/README-pr24.md) until the parent a
+# PR is measured against was PR 24, whose every record carries them;
+# PR 26 appended them, and `readback_ready_share.paced` with them.
 LAUNCH_LEGS = ("assign_us", "device_submit_us", "readback_us", "decide_us", "handoff_us")
 HELD = LAUNCH_LEGS + ("incident_stall_ms",)
+PR26 = tuple(n + ".paced" for n in LAUNCH_LEGS) + ("readback_ready_share.paced",)
 H = "ratelimit_server.ShouldRateLimit."
 
 
-def _obs(with_new: bool) -> dict:
+def _obs(with_new: bool, ready: bool = False) -> dict:
     """Observations as run.py gathers them: a program before PR 24 has
-    response_ms, launch.rate, snapshots and the old launch fields only."""
+    response_ms, launch.rate, snapshots and the old launch fields only;
+    one before PR 26 (`ready` false) no bank's readback_ready."""
     def stats(n):
         hist = {H + "response_ms": {"count": 10 * n, "total_ms": 40.0 * n}}
         if with_new:
             for leg, ms in (("pool_wait_ms", 5.0), ("prepare_ms", 4.0), ("wake_ms", 3.0), ("apply_ms", 2.0)):
                 hist[H + leg] = {"count": 10 * n, "total_ms": ms * n}
-        return {"histograms": hist, "stats": {"ratelimit.tpu.launch.rate": 9 * n}}
+        flat = {"ratelimit.tpu.launch.rate": 9 * n}
+        if ready:
+            flat.update({f"ratelimit.tpu.bank{b}.readback_ready": (3 - b) * n for b in range(3)})
+        return {"histograms": hist, "stats": flat}
 
     def faults(n):
         doc = {"snapshots": 3 * n}
@@ -95,14 +102,44 @@ def test_pr24_metric_reads_what_the_program_gained(name, suffix):
         assert layers.read(spec["reader"], _obs(with_new=False)) is None
 
 
-def test_pr24_entries_are_appended_and_the_rest_wait_outside_the_benchmark():
+def test_readback_ready_share_is_silent_on_a_program_without_the_counter():
+    spec = load_json("layer_metrics", "readback_ready_share.paced")
+    assert set(spec) == {"what", "reader"}
+    # (3 + 2 + 1) x (3 - 1) ready of 9 x (3 - 1) launches.
+    assert layers.read(spec["reader"], _obs(True, ready=True)) == pytest.approx(100 * 12 / 18)
+    assert layers.read(spec["reader"], _obs(True)) is None  # the parent: PR 24
+    assert layers.read(spec["reader"], _obs(False)) is None
+
+
+@pytest.mark.parametrize("name", PR26)
+def test_pr26_entry_reads_on_pr24s_records_and_on_the_change(name):
+    """What let the launch legs land: on the parent (PR 24: the fields
+    are in every record, the counter is not) no reader raises."""
+    spec = load_json("layer_metrics", name)
+    on_parent = layers.read(spec["reader"], _obs(True))
+    on_change = layers.read(spec["reader"], _obs(True, ready=True))
+    assert on_change is not None
+    assert (on_parent is None) == (name == "readback_ready_share.paced")
+
+
+def test_pr24_and_pr26_entries_are_appended_and_the_rest_wait_outside_the_benchmark():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     names = [m["name"] for m in bench["per_layer"]]
-    assert names[-len(PR24):] == [n + ".paced" for n in PR24]
-    assert not {n + s for n in HELD for s in ("", ".paced")} & set(names)
-    for m in bench["per_layer"][-len(PR24):]:
+    n24, n26 = len(PR24), len(PR26)
+    assert names[-n24 - n26:-n26] == [n + ".paced" for n in PR24]
+    assert names[-n26:] == list(PR26)
+    waiting = {n for n in LAUNCH_LEGS} | {"incident_stall_ms", "incident_stall_ms.paced"}
+    assert not waiting & set(names)
+    for m in bench["per_layer"][-n24 - n26:]:
         assert m["moves"] == "p50_ms" and m["workloads"] == ["tenants-zipf.paced"]
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    for n in PR26[:-1]:
+        m = by_name[n]
+        assert (m["unit"], m["better"], m["source"]) == ("us", "lower", "program_span")
+        assert m["layer"] == ("dispatcher" if n == "handoff_us.paced" else "engine (host)")
+    m = by_name["readback_ready_share.paced"]
+    assert (m["unit"], m["better"], m["source"], m["layer"]) == ("%", "higher", "program_counter", "engine (host)")
     with open(os.path.join(ROOT, "chipbench", "README-pr24.md")) as f:
         (block,) = re.findall(r"<!-- merge: BENCHMARK.json -->\n```json\n(.*?)```", f.read(), re.S)
     twins = json.loads(block)["per_layer"]

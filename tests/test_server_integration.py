@@ -529,6 +529,27 @@ def test_stats_json_endpoint(runner):
     )
 
 
+def test_stats_json_carries_readback_ready(runner):
+    """Every bank's `readback_ready` counter — launches whose result
+    was ready when the completer took them up — is in /stats.json,
+    beside the launch count it is a share of."""
+    body = json.dumps(
+        {
+            "domain": "basic",
+            "descriptors": [{"entries": [{"key": "key1", "value": "rr"}]}],
+        }
+    ).encode()
+    assert _http(runner, "/json", body)[0] in (200, 429)
+    status, out = _http(
+        runner, "/stats.json", port=runner.debug_server.bound_port
+    )
+    assert status == 200
+    stats = json.loads(out)["stats"]
+    ready = stats["ratelimit.tpu.bank0.readback_ready"]
+    assert isinstance(ready, int) and ready >= 0
+    assert stats["ratelimit.tpu.launch.rate"] >= 1
+
+
 def test_per_second_bank_wired_through_runner(tmp_path_factory):
     """TPU_PERSECOND=true gives SECOND-unit limits their own counter
     bank + dispatcher (the dual-Redis analog, fixed_cache_impl.go:
