@@ -32,6 +32,7 @@ import numpy as np
 
 from ..observability import spans as _spans
 from ..observability.spans import SPANS
+from .slot_table import PackedEntries
 
 logger = logging.getLogger("ratelimit.checkpoint")
 
@@ -70,28 +71,33 @@ def bank_roles(cache) -> list:
     return roles
 
 
-def snapshot_engine(engine) -> tuple:
-    """Copy one bank's state: (state dict, entries).  The state dict
-    is ``{"counts": ...}`` for fixed-window banks and one named row
-    per kernel state array for algorithm banks (sliding-window's
+def snapshot_engine(engine, bank: int = -1) -> tuple:
+    """Copy one bank's state: (state dict, packed entries).  The state
+    dict is ``{"counts": ...}`` for fixed-window banks and one named
+    row per kernel state array for algorithm banks (sliding-window's
     window/curr/prev, GCRA's tat_anchor/tat_cells — see
-    models/registry.py state_rows).  This is the only part that needs
-    exclusive access to the engine; serialization and disk I/O happen
-    afterwards on the caller's thread."""
-    return engine.export_state(), engine.slot_table.entries()
+    models/registry.py state_rows); the entries are the slot table's
+    four arrays (slot_table.PackedEntries).  This is the only part
+    that needs exclusive access to the engine — a device read and an
+    array copy, nothing per key, timed as ``rl.bg.snapshot.grab``:
+    serialization and disk I/O happen afterwards on the caller's
+    thread."""
+    with SPANS.background(_spans.BG_SNAPSHOT_GRAB, bank):
+        return engine.export_state(), engine.slot_table.export_packed()
 
 
 def write_snapshot(
     path: str,
     num_slots: int,
     state,
-    entries,
+    entries: PackedEntries,
     role: str = "",
     algorithm: str = "fixed_window",
 ) -> None:
     """Serialize + atomically write a snapshot (no pickle: keys are
-    stored as concatenated utf-8 bytes + a length array, so restore
-    can run with allow_pickle=False on untrusted files).  `role` names
+    stored as concatenated utf-8 bytes + a length array — the packed
+    entries as they are — so restore can run with allow_pickle=False
+    on untrusted files).  `role` names
     the bank's position in the cache topology (e.g. "lane1of4",
     "per_second", "algo_gcra") so a topology change can't silently
     restore one bank's keys into a different-purpose engine whose
@@ -101,13 +107,6 @@ def write_snapshot(
     dict."""
     if not isinstance(state, dict):
         state = {"counts": state}
-    with SPANS.span(_spans.BG_CHECKPOINT_SERIALIZE):
-        # Pure Python over every live key: holds the GIL throughout.
-        key_bytes = [e[0].encode("utf-8") for e in entries]
-        key_lens = np.array([len(b) for b in key_bytes], dtype=np.int64)
-        key_blob = np.frombuffer(b"".join(key_bytes), dtype=np.uint8)
-        slots = np.array([e[1] for e in entries], dtype=np.int64)
-        expiries = np.array([e[2] for e in entries], dtype=np.int64)
     tmp = f"{path}.tmp.{os.getpid()}"
     meta = json.dumps(
         {
@@ -129,10 +128,10 @@ def write_snapshot(
             np.savez_compressed(
                 f,
                 meta=np.frombuffer(meta.encode(), dtype=np.uint8),
-                key_lens=key_lens,
-                key_blob=key_blob,
-                slots=slots,
-                expiries=expiries,
+                key_lens=entries.key_lens,
+                key_blob=entries.key_blob,
+                slots=entries.slots,
+                expiries=entries.expiries,
                 **arrays,
             )
         os.replace(tmp, path)
@@ -232,31 +231,18 @@ def restore_engine(
                     sorted(rows),
                 )
                 return False
-            blob = bytes(z["key_blob"])
-            keys = []
-            off = 0
-            for n in z["key_lens"].tolist():
-                keys.append(blob[off : off + n].decode("utf-8"))
-                off += n
-            entries = list(
-                zip(keys, z["slots"].tolist(), z["expiries"].tolist())
+            entries = PackedEntries(
+                z["key_blob"].astype(np.uint8, copy=False),
+                z["key_lens"].astype(np.int64, copy=False),
+                z["slots"].astype(np.int64, copy=False),
+                z["expiries"].astype(np.int64, copy=False),
             )
     except Exception as e:
         logger.warning("checkpoint %s unreadable (%s), starting fresh", path, e)
         return False
 
     engine.import_state({k: v.astype(np.uint32) for k, v in state.items()})
-    table_cls = type(engine.slot_table)
-    if getattr(engine.slot_table, "refresh_expiry", False):
-        # Algorithm banks: preserve the refresh-on-touch lease policy
-        # across the restore (engine.py _refresh_table_cls).
-        engine.slot_table = table_cls.from_entries(
-            engine.model.num_slots, entries, refresh_expiry=True
-        )
-    else:
-        engine.slot_table = table_cls.from_entries(
-            engine.model.num_slots, entries
-        )
+    engine.restore_slot_table(entries)
     logger.warning(
         "restored %d live keys from %s (saved %.0fs ago)",
         len(entries),
@@ -318,7 +304,7 @@ class CheckpointManager:
         fd = getattr(self.cache, "fault_domain", None)
         for idx, engine in enumerate(self.cache.engines()):
             # One bank, one piece of background work with a duration
-            # (rl.bg.checkpoint; children grab / serialize / write).
+            # (rl.bg.checkpoint; children grab / write).
             with SPANS.background(_spans.BG_CHECKPOINT, idx):
                 self._checkpoint_bank(idx, engine, roles[idx], fd)
 
@@ -332,7 +318,7 @@ class CheckpointManager:
             grabbed = {}
 
             def grab():
-                grabbed["state"], grabbed["entries"] = snapshot_engine(engine)
+                grabbed["state"], grabbed["entries"] = snapshot_engine(engine, idx)
 
             try:
                 with SPANS.span(_spans.BG_CHECKPOINT_GRAB, idx):

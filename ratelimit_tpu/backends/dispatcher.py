@@ -589,19 +589,27 @@ class BatchDispatcher:
         if token.error is not None:
             raise token.error
 
-    def stuck_age(self, now: Optional[float] = None) -> float:
+    def stuck_age(
+        self, now: Optional[float] = None, excuse_gc: bool = True
+    ) -> float:
         """Seconds the oldest in-progress device call of an
         already-proven kernel shape (launch or readback wait) has been
-        running; 0.0 when there is none.  The one definition of "a
-        device call is stuck" — the watchdog and the RPC waits both
-        compare it to KERNEL_DEADLINE_S.  `now` defaults to (and must
-        come from) `stamp_clock`."""
+        running, less what it spent inside full garbage collections
+        (engine.CallWatch; ``excuse_gc=False`` leaves them in — the
+        watchdog asks both to count what it excused); 0.0 when there is
+        none.  The one definition of "a device call is stuck" — the
+        watchdog and the RPC waits both compare it to
+        KERNEL_DEADLINE_S.  `now` defaults to (and must come from)
+        `stamp_clock`."""
         if now is None:
             now = self._stamp_now()
         age = 0.0
-        for since in (self._launch_watch.since, self._complete_watch.since):
-            if since is not None and now - since > age:
-                age = now - since
+        for watch in (self._launch_watch, self._complete_watch):
+            open_s, in_gc_s = watch.age(now)
+            if excuse_gc:
+                open_s -= in_gc_s
+            if open_s > age:
+                age = open_s
         return age
 
     def watch_report(self, now: Optional[float] = None) -> List[dict]:

@@ -20,6 +20,7 @@ import numpy as np
 from ..models.fixed_window import DeviceBatch, FixedWindowModel
 from ..observability import spans as _spans
 from ..observability.spans import SPANS
+from .slot_table import PackedEntries
 
 logger = logging.getLogger("ratelimit.engine")
 
@@ -67,26 +68,46 @@ class CallWatch:
     brackets exactly its device interactions with begin/end
     (CounterEngine._device_call), so host work — slot assignment, a
     table rehash, the decide pass — never runs on the deadline's
-    clock.  One writer (the thread making the calls); read lock-free
-    by the watchdog and by waiting RPCs (BatchDispatcher.stuck_age).
+    clock.  Nor does a full garbage collection: it stops every Python
+    thread, this one too, for up to a quarter of a second at a million
+    keys, and the device call it fell into returns the moment it ends
+    — so ``age`` leaves out what ``SPANS.gc_pause_ns`` grew by since
+    the call began (``rl.bg.gc`` brackets the same collections).  A
+    call that is really stuck stays open with no collection running,
+    and its age grows as before.  One writer (the thread making the
+    calls); read lock-free by the watchdog and by waiting RPCs
+    (BatchDispatcher.stuck_age).
 
     ``last_leg`` is the thread's last COMPLETED leg — (span name,
     monotonic_ns it ended, its duration in ns) — which a hang fault
     copies beside the open background work: what the thread had just
     done when its device call stopped returning."""
 
-    __slots__ = ("since", "last_leg", "_now")
+    __slots__ = ("since", "gc_ns", "last_leg", "_now")
 
     def __init__(self, now: Callable[[], float]):
         self.since: Optional[float] = None
+        self.gc_ns = 0  # SPANS.gc_pause_ns() when the open call began
         self.last_leg: Optional[tuple] = None
         self._now = now
 
     def begin(self, armed: bool) -> None:
-        self.since = self._now() if armed else None  # tpu-lint: disable=shared-state -- one CallWatch per dispatcher thread: single writer, lock-free readers
+        # gc_ns before since: a reader that sees this call's `since`
+        # sees its gc_ns too.
+        self.gc_ns = SPANS.gc_pause_ns()  # tpu-lint: disable=shared-state -- one CallWatch per dispatcher thread: single writer, lock-free readers
+        self.since = self._now() if armed else None  # tpu-lint: disable=shared-state -- same single-writer stamp
 
     def end(self) -> None:
         self.since = None  # tpu-lint: disable=shared-state -- same single-writer stamp
+
+    def age(self, now: float) -> tuple:
+        """(seconds the open call has been running, seconds of them
+        inside full collections); (0.0, 0.0) with no call open."""
+        since = self.since
+        if since is None:
+            return 0.0, 0.0
+        in_gc = (SPANS.gc_pause_ns() - self.gc_ns) / 1e9
+        return now - since, max(0.0, in_gc)
 
 
 def device_report() -> dict:
@@ -1026,32 +1047,38 @@ class CounterEngine:
 
         Must run with exclusive engine access (cache.run_exclusive),
         like every slot-table touch."""
-        ents = self.slot_table.entries()
-        sel = [(k, s, e) for k, s, e in ents if pred(k)]
+        packed = self.slot_table.export_packed()
+        keys = packed.keys()
+        mask = np.fromiter((pred(k) for k in keys), dtype=bool, count=len(keys))
+        sel = packed.select(mask)
         # Writable copies: device readbacks can come back read-only.
         state = {
             name: np.array(arr, copy=True)
             for name, arr in self.export_state().items()
         }
-        if not sel:
-            return {name: arr[:0].copy() for name, arr in state.items()}, []
-        idx = np.array([s for _, s, _ in sel], dtype=np.int64)
-        out = {name: arr[idx].copy() for name, arr in state.items()}
-        if drop:
+        out = {name: arr[sel.slots].copy() for name, arr in state.items()}
+        if drop and len(sel):
             for arr in state.values():
-                arr[idx] = 0
+                arr[sel.slots] = 0
             self.import_state(state)
-            keep = [(k, s, e) for k, s, e in ents if not pred(k)]
-            table_cls = type(self.slot_table)
-            if getattr(self.slot_table, "refresh_expiry", False):
-                self.slot_table = table_cls.from_entries(
-                    self.model.num_slots, keep, refresh_expiry=True
-                )
-            else:
-                self.slot_table = table_cls.from_entries(
-                    self.model.num_slots, keep
-                )
-        return out, [(k, e) for k, _s, e in sel]
+            self.restore_slot_table(packed.select(~mask))
+        sel_keys = [k for k, m in zip(keys, mask.tolist()) if m]
+        return out, list(zip(sel_keys, sel.expiries.tolist()))
+
+    def restore_slot_table(self, entries: PackedEntries) -> None:
+        """Replace the slot table by one rebuilt from packed entries
+        (checkpoint restore, handoff), keeping this bank's table kind
+        and, for algorithm banks, its refresh-on-touch lease policy
+        (_refresh_table_cls)."""
+        table_cls = type(self.slot_table)
+        if getattr(self.slot_table, "refresh_expiry", False):
+            self.slot_table = table_cls.from_packed(
+                self.model.num_slots, entries, refresh_expiry=True
+            )
+        else:
+            self.slot_table = table_cls.from_packed(
+                self.model.num_slots, entries
+            )
 
     def import_keys(self, state: dict, entries, now: int) -> dict:
         """Inverse of export_keys, into THIS engine's table: assign a

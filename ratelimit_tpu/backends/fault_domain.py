@@ -250,6 +250,8 @@ class DeviceFaultDomain:
         self.stat_restarts = 0
         self.stat_probe_failures = 0
         self.stat_snapshots = 0
+        self.stat_snapshot_timeouts = 0  # tokens the collector did not serve in time
+        self.stat_gc_excused = 0  # ticks a call was past the deadline only by a collection
         # Lifecycle event journal (observability/events.py), wired by
         # the runner when EVENT_JOURNAL_SIZE > 0: quarantine entry,
         # first fallback decision of an episode, half-open probes and
@@ -426,6 +428,11 @@ class DeviceFaultDomain:
             rec.backoff_s,
         )
 
+    def snapshot_entries(self) -> int:
+        """Slot-table entries the banks' current snapshots hold."""
+        snaps = [rec.snapshot for rec in self._records]
+        return sum(len(snap[1]) for snap in snaps if snap is not None)
+
     def quarantined_count(self) -> int:
         return sum(1 for r in self._records if r.state != "closed")
 
@@ -440,7 +447,7 @@ class DeviceFaultDomain:
                 return None
             return (
                 rec.fallback.export_state(),
-                rec.fallback.slot_table.entries(),
+                rec.fallback.slot_table.export_packed(),
             )
 
     def _report_health(self) -> None:
@@ -483,6 +490,10 @@ class DeviceFaultDomain:
         if stuck > self.kernel_deadline_s:
             self.record_fault(bank, FAULT_HANG, self.hang_error(stuck))
             return
+        if d.stuck_age(now, excuse_gc=False) > self.kernel_deadline_s:
+            # Open past the deadline only by the length of a full
+            # collection, which held this thread as well: not a hang.
+            self.stat_gc_excused += 1  # tpu-lint: disable=shared-state -- GIL-atomic stats counter, single supervisor writer
         if self.snapshot_interval_s > 0 and now >= rec.next_snapshot:
             self._snapshot_bank(bank, rec, d, now)
 
@@ -526,7 +537,7 @@ class DeviceFaultDomain:
         grabbed = {}
 
         def grab():
-            grabbed["snap"] = snapshot_engine(engine)
+            grabbed["snap"] = snapshot_engine(engine, bank)
 
         try:
             # From asking for the token to holding the copy: the wait
@@ -537,6 +548,7 @@ class DeviceFaultDomain:
                     grab, timeout=max(1.0, 4.0 * self.kernel_deadline_s)
                 )
         except TimeoutError:
+            self.stat_snapshot_timeouts += 1  # tpu-lint: disable=shared-state -- GIL-atomic stats counter, single supervisor writer
             logger.warning(
                 "bank %d: snapshot token not served in time (queue "
                 "backlog?); retrying next interval",
@@ -762,6 +774,11 @@ class DeviceFaultDomain:
             scope + ".probe_failures", lambda: self.stat_probe_failures
         )
         store.counter_fn(scope + ".snapshots", lambda: self.stat_snapshots)
+        store.counter_fn(
+            scope + ".snapshot_timeouts", lambda: self.stat_snapshot_timeouts
+        )
+        store.gauge_fn(scope + ".snapshot_entries", self.snapshot_entries)
+        store.counter_fn(scope + ".gc_excused", lambda: self.stat_gc_excused)
         store.gauge_fn(
             scope + ".quarantined_banks", lambda: self.quarantined_count()
         )
@@ -807,6 +824,9 @@ class DeviceFaultDomain:
             "restarts": self.stat_restarts,
             "probe_failures": self.stat_probe_failures,
             "snapshots": self.stat_snapshots,
+            "snapshot_timeouts": self.stat_snapshot_timeouts,
+            "snapshot_entries": self.snapshot_entries(),
+            "gc_excused": self.stat_gc_excused,
             "quarantined_banks": self.quarantined_count(),
             # What runs beside serving (observability/spans.py): open
             # now, and per activity the time and count since start.

@@ -38,7 +38,7 @@ import numpy as np
 
 from ..api import Code
 from ..models.registry import get_algorithm
-from .slot_table import SlotTable
+from .slot_table import PackedEntries, SlotTable
 
 _OK = int(Code.OK)
 _OVER = int(Code.OVER_LIMIT)
@@ -237,27 +237,25 @@ class HostEngine:
                 f"state row {bad_row!r} size {bad_size} != num_slots {ns}"
             )
 
-    def import_snapshot(self, state: dict, entries) -> int:
+    def import_snapshot(self, state: dict, entries: PackedEntries) -> int:
         """Seed the mirror from a bank's last pre-fault snapshot
         (backends/checkpoint.py snapshot_engine shape): state rows +
-        live (key, slot, expiry) entries.  The quarantined bank then
+        the live entries, packed.  The quarantined bank then
         continues counting from where the device was at the snapshot —
         restart loss is bounded by the snapshot interval."""
         self.import_state({k: np.asarray(v) for k, v in state.items()})
-        self.slot_table = SlotTable.from_entries(
-            self.model.num_slots,
-            entries,
-            refresh_expiry=self.slot_table.refresh_expiry,
-        )
+        self.restore_slot_table(entries)
         self.stat_live_keys = len(self.slot_table)
         return len(entries)
 
-    # Live key-range export/import: identical semantics to the device
-    # engine's (merge-on-collision, drop-expired) — reuse its
-    # implementation, which only touches export_state/import_state and
-    # the slot table (all provided above).
+    # Live key-range export/import and the slot table's rebuild from
+    # packed entries: identical semantics to the device engine's
+    # (merge-on-collision, drop-expired; the table keeps its kind and
+    # lease policy) — reuse its implementation, which only touches
+    # export_state/import_state and the slot table (all provided above).
     from .engine import CounterEngine as _CE
 
+    restore_slot_table = _CE.restore_slot_table
     export_keys = _CE.export_keys
     import_keys = _CE.import_keys
     del _CE

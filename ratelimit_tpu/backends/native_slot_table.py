@@ -23,6 +23,8 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
+from .slot_table import PackedEntries
+
 logger = logging.getLogger("ratelimit.native")
 
 _LIB: Optional[ctypes.CDLL] = None
@@ -532,38 +534,34 @@ class NativeSlotTable:
 
     # -- checkpoint surface ---------------------------------------------
 
-    def entries(self) -> List[Tuple[str, int, int]]:
+    def export_packed(self) -> PackedEntries:
+        """Every live entry in one C call and four arrays: nothing per
+        key happens in Python, so the collector thread (which owns the
+        table, and behind which every launch waits) is held for a copy
+        and no longer."""
         total_bytes = ctypes.c_int64(0)
         n = int(self._lib.sk_export_size(self._handle, ctypes.byref(total_bytes)))
-        if n == 0:
-            return []
         blob = np.empty(total_bytes.value, dtype=np.uint8)
         lens = np.empty(n, dtype=np.int64)
         slots = np.empty(n, dtype=np.int64)
         expiries = np.empty(n, dtype=np.int64)
-        self._lib.sk_export(
-            self._handle, _ptr(blob), _ptr(lens), _ptr(slots), _ptr(expiries)
-        )
-        out = []
-        raw = blob.tobytes()
-        off = 0
-        for i in range(n):
-            ln = int(lens[i])
-            out.append(
-                (raw[off : off + ln].decode("utf-8"), int(slots[i]), int(expiries[i]))
+        if n:
+            self._lib.sk_export(
+                self._handle, _ptr(blob), _ptr(lens), _ptr(slots), _ptr(expiries)
             )
-            off += ln
-        return out
+        return PackedEntries(blob, lens, slots, expiries)
 
     @classmethod
-    def from_entries(cls, num_slots: int, entries) -> "NativeSlotTable":
+    def from_packed(cls, num_slots: int, packed: PackedEntries) -> "NativeSlotTable":
         t = cls(num_slots)
-        if entries:
-            keys = [e[0] for e in entries]
-            blob, lens = _pack_keys(keys)
-            slots = np.asarray([e[1] for e in entries], dtype=np.int64)
-            exp = np.asarray([e[2] for e in entries], dtype=np.int64)
+        if len(packed):
+            # Named, so that each array outlives the call its address goes to.
+            blob = np.ascontiguousarray(packed.key_blob, dtype=np.uint8)
+            lens = np.ascontiguousarray(packed.key_lens, dtype=np.int64)
+            slots = np.ascontiguousarray(packed.slots, dtype=np.int64)
+            expiries = np.ascontiguousarray(packed.expiries, dtype=np.int64)
             t._lib.sk_import(
-                t._handle, _ptr(blob), _ptr(lens), _ptr(slots), _ptr(exp), len(keys)
+                t._handle, _ptr(blob), _ptr(lens), _ptr(slots), _ptr(expiries),
+                len(packed),
             )
         return t

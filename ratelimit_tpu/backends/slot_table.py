@@ -25,7 +25,73 @@ run_on_thread instead of locking), so its state carries no locks.
 from __future__ import annotations
 
 import heapq
+from dataclasses import dataclass
 from typing import Dict, List, Tuple
+
+import numpy as np
+
+
+@dataclass(frozen=True)
+class PackedEntries:
+    """A slot table's live entries as four arrays — the form a
+    snapshot holds, a checkpoint file stores and the native table
+    exports and imports in one call each, so that a million keys never
+    become a million Python objects: ``key_blob`` is the keys' utf-8
+    bytes end to end, ``key_lens`` the length of each, ``slots`` and
+    ``expiries`` one per key.  Who needs keys as strings decodes here
+    (``keys`` / ``tuples``), the one definition."""
+
+    key_blob: np.ndarray  # uint8[sum(key_lens)]
+    key_lens: np.ndarray  # int64[n]
+    slots: np.ndarray  # int64[n]
+    expiries: np.ndarray  # int64[n]
+
+    def __post_init__(self):
+        # The arrays may come from a file (checkpoint.restore_engine)
+        # and go to C++ unread by Python: hold them to their shape.
+        n = len(self.slots)
+        if (
+            len(self.key_lens) != n
+            or len(self.expiries) != n
+            or (n and int(self.key_lens.min()) < 0)
+            or int(self.key_lens.sum()) != len(self.key_blob)
+        ):
+            raise ValueError("packed entries: arrays disagree in length")
+
+    def __len__(self) -> int:
+        return len(self.slots)
+
+    @classmethod
+    def from_tuples(cls, entries) -> "PackedEntries":
+        """From ``[(key, slot, expiry), ...]`` (the Python table's form)."""
+        key_bytes = [e[0].encode("utf-8") for e in entries]
+        return cls(
+            np.frombuffer(b"".join(key_bytes), dtype=np.uint8),
+            np.array([len(b) for b in key_bytes], dtype=np.int64),
+            np.array([e[1] for e in entries], dtype=np.int64),
+            np.array([e[2] for e in entries], dtype=np.int64),
+        )
+
+    def keys(self) -> List[str]:
+        raw = self.key_blob.tobytes()
+        ends = np.cumsum(self.key_lens).tolist()
+        return [
+            raw[lo:hi].decode("utf-8") for lo, hi in zip([0] + ends, ends)
+        ]
+
+    def tuples(self) -> List[Tuple[str, int, int]]:
+        return list(
+            zip(self.keys(), self.slots.tolist(), self.expiries.tolist())
+        )
+
+    def select(self, mask: np.ndarray) -> "PackedEntries":
+        """The entries where ``mask`` (bool[n]) is set."""
+        return PackedEntries(
+            self.key_blob[np.repeat(mask, self.key_lens)],
+            self.key_lens[mask],
+            self.slots[mask],
+            self.expiries[mask],
+        )
 
 
 class SlotTable:
@@ -136,6 +202,19 @@ class SlotTable:
             heapq.heappush(t._heap, (int(expiry), key))
         t._free = [s for s in range(num_slots - 1, -1, -1) if s not in used]
         return t
+
+    # The packed form (snapshots, checkpoints): a thin adapter over
+    # the tuple form, which stays the oracle the native table is
+    # tested against.
+
+    def export_packed(self) -> PackedEntries:
+        return PackedEntries.from_tuples(self.entries())
+
+    @classmethod
+    def from_packed(
+        cls, num_slots: int, packed: PackedEntries, refresh_expiry: bool = False
+    ) -> "SlotTable":
+        return cls.from_entries(num_slots, packed.tuples(), refresh_expiry)
 
     def gc(self, now: int) -> int:
         """Reclaim slots of expired keys; returns how many were freed.

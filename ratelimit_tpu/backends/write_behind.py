@@ -368,7 +368,7 @@ class WriteBehindRateLimitCache:
         with self._view_lock:
             self._view = {
                 key: [int(counts[slot]), 0, expiry]
-                for key, slot, expiry in self.engine.slot_table.entries()
+                for key, slot, expiry in self.engine.slot_table.export_packed().tuples()
             }
 
     # -- lifecycle / parity surface -------------------------------------

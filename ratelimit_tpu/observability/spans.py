@@ -79,9 +79,9 @@ COMPLETE_DECIDE = "rl.complete.decide"  # host threshold machine
 COMPLETE_SIGNAL = "rl.complete.signal"  # scatter + event.set() loop
 # background threads
 BG_SNAPSHOT = "rl.bg.snapshot"  # fault-domain snapshot: token asked -> copy held
-BG_CHECKPOINT = "rl.bg.checkpoint"  # one bank: children grab / serialize / write
+BG_SNAPSHOT_GRAB = "rl.bg.snapshot.grab"  # the collector's share of a snapshot or checkpoint grab: device read + packed copy
+BG_CHECKPOINT = "rl.bg.checkpoint"  # one bank: children grab / write
 BG_CHECKPOINT_GRAB = "rl.bg.checkpoint.grab"
-BG_CHECKPOINT_SERIALIZE = "rl.bg.checkpoint.serialize"
 BG_CHECKPOINT_WRITE = "rl.bg.checkpoint.write"
 BG_INCIDENT_CAPTURE = "rl.bg.incident_capture"
 BG_DETECTOR_TICK = "rl.bg.detector_tick"
@@ -95,6 +95,7 @@ CLOCK_PREFIX = "rl.clock."  # rl.clock.<monotonic_ns>, twice a capture
 TICK_NAMES = (BG_DETECTOR_TICK, BG_TSDB_TICK, BG_WATCHDOG_TICK, BG_GC)
 BACKGROUND_NAMES = (
     BG_SNAPSHOT,
+    BG_SNAPSHOT_GRAB,
     BG_CHECKPOINT,
     BG_INCIDENT_CAPTURE,
     CALL_TOKEN,
@@ -112,7 +113,6 @@ SPAN_NAMES = (
     COMPLETE_DECIDE,
     COMPLETE_SIGNAL,
     BG_CHECKPOINT_GRAB,
-    BG_CHECKPOINT_SERIALIZE,
     BG_CHECKPOINT_WRITE,
 ) + BACKGROUND_NAMES
 
@@ -256,6 +256,17 @@ class ProgramSpans:
             self._gc_done.append(
                 (threading.current_thread().name, start, dur)
             )
+
+    def gc_pause_ns(self) -> int:
+        """Nanoseconds this process has spent in full collections so
+        far, the open one included — lock-free, like ``_on_gc``.  The
+        kernel watchdog takes it when a device call begins and again
+        when it judges the call (engine.CallWatch): every Python
+        thread waits while a collection runs, the one that would have
+        returned from the device call too, so that time is the
+        interpreter's and not the device's."""
+        total, start = self._total_ns[BG_GC], self._gc_start_ns
+        return total + (time.monotonic_ns() - start if start else 0)
 
     def open_work(self, now_ns: Optional[int] = None) -> List[dict]:
         """What background work is open right now, oldest first — what

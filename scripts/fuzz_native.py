@@ -18,7 +18,7 @@ Adversarial surface, on top of plain workloads:
   assigns;
 - exhaustion: batches with more distinct live keys than slots must
   raise on BOTH sides;
-- export/entries + from_entries checkpoint round-trips;
+- export_packed + from_packed checkpoint round-trips;
 - the fused dedup call vs python assign + engine._dedup_chunk, and
   the decide kernel vs _decide_host with saturating device counters.
 
@@ -278,14 +278,14 @@ class Harness:
             (int(s), bool(f)) for s, f in res[1]
         ], f"{ctx}: pinned assigns"
         py, nat = self.pairs[label]
-        assert sorted(py.entries()) == sorted(nat.entries()), f"{ctx}: entries"
+        assert sorted(py.entries()) == sorted(nat.export_packed().tuples()), f"{ctx}: entries"
         self.stats["pin"] += 1
 
     def check_roundtrip(self, pair, ctx):
         py, nat = pair
-        assert sorted(py.entries()) == sorted(nat.entries()), f"{ctx}: entries"
-        clone = nst.NativeSlotTable.from_entries(nat.num_slots, nat.entries())
-        assert sorted(clone.entries()) == sorted(nat.entries()), (
+        assert sorted(py.entries()) == sorted(nat.export_packed().tuples()), f"{ctx}: entries"
+        clone = nst.NativeSlotTable.from_packed(nat.num_slots, nat.export_packed())
+        assert sorted(clone.export_packed().tuples()) == sorted(nat.export_packed().tuples()), (
             f"{ctx}: from_entries round-trip"
         )
         self.stats["roundtrip"] += 1

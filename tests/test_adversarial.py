@@ -342,7 +342,7 @@ def test_many_thread_duplicate_key_stress_exact_counting(clock):
         # Per-key: look the slots up through the table.
         entries = {
             key: int(counts[slot])
-            for key, slot, _exp in cache.engine.slot_table.entries()
+            for key, slot, _exp in cache.engine.slot_table.export_packed().tuples()
         }
         for k, n in want.items():
             matching = [v for key, v in entries.items() if f"_{k}_" in key]

@@ -592,7 +592,7 @@ def test_checkpoint_roundtrip_algorithm_state(tmp_path):
     }
     path = str(tmp_path / "gcra_old_layout.npz")
     write_snapshot(
-        path, 1 << 10, old, bank.slot_table.entries(), "algo_gcra", "gcra"
+        path, 1 << 10, old, bank.slot_table.export_packed(), "algo_gcra", "gcra"
     )
     fresh = CounterEngine(
         buckets=(8, 32), model=get_algorithm("gcra").make_model(1 << 10, 0.8)

@@ -236,7 +236,7 @@ descriptors:
         eng = CounterEngine(num_slots=256)
         assert restore_engine(eng, str(tmp_path / "bank0.npz"), "lane0of1")
         counts = np.asarray(eng.export_counts())
-        entries = eng.slot_table.entries()
+        entries = eng.slot_table.export_packed().tuples()
         per_key = {k: int(counts[s]) for k, s, _e in entries}
         for k, c in per_key.items():
             assert 0 <= c <= per_thread, (k, c)  # a prefix, never more
@@ -250,7 +250,7 @@ descriptors:
     assert restore_engine(eng, str(tmp_path / "bank0.npz"), "lane0of1")
     counts = np.asarray(eng.export_counts())
     total = sum(
-        int(counts[s]) for _k, s, _e in eng.slot_table.entries()
+        int(counts[s]) for s in eng.slot_table.export_packed().slots
     )
     assert total == n_threads * per_thread  # drained snapshot is exact
     cache.close()

@@ -47,6 +47,12 @@ PR24 = ("pool_wait_ms", "prepare_us", "apply_us", "wake_us", "snapshot_ms")
 LAUNCH_LEGS = ("assign_us", "device_submit_us", "readback_us", "decide_us", "handoff_us")
 HELD = LAUNCH_LEGS + ("incident_stall_ms",)
 PR26 = tuple(n + ".paced" for n in LAUNCH_LEGS) + ("readback_ready_share.paced",)
+# PR 27 added the cell `mixed-1m.paced`: its name appended to every
+# `.paced` metric's cells, and seven metrics of its own after them.
+PR27 = (
+    "slot_fill_share.paced", "slot_evictions.paced", "gc_pause_ms.paced", "snapshot_hold_ms.paced",
+    "snapshot_timeouts.paced", "device_submit_p99_us.paced", "readback_p99_us.paced",
+)
 H = "ratelimit_server.ShouldRateLimit."
 
 
@@ -126,13 +132,17 @@ def test_pr24_and_pr26_entries_are_appended_and_the_rest_wait_outside_the_benchm
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     names = [m["name"] for m in bench["per_layer"]]
-    n24, n26 = len(PR24), len(PR26)
-    assert names[-n24 - n26:-n26] == [n + ".paced" for n in PR24]
-    assert names[-n26:] == list(PR26)
+    n24, n26, n27 = len(PR24), len(PR26), len(PR27)
+    assert names[-n24 - n26 - n27:-n26 - n27] == [n + ".paced" for n in PR24]
+    assert names[-n26 - n27:-n27] == list(PR26)
+    assert names[-n27:] == list(PR27)
     waiting = {n for n in LAUNCH_LEGS} | {"incident_stall_ms", "incident_stall_ms.paced"}
     assert not waiting & set(names)
-    for m in bench["per_layer"][-n24 - n26:]:
-        assert m["moves"] == "p50_ms" and m["workloads"] == ["tenants-zipf.paced"]
+    for m in bench["per_layer"][-n24 - n26 - n27:-n27]:
+        assert m["moves"] == "p50_ms"
+        assert m["workloads"] == ["tenants-zipf.paced", "mixed-1m.paced"]
+    for m in bench["per_layer"][-n27:]:
+        assert m["moves"] == "p50_ms" and m["workloads"] == ["mixed-1m.paced"]
     by_name = {m["name"]: m for m in bench["per_layer"]}
     for n in PR26[:-1]:
         m = by_name[n]

@@ -9,6 +9,7 @@ supervisor thread is disabled (fault_interval_s=0) and tick() driven
 manually, so restarts happen deterministically.
 """
 
+import gc
 import threading
 import time
 
@@ -31,6 +32,7 @@ from ratelimit_tpu.observability import (
     FLIGHT_CODE_FALLBACK,
     make_flight_recorder,
 )
+from ratelimit_tpu.observability.spans import BG_GC, BG_SNAPSHOT_GRAB, SPANS
 from ratelimit_tpu.stats.manager import Manager
 from ratelimit_tpu.utils.time import PinnedTimeSource
 
@@ -391,6 +393,110 @@ def test_slow_collector_is_not_a_device_hang():
         assert cache.fault_domain.stat_faults[FAULT_HANG] == 0
         assert cache.fault_domain.stat_fallback_decisions == 0
     finally:
+        cache.close()
+
+
+# ---------------------------------------------------------------------------
+# a collection is not a hang; a snapshot that is not served is counted
+# ---------------------------------------------------------------------------
+
+
+def _gated_cache(deadline: float):
+    engine = _GatedKernelEngine(num_slots=256, buckets=(8,))
+    cache = TpuRateLimitCache(
+        engine,
+        time_source=PinnedTimeSource(1234),
+        batch_window_us=100,
+        kernel_deadline_s=deadline,
+        fault_interval_s=0,  # tick() manually
+        fault_snapshot_interval_s=1000.0,
+    )
+    return cache, engine, _rule(Manager())
+
+
+def test_a_collection_inside_a_device_call_is_not_a_hang_a_stuck_call_is():
+    """A full collection stops every Python thread: a device call of a
+    proven shape that stays open for its length is the interpreter's
+    time, not the device's (counted as gc_excused); the same call open
+    as long with no collection running is a hang."""
+    cache, engine, rule = _gated_cache(deadline=0.15)
+    fd = cache.fault_domain
+    req = RateLimitRequest("d", [Descriptor.of(("k", "v"))], 1)
+
+    def slow_collection(phase, info):  # inside rl.bg.gc's bracket
+        if phase == "start" and info["generation"] == 2:
+            time.sleep(0.5)
+
+    SPANS.watch_gc()
+    gc.callbacks.append(slow_collection)
+    try:
+        assert cache.do_limit(req, [rule])[0].code is Code.OK  # shape proven
+        fd.snapshot_now()
+        before = SPANS.summary()["count"][BG_GC]
+        t, got = _hold_one_request(cache, rule, engine, req)
+        gc.collect()
+        assert SPANS.summary()["count"][BG_GC] == before + 1
+        d = next(iter(cache._dispatchers.values()))
+        assert d.stuck_age(excuse_gc=False) > 0.5 > 0.15 > d.stuck_age()
+        fd.tick()
+        assert not fd.is_quarantined(0)
+        assert fd.stat_gc_excused == 1
+        assert fd.summary()["gc_excused"] == 1
+        engine.gate.set()
+        t.join(10)
+        assert got["status"].code is Code.OK
+        assert fd.stat_faults[FAULT_HANG] == 0
+
+        gc.callbacks.remove(slow_collection)
+        t, got = _hold_one_request(cache, rule, engine, req)
+        time.sleep(0.3)  # two deadlines, no collection
+        fd.tick()
+        assert fd.is_quarantined(0)
+        assert fd.stat_faults[FAULT_HANG] == 1
+        assert fd.stat_gc_excused == 1
+        engine.gate.set()
+        t.join(10)
+        assert got["status"].code is Code.OK  # answered by the mirror
+    finally:
+        if slow_collection in gc.callbacks:
+            gc.callbacks.remove(slow_collection)
+        SPANS.watch_gc(False)
+        engine.gate.set()
+        cache.close()
+
+
+def test_a_snapshot_the_collector_cannot_serve_counts_one_timeout():
+    """The grab waits behind the collector's queue; past its timeout it
+    is counted (ratelimit.tpu.fault.snapshot_timeouts), not a fault.
+    Served, it records the entries it holds and the collector's share
+    of the work (rl.bg.snapshot.grab)."""
+    cache, engine, rule = _gated_cache(deadline=0.05)  # timeout max(1, 4 x 0.05) = 1 s
+    fd = cache.fault_domain
+    store = Manager().store
+    fd.register_stats(store)
+    release = threading.Event()
+    d = next(iter(cache._dispatchers.values()))
+    blocker = threading.Thread(target=lambda: d.run_on_thread(lambda: release.wait(30)))
+    try:
+        for value in ("a", "b", "c"):
+            req = RateLimitRequest("d", [Descriptor.of(("k", value))], 1)
+            assert cache.do_limit(req, [rule])[0].code is Code.OK
+        grabs = SPANS.summary()["count"][BG_SNAPSHOT_GRAB]
+        assert fd.snapshot_now() == 1
+        assert fd.snapshot_entries() == 3
+        assert SPANS.summary()["count"][BG_SNAPSHOT_GRAB] == grabs + 1
+        blocker.start()
+        assert fd.snapshot_now() == 0  # one second behind the blocker
+        assert fd.stat_snapshot_timeouts == 1
+        assert fd.summary()["snapshot_timeouts"] == 1
+        assert fd.summary()["snapshot_entries"] == 3
+        counters = store.counters()
+        assert counters["ratelimit.tpu.fault.snapshot_timeouts"] == 1
+        assert not fd.is_quarantined(0) and sum(fd.stat_faults.values()) == 0
+    finally:
+        release.set()
+        if blocker.is_alive():
+            blocker.join(10)
         cache.close()
 
 

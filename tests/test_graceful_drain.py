@@ -116,6 +116,6 @@ def test_sigterm_drain_completes_inflight_and_snapshots(tmp_path):
     eng = CounterEngine(num_slots=1 << 10)
     assert restore_engine(eng, str(bank0), "lane0of1")
     counts = np.asarray(eng.export_counts())
-    entries = eng.slot_table.entries()
+    entries = eng.slot_table.export_packed().tuples()
     assert entries, "snapshot lost the drained key"
     assert sum(int(counts[s]) for _k, s, _e in entries) == 1

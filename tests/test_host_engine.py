@@ -132,7 +132,7 @@ def test_import_snapshot_seeds_mirror():
     src = HostEngine(num_slots=64)
     blob, meta = _meta([("a", 5, 10, 0, 0, 0), ("b", 2, 10, 0, 0, 0)])
     _run(src, 1000, blob, meta)
-    snap = (src.export_state(), src.slot_table.entries())
+    snap = (src.export_state(), src.slot_table.export_packed())
 
     mirror = HostEngine(num_slots=64)
     assert mirror.import_snapshot(*snap) == 2
@@ -146,7 +146,7 @@ def test_snapshot_num_slots_mismatch_refused():
     src = HostEngine(num_slots=64)
     mirror = HostEngine(num_slots=32)
     with pytest.raises(ValueError, match="num_slots"):
-        mirror.import_snapshot(src.export_state(), [])
+        mirror.import_snapshot(src.export_state(), src.slot_table.export_packed())
 
 
 def test_static_allow_answers_ok_with_zero_stats():

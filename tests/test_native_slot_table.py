@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 from ratelimit_tpu.backends import native_slot_table
-from ratelimit_tpu.backends.slot_table import SlotTable
+from ratelimit_tpu.backends.slot_table import PackedEntries, SlotTable
 
 pytestmark = pytest.mark.skipif(
     not native_slot_table.available(), reason="no C++ toolchain"
@@ -59,7 +59,7 @@ def test_existing_keys_pinned_against_mid_batch_eviction():
         # 'b' must be evicted, never 'a'.
         slots, fresh = table.assign_batch(["a", "c"], 0, [10, 30])
         assert slots[0] != slots[1]
-        live = {k for k, _, _ in table.entries()}
+        live = {k for k, _, _ in table.export_packed().tuples()}
         assert live == {"a", "c"}
 
     # Same guarantee through the cross-call begin/end protocol.
@@ -72,7 +72,7 @@ def test_existing_keys_pinned_against_mid_batch_eviction():
         finally:
             table.end_batch()
         assert sa != sc
-        assert {k for k, _, _ in table.entries()} == {"a", "c"}
+        assert {k for k, _, _ in table.export_packed().tuples()} == {"a", "c"}
 
 
 def test_exhaustion_matches():
@@ -86,13 +86,13 @@ def test_export_import_roundtrip():
     py, nat = make_pair(16)
     for table in (py, nat):
         table.assign_batch(["x", "y", "z"], 0, [30, 10, 20])
-    assert sorted(py.entries()) == sorted(nat.entries())
+    assert sorted(py.entries()) == sorted(nat.export_packed().tuples())
 
-    restored = native_slot_table.NativeSlotTable.from_entries(16, nat.entries())
-    assert sorted(restored.entries()) == sorted(nat.entries())
+    restored = native_slot_table.NativeSlotTable.from_packed(16, nat.export_packed())
+    assert sorted(restored.export_packed().tuples()) == sorted(py.entries())
     # Known key keeps its slot; new key gets a free one.
     s, f = restored.assign_batch(["x", "new"], 0, [30, 40])
-    old = dict((k, v) for k, v, _ in nat.entries())
+    old = dict((k, v) for k, v, _ in nat.export_packed().tuples())
     assert s[0] == old["x"] and not f[0]
     assert f[1]
 
@@ -125,14 +125,14 @@ def test_gc_respects_batch_pins():
         slots, fresh = table.assign_batch(["k_90", "k_100"], 100, [100, 110])
         assert slots[0] != slots[1]
         assert list(fresh) == [True, True]
-        assert {k for k, _, _ in table.entries()} == {"k_90", "k_100"}
+        assert {k for k, _, _ in table.export_packed().tuples()} == {"k_90", "k_100"}
 
     # Explicit gc() between batches keeps reclaiming as before.
     py, nat = make_pair(4)
     for table in (py, nat):
         table.assign_batch(["a", "b"], 0, [10, 20])
         assert table.gc(15) == 1
-        assert {k for k, _, _ in table.entries()} == {"b"}
+        assert {k for k, _, _ in table.export_packed().tuples()} == {"b"}
 
 
 def test_import_skips_duplicate_keys():
@@ -140,9 +140,11 @@ def test_import_skips_duplicate_keys():
     slots (slot marked used but mapping dropped/overwritten)."""
     entries = [("dup", 0, 100), ("dup", 1, 200), ("other", 2, 300)]
     py = SlotTable.from_entries(8, entries)
-    nat = native_slot_table.NativeSlotTable.from_entries(8, entries)
+    nat = native_slot_table.NativeSlotTable.from_packed(
+        8, PackedEntries.from_tuples(entries)
+    )
     for table in (py, nat):
-        live = sorted(table.entries())
+        live = sorted(table.export_packed().tuples())
         assert live == [("dup", 0, 100), ("other", 2, 300)]
         assert len(table) == 2
         # slot 1 must be free again: 6 fresh keys fit (8 - 2 live).
