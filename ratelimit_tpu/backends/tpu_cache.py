@@ -39,9 +39,13 @@ from ..api import Code, DescriptorStatus, RateLimitRequest
 from ..config import RateLimitRule
 from ..models.registry import ALGORITHMS
 from ..observability import HotKeySketch, TRACER
-from ..limiter.cache_key import CacheKeyGenerator, EMPTY_KEY
+from ..limiter.cache_key import CacheKey, CacheKeyGenerator, EMPTY_KEY
 from ..limiter.local_cache import LocalCache
-from ..limiter.resolution import ResolutionCache
+from ..limiter.resolution import (
+    ResolutionCache,
+    flat_key,
+    record,
+)
 from ..utils.time import (
     TimeSource,
     RealTimeSource,
@@ -66,6 +70,10 @@ _CAT_NONE = 0  # no matching rule: OK, no stats
 _CAT_ENGINE = 1  # goes to the counter engine
 _CAT_LOCAL = 2  # host cache says over-limit: short-circuit
 _CAT_SKIP = 3  # shadow rule + cached over-limit: skip counter, OK
+
+# The resolution map's probe for a request whose config is not the
+# table's generation: finds nothing, so every descriptor goes to miss().
+_NO_ENTRY = {}.get
 
 
 def warmup_engine(engine) -> None:
@@ -215,9 +223,8 @@ class TpuRateLimitCache:
         # pipeline.  0 disables it (A/B benchmarking knob).
         self.resolver = (
             ResolutionCache(
+                LANE_DTYPE,
                 prefix=cache_key_prefix,
-                n_lanes=len(lanes),
-                lane_dtype=LANE_DTYPE,
                 capacity=resolution_cache_entries,
                 algorithms=frozenset(self.algorithm_banks),
             )
@@ -453,14 +460,18 @@ class TpuRateLimitCache:
         lookup, key, TotalHits, local-cache check, bank routing AND
         per-bank pack assembly fused into a single pass over the
         descriptors.  Each engine-bound descriptor contributes three
-        list appends — row index, memoized key bytes, memoized
-        template record bytes — and the per-bank packer just joins
-        them.  ``_construct_limits_to_check``, CacheKeyGenerator
-        .generate and _make_item's per-lane loop all collapse here.
+        list appends — row index, key bytes (the key's stem + the
+        rule's window suffix), record bytes (the rule's window
+        template around the key's length) — and the per-bank packer
+        just joins them.  ``_construct_limits_to_check``,
+        CacheKeyGenerator.generate and _make_item's per-lane loop all
+        collapse here.
 
         Returns (items, statuses, categories, keys, limits,
         is_unlimited, hits_addend, now, hot) — ``hot`` is the per-row
-        hot-key entry list (None when the sketch is disabled)."""
+        hot-key entry list (None when the sketch is disabled); ``keys``
+        holds a CacheKey only where something reads one (override rows,
+        and every limited row when the local over-limit cache is on)."""
         resolver = self.resolver
         descriptors = request.descriptors
         domain = request.domain
@@ -500,7 +511,6 @@ class TpuRateLimitCache:
         # (one GIL-atomic op per descriptor); only HITS pay the
         # contains() call (expiry check + counting).
         promo_entries = promotion.entries if promotion is not None else None
-        resolve = resolver.resolve
         # Hot-key sketch feed: one counter bump per limited descriptor
         # on the handle pinned to its ResolvedDescriptor; track() (the
         # locked, structural path) only runs on first sight of a stem
@@ -516,13 +526,16 @@ class TpuRateLimitCache:
         # One branch per descriptor until noted, then free.
         fl = self.flight
         fl_pending = fl is not None
-        # Inlined resolve() hit path: one dict probe + generation
-        # check per descriptor, with the hit tally batched into one
-        # attribute add per request.  Misses (and their counting) go
-        # through resolve() itself.
-        entries_map = resolver._entries
-        generation = config.generation
-        resolver_lanes = resolver.n_lanes
+        # Inlined resolve() hit path: the generation is checked once a
+        # request (the table belongs to one; under another every probe
+        # falls through to miss(), which sorts it out), then one dict
+        # probe per descriptor on the flat string key, with the hit
+        # tally batched into one attribute add per request.
+        generation, entries_map, _ = resolver._live
+        entries_get = (
+            entries_map.get if generation == config.generation else _NO_ENTRY
+        )
+        miss = resolver.miss
         resolution_hits = 0
         overrides: Optional[list] = None
         # TotalHits adds batched by rule identity: consecutive
@@ -538,39 +551,50 @@ class TpuRateLimitCache:
                     overrides = []
                 overrides.append(i)
                 continue
-            rd = entries_map.get((domain, desc.entries))
-            if rd is not None and rd.generation == generation:
-                if rd.n_lanes != resolver_lanes:
-                    rd.rehash_lanes(resolver_lanes)
+            entries = desc.entries
+            # flat_key(), its two common shapes spelled out.
+            if len(entries) == 1:
+                a = entries[0]
+                ck = (domain, a.key, a.value)
+            elif len(entries) == 2:
+                a, b = entries
+                ck = (domain, a.key, a.value, b.key, b.value)
+            else:
+                ck = flat_key(domain, entries)
+            rd = entries_get(ck)
+            if rd is not None:
+                rd.ref = True  # tpu-lint: disable=shared-state -- idempotent second-chance bit
                 resolution_hits += 1
             else:
-                rd = resolve(config, domain, desc)
-            rule = rd.rule
+                rd = miss(config, domain, desc, ck)
+            rs = rd.rs
+            rule = rs.rule
             if rule is None:
                 continue  # no matching rule: CAT_NONE, empty key
-            if rd.unlimited:
+            if rs.unlimited:
                 is_unlimited[i] = True
                 continue  # limits[i] stays None (service contract)
             limits[i] = rule
             # Hot-loop hoists (tpu-lint hot-path-cost): each of these
-            # rd.* chains is probed several times per descriptor below
-            # — load once per iteration instead of per use.
-            algo_id = rd.algo_id
-            algorithm = rd.algorithm
-            stem = rd.stem
+            # chains is probed several times per descriptor below —
+            # load once per iteration instead of per use.
+            algo_id = rs.algo_id
+            algorithm = rs.algorithm
+            per_second = rs.per_second
+            stem_bytes = rd.stem_bytes
             if fl_pending:
                 fl_pending = False
-                if algo_id and not rd.algo_shadow:
+                if algo_id and not rs.algo_shadow:
                     note_bank = self._algo_bank_index[algorithm]
-                elif ps_bank is not None and rd.per_second:
+                elif ps_bank is not None and per_second:
                     note_bank = n_lanes
                 else:
-                    note_bank = rd.lane
+                    note_bank = rd.lane(n_lanes)
                 fl.note(rd.stem_hash, note_bank)
             if hk is not None:
                 e = rd.hot
                 if e is None or e.key is None:
-                    e = hk.track(stem)
+                    e = hk.track(rd.stem)
                     rd.hot = e
                 e.hits += hits_addend
                 hk_observed += hits_addend
@@ -583,43 +607,61 @@ class TpuRateLimitCache:
                 prev_rule = rule
                 prev_hits = hits_addend
             # Inline window-hit check (the overwhelmingly common case);
-            # window_state() handles the rollover rebuild.
-            ws = rd._win
-            if ws is None or ws.window != now - now % rd.divider:
-                ws = rd.window_state(now)
-            key = keys[i] = ws.cache_key
-            if algo_id and not rd.algo_shadow:
-                # Rule ENFORCES a non-default algorithm: route to its
-                # dedicated bank.  The host over-limit cache is skipped
-                # — these kernels refill capacity continuously, so a
-                # full-window OVER_LIMIT verdict has no valid TTL.
-                categories[i] = _CAT_ENGINE
-                if algo_accs is None:
-                    algo_accs = {}
-                acc = algo_accs.get(algorithm)
-                if acc is None:
-                    acc = algo_accs[algorithm] = ([], [], [])
-                acc[0].append(i)
-                acc[1].append(ws.algo_key_bytes)
-                acc[2].append(ws.algo_template_bytes)
-                continue
-            if (
-                promo_entries is not None
-                and stem in promo_entries
-                and promotion.contains(stem)
-            ):
-                # Hot-key promotion (overload/controller.py): the
-                # sketch marked this stem a repeat offender; serve the
-                # short-TTL host decision and skip the device.  Shadow
-                # rules stay non-enforcing here exactly like the host
-                # over-limit cache below.
-                categories[i] = _CAT_SKIP if rule.shadow_mode else _CAT_LOCAL
-                continue
-            if local_cache is not None and local_cache.contains(key.key):
-                # Shadow rules skip the counter but never short-circuit
-                # to OVER_LIMIT (fixed_cache_impl.go:57-67).
-                categories[i] = _CAT_SKIP if rule.shadow_mode else _CAT_LOCAL
-                continue
+            # window() handles the rollover rebuild.
+            win = rs.win
+            if win is None or win.start != now - now % rs.divider:
+                win = rs.window(now)
+            if algo_id:
+                # The algorithm bank's pack pieces: the stable-stem key
+                # and its record.
+                klen = len(stem_bytes)
+                algo_tpl = record(win.algo_head, klen, win.algo_tail)
+                if not rs.algo_shadow:
+                    # Rule ENFORCES a non-default algorithm: route to
+                    # its dedicated bank.  The host over-limit cache is
+                    # skipped — these kernels refill capacity
+                    # continuously, so a full-window OVER_LIMIT verdict
+                    # has no valid TTL.
+                    categories[i] = _CAT_ENGINE
+                    if local_cache is not None:
+                        keys[i] = CacheKey(
+                            stem_bytes.decode("utf-8"), False, klen
+                        )
+                    if algo_accs is None:
+                        algo_accs = {}
+                    acc = algo_accs.get(algorithm)
+                    if acc is None:
+                        acc = algo_accs[algorithm] = ([], [], [])
+                    acc[0].append(i)
+                    acc[1].append(stem_bytes)
+                    acc[2].append(algo_tpl)
+                    continue
+            if promo_entries is not None:
+                stem = rd.stem
+                if stem in promo_entries and promotion.contains(stem):
+                    # Hot-key promotion (overload/controller.py): the
+                    # sketch marked this stem a repeat offender; serve
+                    # the short-TTL host decision and skip the device.
+                    # Shadow rules stay non-enforcing here exactly like
+                    # the host over-limit cache below.
+                    categories[i] = (
+                        _CAT_SKIP if rule.shadow_mode else _CAT_LOCAL
+                    )
+                    continue
+            key_bytes = stem_bytes + win.suffix
+            if local_cache is not None:
+                # The one consumer of the key as text (here and in
+                # _apply_decisions): built on this branch only.
+                key = keys[i] = CacheKey(
+                    key_bytes.decode("utf-8"), per_second, len(stem_bytes)
+                )
+                if local_cache.contains(key.key):
+                    # Shadow rules skip the counter but never short-
+                    # circuit to OVER_LIMIT (fixed_cache_impl.go:57-67).
+                    categories[i] = (
+                        _CAT_SKIP if rule.shadow_mode else _CAT_LOCAL
+                    )
+                    continue
             categories[i] = _CAT_ENGINE
             if algo_id:
                 # Shadow rollout: the candidate kernel evaluates the
@@ -636,21 +678,22 @@ class TpuRateLimitCache:
                 if sa is None:
                     sa = shadow_accs[algorithm] = ([], [], [])
                 sa[0].append(i)
-                sa[1].append(ws.algo_key_bytes)
-                sa[2].append(ws.algo_template_bytes)
+                sa[1].append(stem_bytes)
+                sa[2].append(algo_tpl)
                 shadow_rows.append((i, algorithm, algo_id))
+            tpl = record(win.head, len(key_bytes), win.tail)
             if single_bank:
                 add_row(i)
-                add_enc(ws.key_bytes)
-                add_tpl(ws.template_bytes)
+                add_enc(key_bytes)
+                add_tpl(tpl)
                 continue
-            if ps_bank is not None and rd.per_second:
+            if ps_bank is not None and per_second:
                 bank = ps_bank
             else:
-                bank = banks[rd.lane]
+                bank = banks[rd.lane(n_lanes)]
             bank[0].append(i)
-            bank[1].append(ws.key_bytes)
-            bank[2].append(ws.template_bytes)
+            bank[1].append(key_bytes)
+            bank[2].append(tpl)
         if prev_rule is not None:
             prev_rule.stats.total_hits.add(prev_hits)
         if resolution_hits:
@@ -1372,6 +1415,9 @@ class TpuRateLimitCache:
             )
             store.counter_fn(
                 scope + ".resolution_cache.clears", lambda: res.clears
+            )
+            store.counter_fn(
+                scope + ".resolution_cache.evictions", lambda: res.evictions
             )
             store.gauge_fn(
                 scope + ".resolution_cache.entries", lambda: len(res)

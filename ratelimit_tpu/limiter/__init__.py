@@ -2,7 +2,7 @@ from .cache import RateLimitCache
 from .cache_key import CacheKey, CacheKeyGenerator, build_stem
 from .base import LimitDecision, decide, decide_batch
 from .local_cache import LocalCache
-from .resolution import ResolutionCache, ResolvedDescriptor
+from .resolution import ResolutionCache, ResolvedDescriptor, ResolvedRule
 
 __all__ = [
     "RateLimitCache",
@@ -15,4 +15,5 @@ __all__ = [
     "LocalCache",
     "ResolutionCache",
     "ResolvedDescriptor",
+    "ResolvedRule",
 ]

@@ -2,15 +2,35 @@
 packed lanes.
 
 The per-request Python pipeline — ``get_limit`` trie walk, key-stem
-assembly, utf-8 encode, crc32 lane routing, per-lane ``LANE_DTYPE``
+assembly, utf-8 encode, crc32 lane hash, per-lane ``LANE_DTYPE``
 record construction — is window-independent for everything except the
-window suffix and the hits addend.  A ``ResolutionCache`` memoizes all
-of it per interned ``(domain, descriptor.entries)``: the matched
-:class:`RateLimitRule` (or None / unlimited), its stats handles (which
-the stats Manager already interns per key, so they survive reloads),
-the encoded utf-8 key stem, the lane index (``crc32(stem) % n_lanes``),
-the per-second-bank flag, and a pre-filled ``LANE_DTYPE`` template
-record where only ``expiry`` and ``hits`` are stamped per request.
+window suffix and the hits addend.  A ``ResolutionCache`` memoizes it
+in two tiers, so that what is kept per key is only what is the key's
+own:
+
+- **per rule, once** (:class:`ResolvedRule`, one per rule and config
+  generation): the matched :class:`RateLimitRule` (or None /
+  unlimited) with its stats handles, unit, divider, per-second flag,
+  algorithm routing — and the **window memo**: for the current window
+  the ASCII suffix ``str(window_start)`` and the lane record's bytes
+  with expiry, ``hits = 1``, limit, shadow, divider and algo stamped,
+  built with numpy once per (rule, window) and cut in two around the
+  one per-key field, the key's byte length;
+- **per key, flat** (:class:`ResolvedDescriptor`): the encoded utf-8
+  key stem, its crc32 (lane route and flight-recorder hash), a
+  reference to the rule's object, the hot-key handle, the second-
+  chance bit.  A window's key bytes are ``stem_bytes + suffix``; its
+  lane record is ``head + length + tail``, three byte strings joined
+  per request.  One collector-tracked object a key (two with the
+  sketch's handle), no numpy array, no per-window object: a full
+  collection has a sixth of the parent's heap to walk, and a first-
+  seen key allocates nothing that outlives the request but its entry.
+
+The map's key is the flat string tuple ``(domain, k1, v1, k2, v2,
+...)``: hashed and compared in C (a tuple of ``Entry`` dataclasses
+runs a Python ``__hash__`` / ``__eq__`` per entry and probe), and —
+holding only strings — dropped from the collector's lists at the first
+collection it survives.
 
 The reference memoizes only the cheap half of this (pooled
 ``bytes.Buffer`` key building, cache_key.go:17-29) and gets the rest
@@ -18,22 +38,35 @@ free from Go; here the full resolution is the measured host-path tax
 (benchmarks/results/host_path.json) so the whole pipeline collapses
 onto one dict hit.
 
-Invalidation is a config **generation counter**: every
+Invalidation is the config **generation counter**: every
 :class:`RateLimitConfig` carries a monotonically increasing
-``generation`` (config/loader.py); entries record the generation they
-were resolved under and miss when it moves.  A FAILED reload keeps the
-old config object AND its old generation (service/ratelimit.py keeps
-the previous config on ConfigError), so the warm cache survives bad
+``generation`` (config/loader.py).  The table belongs to one
+generation; the first miss under a newer one drops it whole (counted
+in ``clears``) and starts the next, and a request still holding an
+older config is answered uncached.  A FAILED reload keeps the old
+config object AND its old generation (service/ratelimit.py keeps the
+previous config on ConfigError), so the warm cache survives bad
 pushes.  Request-supplied overrides (``descriptor.limit is not None``)
-bypass the cache entirely, and the entry map is capacity-bounded with
-the same clear-on-full policy as the key-stem cache (rare full reset
-beats per-entry LRU bookkeeping on the hot path).
+bypass the cache entirely.
 
-Thread model: resolve() runs concurrently on RPC handler threads with
-no lock — dict get/set are single atomic ops under the GIL, a racing
-double-resolve builds equivalent entries (last write wins), and the
-hit/miss tallies are plain ints whose rare lost increments are an
-accepted stats-only race (the same trade the stem cache makes).
+Capacity is held by **second-chance eviction** (CLOCK): entries sit in
+a ring in insertion order, a hit sets the entry's ``ref`` bit, and a
+miss at capacity moves the hand past referenced entries (clearing
+their bits) and replaces the first unreferenced one — amortized O(1),
+counted in ``evictions``.  A scan of first-seen keys therefore evicts
+its own tail and a hot set stays; nothing is cleared wholesale, so the
+heap neither saws nor is freed in one storm.
+
+Thread model: hits run concurrently on RPC handler threads with no
+lock — ``(generation, entries, rules)`` is one tuple read in one
+atomic load, a dict get is atomic under the GIL, the ``ref`` store is
+idempotent, and the hit/miss tallies are plain ints whose rare lost
+increments are an accepted stats-only race (the same trade the stem
+cache makes).  A miss builds its entry unlocked and takes the cache's
+lock only to insert (ring, hand and map move together); a racing
+double-resolve returns the entry that got there first.  The window
+memo is swapped whole, so concurrent readers see the old window's
+state or the new one, never a mix.
 
 This module is dependency-light on purpose: the lane record dtype is
 injected by the backend (``lane_dtype=LANE_DTYPE``) so the limiter
@@ -42,7 +75,9 @@ layer never imports the device stack.
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+import sys
+import threading
+from typing import Optional
 from zlib import crc32
 
 import numpy as np
@@ -50,7 +85,7 @@ import numpy as np
 from ..api import Descriptor, Unit
 from ..models.registry import DEFAULT_ALGORITHM, get_algorithm
 from ..utils.time import unit_to_divider
-from .cache_key import CacheKey, build_stem
+from .cache_key import build_stem
 
 _MISSING_BANK_WARNED: set = set()
 
@@ -73,118 +108,101 @@ def _warn_missing_bank(algo: str) -> None:
     )
 
 
-class WindowState:
-    """Everything about one (resolved descriptor, window) pair: the
-    finished :class:`CacheKey`, its utf-8 encoding (the pack blob
-    piece), and the template lane record with ``expiry`` pre-stamped
-    to ``window_start + divider`` — per request only ``hits`` remains.
-    ``template_bytes`` is the record's raw encoding: the packer joins
-    these (bytes.join is ~an order cheaper than per-row structured-
-    array assignment) and reinterprets the blob as one LANE_DTYPE
-    array.
+def flat_key(domain: str, entries) -> tuple:
+    """The map's key: ``(domain, k1, v1, k2, v2, ...)``.  One and two
+    entries — nearly every descriptor — are spelled out; the serving
+    loop (tpu_cache._prepare_resolved) inlines the same two shapes."""
+    n = len(entries)
+    if n == 1:
+        a = entries[0]
+        return (domain, a.key, a.value)
+    if n == 2:
+        a, b = entries
+        return (domain, a.key, a.value, b.key, b.value)
+    out = [domain]
+    for e in entries:
+        out.append(e.key)
+        out.append(e.value)
+    return tuple(out)
 
-    For rules running a non-default algorithm in SHADOW mode the state
-    additionally carries the candidate bank's pack pieces
-    (``algo_key_bytes``/``algo_template_bytes``): the stable-stem key
-    and a template whose expiry leases the slot for two windows past
-    the current one (refresh-on-touch keeps it alive while hot).  An
-    ENFORCING algorithm rule needs no extra fields — its primary
-    key/template ARE the stable-stem ones.
 
-    Immutable after construction; the owning entry swaps the whole
-    object on window rollover so concurrent readers see either the old
-    window's state or the new one, never a mix."""
+# The key-length field of a lane record, little-endian as LANE_DTYPE
+# stores it, for every length a key is likely to have (``record``
+# falls back to int.to_bytes past the table).
+_LEN_FIELD_BYTES = 4
+_LEN_LE = tuple(n.to_bytes(_LEN_FIELD_BYTES, "little") for n in range(1024))
+
+
+def record(head: bytes, key_len: int, tail: bytes) -> bytes:
+    """One lane record: a window's template with the key's byte length
+    spliced in."""
+    if key_len < 1024:
+        return head + _LEN_LE[key_len] + tail
+    return head + key_len.to_bytes(_LEN_FIELD_BYTES, "little") + tail
+
+
+class Window:
+    """Everything about one (rule, window) pair: the window's start,
+    its ASCII key suffix, and the lane record with ``expiry`` pre-
+    stamped to ``start + divider`` and ``hits`` to the common addend 1
+    — cut into the bytes before (``head``) and after (``tail``) the
+    key-length field, which is the key's.
+
+    For rules running a non-default algorithm the window carries that
+    bank's record too (``algo_head`` / ``algo_tail``): the rule's
+    divider (the kernel's window / emission math needs it), the
+    algorithm's id, and an expiry leasing the slot TWO windows past
+    the current one — the algorithm banks' refresh-on-touch slot
+    tables extend it while the key stays hot, so per-slot window / TAT
+    state survives exactly as long as it matters.  An ENFORCING
+    algorithm rule has only that record (its key is the bare stem: the
+    kernels track windows per slot), a SHADOWING one has both.
+
+    Immutable after construction; the owning rule swaps the whole
+    object on window rollover."""
+
+    __slots__ = ("start", "suffix", "head", "tail", "algo_head", "algo_tail")
+
+    def __init__(self, start, suffix, head, tail, algo_head, algo_tail):
+        self.start = start
+        self.suffix = suffix
+        self.head = head
+        self.tail = tail
+        self.algo_head = algo_head
+        self.algo_tail = algo_tail
+
+
+class ResolvedRule:
+    """What a resolution owes to the rule alone, once per (rule,
+    config generation): the rule, its routing flags, and the single-
+    slot window memo (:class:`Window`)."""
 
     __slots__ = (
-        "window",
-        "cache_key",
-        "key_bytes",
-        "template",
-        "template_bytes",
-        "algo_key_bytes",
-        "algo_template_bytes",
-        "_arr",
-    )
-
-    def __init__(
-        self,
-        window: int,
-        cache_key: CacheKey,
-        key_bytes: bytes,
-        template: Optional[np.void],
-        arr: Optional[np.ndarray],
-        algo_key_bytes: bytes = b"",
-        algo_template_bytes: bytes = b"",
-    ):
-        self.window = window
-        self.cache_key = cache_key
-        self.key_bytes = key_bytes
-        self.template = template
-        self.template_bytes = arr.tobytes() if arr is not None else b""
-        self.algo_key_bytes = algo_key_bytes
-        self.algo_template_bytes = algo_template_bytes
-        # The 1-element array backing `template` (np.void records are
-        # views; keep the base alive explicitly).
-        self._arr = arr
-
-
-class ResolvedDescriptor:
-    """One interned (domain, entries) resolution: rule + everything
-    window-independent, plus a single-slot per-window memo."""
-
-    __slots__ = (
-        "generation",
         "rule",
         "unlimited",
         "per_second",
-        "stem",
-        "stem_bytes",
-        "stem_hash",
-        "n_lanes",
-        "lane",
         "unit",
         "divider",
         "algorithm",
         "algo_id",
         "algo_shadow",
-        "_lane_dtype",
-        "_win",
-        "hot",
+        "win",
+        "_cut",
     )
 
-    def __init__(
-        self,
-        generation: int,
-        rule,
-        stem: str,
-        n_lanes: int,
-        lane_dtype,
-        algorithms: frozenset = frozenset(),
-    ):
-        self.generation = generation
+    def __init__(self, rule, cut, algorithms: frozenset):
         self.rule = rule
         self.unlimited = rule is not None and rule.unlimited
-        self.stem = stem
-        self.stem_bytes = stem.encode("utf-8")
-        # One crc32 per resolution (cold path): the lane route below
-        # and the flight recorder's key-stem hash share it, so ring
-        # records and lane hashing agree by construction.
-        self.stem_hash = crc32(self.stem_bytes)
-        self.n_lanes = n_lanes
-        self.lane = self.stem_hash % n_lanes if n_lanes > 1 else 0
-        self._lane_dtype = lane_dtype
-        self._win: Optional[WindowState] = None
-        # Hot-key sketch handle (observability/hotkeys.py), pinned by
-        # the serving loop on first observation so the per-request
-        # cost is one counter bump — None until tracked, and the
-        # handle itself goes dead (key=None) on sketch eviction.
-        self.hot = None
+        # (record fields) -> (head, tail): the cache's, which owns the
+        # lane dtype.
+        self._cut = cut
+        self.win: Optional[Window] = None
         if rule is not None and not rule.unlimited:
             self.unit = rule.limit.unit
             self.divider = unit_to_divider(self.unit)
             self.per_second = self.unit == Unit.SECOND
             # Algorithm-table routing (models/registry.py): resolved
-            # once per entry so the serving loop reads plain attrs.
+            # once per rule so the serving loop reads plain attrs.
             # An algorithm the backend has NO bank for folds back to
             # the default — the rule keeps limiting (fixed-window)
             # instead of erroring every request it matches.
@@ -209,135 +227,150 @@ class ResolvedDescriptor:
             self.algo_id = 0
             self.algo_shadow = False
 
-    def rehash_lanes(self, n_lanes: int) -> None:
-        """Lane-count change (new cache topology): recompute the route
-        for the new modulus.  The amnesia envelope is the same as a
-        restart with a changed TPU_NUM_LANES — old windows' counters
-        age out in the old lane while the key counts afresh."""
-        self.lane = self.stem_hash % n_lanes if n_lanes > 1 else 0  # tpu-lint: disable=shared-state -- idempotent re-derivation: every racer computes the same value
-        self.n_lanes = n_lanes  # tpu-lint: disable=shared-state -- idempotent re-derivation (same n_lanes input)
-
-    def _algo_template_bytes(self, w: int) -> bytes:
-        """Lane record for this entry's non-default algorithm bank:
-        stable-stem key length, the rule's divider (the kernel's
-        window/emission math needs it), and an expiry leasing the slot
-        TWO windows past the current one — the algorithm banks'
-        refresh-on-touch slot tables extend it while the key stays
-        hot, so per-slot window/TAT state survives exactly as long as
-        it matters."""
-        rule = self.rule
-        arr = np.empty(1, dtype=self._lane_dtype)
-        arr[0] = (
-            w + 2 * self.divider,  # expiry lease (refreshed on touch)
-            1,  # hits pre-stamped to the common addend
-            rule.limit.requests_per_unit,
-            len(self.stem_bytes),
-            1 if rule.shadow_mode else 0,
-            self.divider,
-            self.algo_id,
-        )
-        return arr.tobytes()
-
-    def window_state(self, now: int) -> WindowState:
-        """The memoized per-window state, rebuilt once per rollover.
-        Byte-identical to CacheKeyGenerator output for fixed-window
-        rules: key string is ``stem + str(window_start)``.  Rules
-        ENFORCING a non-default algorithm key by the bare stem (their
-        kernels track windows per slot); rules SHADOWING one keep the
-        fixed-window primary and carry the candidate bank's pack
-        pieces alongside."""
+    def window(self, now: int) -> Window:
+        """The memoized window state, rebuilt once per rollover.  With
+        it a key is byte-identical to CacheKeyGenerator's: ``stem +
+        str(window_start)`` for fixed-window rules (and the fixed-
+        window primary of a rule SHADOWING an algorithm), the bare
+        stem for rules ENFORCING one."""
         # Inline window_start(now, unit): the divider is resolved once
-        # at entry construction, so the hot path skips the per-call
-        # Unit coercion + divider lookup (measured ~1.5us/descriptor).
-        w = now - now % self.divider
-        ws = self._win
-        if ws is not None and ws.window == w:
-            return ws
-        algo_enforced = self.algo_id != 0 and not self.algo_shadow
-        if algo_enforced:
-            # Stable-stem identity: one key across window rollovers,
-            # never routed to the per-second bank (algorithm banks are
-            # unit-agnostic — the divider rides the lane record).
-            ws = WindowState(
-                w,
-                CacheKey(self.stem, False, len(self.stem_bytes)),
-                self.stem_bytes,
-                None,
-                None,
-                algo_key_bytes=self.stem_bytes,
-                algo_template_bytes=(
-                    self._algo_template_bytes(w)
-                    if self._lane_dtype is not None
-                    else b""
-                ),
+        # at construction, so the hot path skips the per-call Unit
+        # coercion + divider lookup (measured ~1.5us/descriptor).
+        divider = self.divider
+        w = now - now % divider
+        win = self.win
+        if win is not None and win.start == w:
+            return win
+        rule = self.rule
+        limit = rule.limit.requests_per_unit
+        shadow = 1 if rule.shadow_mode else 0
+        head = tail = algo_head = algo_tail = b""
+        if self.algo_id:
+            algo_head, algo_tail = self._cut(
+                (
+                    w + 2 * divider,  # expiry lease (refreshed on touch)
+                    1,  # hits pre-stamped to the common addend
+                    limit,
+                    0,  # len: the key's
+                    shadow,
+                    divider,
+                    self.algo_id,
+                )
             )
-            self._win = ws  # tpu-lint: disable=shared-state -- whole-object swap: readers see the old or the new WindowState, never a mix (class docstring)
-            return ws
-        suffix = str(w)
-        key_str = self.stem + suffix
-        key_bytes = self.stem_bytes + suffix.encode("ascii")
-        template = arr = None
-        algo_tpl = b""
-        if self._lane_dtype is not None:
-            rule = self.rule
-            arr = np.empty(1, dtype=self._lane_dtype)
-            arr[0] = (
-                w + self.divider,  # expiry base (jitter stamped later)
-                1,  # hits pre-stamped to the common addend; the packer
-                #    only overwrites when the request carries hits != 1
-                rule.limit.requests_per_unit,
-                len(key_bytes),
-                1 if rule.shadow_mode else 0,
-                0,  # divider: fixed-window kernels never read it
-                0,  # algo: fixed_window
+        if not self.algo_id or self.algo_shadow:
+            head, tail = self._cut(
+                (
+                    w + divider,  # expiry base (jitter stamped later)
+                    1,  # hits pre-stamped to the common addend; the packer
+                    #    only overwrites when the request carries hits != 1
+                    limit,
+                    0,  # len: the key's
+                    shadow,
+                    0,  # divider: fixed-window kernels never read it
+                    0,  # algo: fixed_window
+                )
             )
-            template = arr[0]
-            if self.algo_shadow:
-                algo_tpl = self._algo_template_bytes(w)
-        ws = WindowState(
-            w,
-            CacheKey(key_str, self.per_second, len(self.stem_bytes)),
-            key_bytes,
-            template,
-            arr,
-            algo_key_bytes=self.stem_bytes if self.algo_shadow else b"",
-            algo_template_bytes=algo_tpl,
+        win = Window(
+            w, str(w).encode("ascii"), head, tail, algo_head, algo_tail
         )
-        self._win = ws  # single-slot swap: readers see old or new
-        return ws
+        self.win = win  # tpu-lint: disable=shared-state -- whole-object swap: readers see the old or the new Window, never a mix (class docstring)
+        return win
+
+
+class ResolvedDescriptor:
+    """One interned (domain, entries) resolution: what is the key's
+    own, and a reference to its rule's :class:`ResolvedRule`."""
+
+    __slots__ = ("key", "rs", "stem_bytes", "stem_hash", "hot", "ref")
+
+    def __init__(self, key: tuple, rs: ResolvedRule, stem_bytes: bytes):
+        self.key = key  # the map's key, for the eviction
+        self.rs = rs
+        self.stem_bytes = stem_bytes
+        # One crc32 per resolution (cold path): the lane route and the
+        # flight recorder's key-stem hash share it, so ring records
+        # and lane hashing agree by construction.
+        self.stem_hash = crc32(stem_bytes)
+        # Hot-key sketch handle (observability/hotkeys.py), pinned by
+        # the serving loop on first observation so the per-request
+        # cost is one counter bump — None until tracked, and the
+        # handle itself goes dead (key=None) on sketch eviction.
+        self.hot = None
+        # Second chance: set by every hit, cleared by the hand.
+        self.ref = False
+
+    @property
+    def stem(self) -> str:
+        """The key stem as text — for the consumers that key by it
+        (hot-key sketch registration, promotion cache, the local
+        over-limit cache's key); the serving path joins bytes."""
+        return self.stem_bytes.decode("utf-8")
+
+    def lane(self, n_lanes: int) -> int:
+        """The host lane of this key among ``n_lanes``: crc32 of the
+        utf-8 STEM, so a key keeps its lane across windows and the
+        cached and uncached paths agree.  A changed lane count re-
+        routes with the same amnesia envelope as a restart with a
+        changed TPU_NUM_LANES."""
+        return self.stem_hash % n_lanes if n_lanes > 1 else 0
+
+
+_NO_GENERATION = -1
 
 
 class ResolutionCache:
-    """Per-service map from interned ``(domain, entries)`` to a
+    """Per-service map from the flat ``(domain, k1, v1, ...)`` key to a
     :class:`ResolvedDescriptor`.  See module docstring for the
-    invalidation and threading contract."""
+    invalidation, eviction and threading contract."""
 
     def __init__(
         self,
+        lane_dtype,
         prefix: str = "",
-        n_lanes: int = 1,
-        lane_dtype=None,
         capacity: int = 1 << 16,
         algorithms: frozenset = frozenset(),
     ):
         self.prefix = prefix
-        self.n_lanes = max(1, int(n_lanes))
         self.lane_dtype = lane_dtype
-        self.capacity = int(capacity)
+        len_type, self._len_at = lane_dtype.fields["len"][:2]
+        if len_type.str not in ("<u4", "=u4") or sys.byteorder != "little":
+            raise ValueError(
+                "lane record's `len` must be a little-endian u4, "
+                f"not {len_type.str!r}"
+            )
+        self.capacity = max(1, int(capacity))
         # Non-default algorithms the owning backend has banks for;
         # rules asking for anything else fold to the default kernel
-        # (see ResolvedDescriptor).
+        # (see ResolvedRule).
         self.algorithms = frozenset(algorithms)
-        self._entries: dict = {}
+        # (generation, entries, rules by id(rule)): one object, so a
+        # lock-free reader never pairs one generation's number with
+        # another's table.
+        self._live: tuple = (_NO_GENERATION, {}, {})
+        # Second-chance ring: the entries in insertion order, and the
+        # hand.  Moved only under the lock.
+        self._ring: list = []
+        self._hand = 0
+        self._lock = threading.Lock()
         # Stats-only tallies; benign GIL races accepted (see module
         # docstring).  Exported as counters via register_stats on the
         # owning backend.
         self.hits = 0
         self.misses = 0
         self.clears = 0
+        self.evictions = 0
 
     def __len__(self) -> int:
-        return len(self._entries)
+        return len(self._live[1])
+
+    def _cut(self, fields: tuple) -> tuple:
+        """(head, tail) of the lane record holding ``fields`` (its
+        ``len`` left 0): numpy builds it, once per (rule, window)."""
+        arr = np.empty(1, dtype=self.lane_dtype)
+        arr[0] = fields
+        raw = arr.tobytes()
+        at = self._len_at
+        return raw[:at], raw[at + _LEN_FIELD_BYTES:]
 
     def resolve(self, config, domain: str, descriptor: Descriptor):
         """One dict hit on the hot path.  Returns None for
@@ -346,32 +379,80 @@ class ResolutionCache:
         :class:`ResolvedDescriptor` valid for ``config.generation``."""
         if descriptor.limit is not None:
             return None
-        ck: Tuple[str, tuple] = (domain, descriptor.entries)
-        e = self._entries.get(ck)
-        if e is not None and e.generation == config.generation:
-            if e.n_lanes != self.n_lanes:
-                e.rehash_lanes(self.n_lanes)
-            self.hits += 1
-            return e
+        key = flat_key(domain, descriptor.entries)
+        generation, entries, _ = self._live
+        if generation == config.generation:
+            e = entries.get(key)
+            if e is not None:
+                e.ref = True  # tpu-lint: disable=shared-state -- idempotent second-chance bit
+                self.hits += 1
+                return e
+        return self.miss(config, domain, descriptor, key)
+
+    def miss(self, config, domain: str, descriptor: Descriptor, key: tuple):
+        """Resolve ``descriptor`` afresh and keep it — unless ``config``
+        is older than the table's (a request that began before a
+        reload): that one is answered and nothing kept."""
         self.misses += 1
+        live = self._live
+        if live[0] != config.generation:
+            live = self._retarget(config.generation)
+        rules = live[2] if live is not None else {}
         rule = config.get_limit(domain, descriptor)
+        rs = rules.get(id(rule))
+        if rs is None:
+            # id(rule) stays the rule's: the ResolvedRule holds it.
+            rs = rules.setdefault(
+                id(rule),
+                ResolvedRule(rule, self._cut, self.algorithms),
+            )
         e = ResolvedDescriptor(
-            config.generation,
-            rule,
-            build_stem(self.prefix, domain, descriptor.entries),
-            self.n_lanes,
-            self.lane_dtype if rule is not None and not rule.unlimited else None,
-            algorithms=self.algorithms,
+            key,
+            rs,
+            build_stem(self.prefix, domain, descriptor.entries).encode("utf-8"),
         )
-        if len(self._entries) >= self.capacity:
-            # Same clear-on-full policy as the stem cache: a key-
-            # cardinality blowup resets the map (and is counted, so
-            # it is visible on /metrics instead of silent).
-            self._entries.clear()
-            self.clears += 1
-        self._entries[ck] = e
+        if live is None:
+            return e
+        with self._lock:
+            if self._live is not live:
+                return e  # a reload landed meanwhile: answered, not kept
+            entries = live[1]
+            other = entries.get(key)
+            if other is not None:
+                return other  # a racing miss of the same key got there first
+            ring = self._ring
+            capacity = self.capacity
+            if len(ring) < capacity:
+                ring.append(e)
+            else:
+                hand = self._hand
+                victim = ring[hand]
+                while victim.ref:
+                    victim.ref = False
+                    hand = hand + 1 if hand + 1 < capacity else 0
+                    victim = ring[hand]
+                del entries[victim.key]
+                ring[hand] = e
+                self._hand = hand + 1 if hand + 1 < capacity else 0
+                self.evictions += 1
+            entries[key] = e
         return e
 
+    def _retarget(self, generation: int, force: bool = False) -> Optional[tuple]:
+        """The live table for ``generation``: a fresh one if it is
+        newer than the table's (or ``force``), None if it is older.
+        A new dict, not ``clear()``: a reader that took the old table
+        keeps probing the old generation's entries, which is what it
+        asked for."""
+        with self._lock:
+            live = self._live
+            if force or generation > live[0]:
+                if live[1]:
+                    self.clears += 1
+                self._ring = []
+                self._hand = 0
+                self._live = live = (generation, {}, {})
+            return live if live[0] == generation else None
+
     def clear(self) -> None:
-        self._entries.clear()
-        self.clears += 1
+        self._retarget(self._live[0], force=True)

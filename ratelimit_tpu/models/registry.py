@@ -10,7 +10,7 @@ or GCRA's virtual-scheduling formulation (token bucket as a
 theoretical-arrival-time).  This module is the pluggable seam: config
 rules carry an ``algorithm:`` field (config/loader.py validates it
 against this table), the resolution cache stamps the algorithm onto
-each ResolvedDescriptor, and the backend routes each algorithm's
+each ResolvedRule, and the backend routes each algorithm's
 lanes to a dedicated engine bank whose model this table builds.
 
 IMPORT DISCIPLINE: this module must stay importable WITHOUT jax — the

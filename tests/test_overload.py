@@ -318,7 +318,7 @@ def test_promotion_short_circuits_device_in_do_limit_resolved(clock):
 
     promo = PromotionCache(ttl_s=5.0, capacity=8, clock=mono)
     cache.promotion = promo
-    rd = cache.resolver._entries[("d", req.descriptors[0].entries)]
+    rd = cache.resolver.resolve(cfg, "d", req.descriptors[0])
     promo.promote(rd.stem)
     statuses, _, _ = cache.do_limit_resolved(req, cfg)
     assert statuses[0].code is Code.OVER_LIMIT
