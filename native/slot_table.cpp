@@ -135,6 +135,7 @@ class FlatMap {
     if ((live_ + tombstones_ + 1) * 10 >= capacity() * 7 ||
         (dead_bytes_ > (1u << 20) && dead_bytes_ * 2 > arena_.size())) {
       rehash(capacity() * (live_ * 10 >= capacity() * 4 ? 2 : 1));
+      ++compactions_;
     }
     size_t i = h & mask_;
     while (state_[i] == kFull) i = (i + 1) & mask_;
@@ -161,6 +162,10 @@ class FlatMap {
   int64_t expiry(int64_t idx) const { return meta_[idx].expiry; }
   size_t size() const { return live_; }
   size_t arena_bytes() const { return arena_.size(); }
+  // Rehashes since construction: each rebuilds the table (same size
+  // or doubled) and compacts the arena — tombstones and the key bytes
+  // of erased entries go.
+  int64_t compactions() const { return compactions_; }
 
   std::string_view key_at(int64_t idx) const {
     const Meta& m = meta_[idx];
@@ -222,6 +227,7 @@ class FlatMap {
   size_t live_ = 0;
   size_t tombstones_ = 0;
   size_t dead_bytes_ = 0;  // arena bytes owned by tombstoned keys
+  int64_t compactions_ = 0;
   std::vector<uint8_t> state_;
   std::vector<uint64_t> hashes_;
   std::vector<Meta> meta_;
@@ -370,6 +376,12 @@ int64_t sk_evictions(void* t) { return static_cast<SlotTable*>(t)->evictions; }
 // — a live memory gauge and the churn-compaction test's probe.
 int64_t sk_arena_bytes(void* t) {
   return static_cast<int64_t>(static_cast<SlotTable*>(t)->map.arena_bytes());
+}
+
+// Rehashes of the key map since construction (growth or same-size):
+// each one compacts the arena.
+int64_t sk_compactions(void* t) {
+  return static_cast<SlotTable*>(t)->map.compactions();
 }
 
 int64_t sk_gc(void* tp, int64_t now) {

@@ -425,6 +425,57 @@ def decide_generic(
     )
 
 
+# A bank's slot-table family: name under ratelimit.tpu.bank<i> ->
+# the plain int its collector keeps (CounterEngine.stat_*).
+_SLOT_COUNTERS = (
+    # Evictions are monotonic; paired with the num_slots gauge, "about
+    # to exhaust TPU_NUM_SLOTS" is a dashboard trend, not a surprise.
+    ("evictions", "stat_evictions"),
+    ("window_rollovers", "stat_window_rollovers"),
+    ("dedup_groups", "stat_groups_launched"),
+    ("slot_gc.runs", "stat_slot_gc_runs"),
+    ("slot_gc.freed", "stat_slot_gc_freed"),
+    ("arena.compactions", "stat_arena_compactions"),
+)
+_SLOT_GAUGES = (
+    ("live_keys", "stat_live_keys"),
+    ("arena.bytes", "stat_arena_bytes"),
+)
+
+
+def register_slot_stats(store, base: str, engine_of: Callable) -> None:
+    """Register a bank's slot-table family under `base`
+    (ratelimit.tpu.bank<i>): occupancy and capacity, evictions, window
+    rollovers over dedup groups launched, the collector's slot GC, the
+    native table's arena.  `engine_of()` gives the bank's engine at
+    each scrape (a warm restart replaces the object).  The values are
+    snapshots written by the table-owning thread: observers never call
+    into the (unsynchronized) native table."""
+    for name, attr in _SLOT_COUNTERS:
+        store.counter_fn(
+            base + "." + name, lambda a=attr: getattr(engine_of(), a)
+        )
+    for name, attr in _SLOT_GAUGES:
+        store.gauge_fn(
+            base + "." + name, lambda a=attr: getattr(engine_of(), a)
+        )
+    store.counter_fn(
+        base + ".slot_gc.total_us",
+        lambda: engine_of().stat_slot_gc_ns // 1000,
+    )
+    store.gauge_fn(
+        base + ".num_slots", lambda: engine_of().model.num_slots
+    )
+    store.gauge_fn(
+        base + ".slot_fill_pct",
+        lambda: (
+            100
+            * engine_of().stat_live_keys
+            // max(1, engine_of().model.num_slots)
+        ),
+    )
+
+
 class CounterEngine:
     def __init__(
         self,
@@ -515,6 +566,21 @@ class CounterEngine:
         # GROUP so one rolled-over key counts once per batch, however
         # many lanes repeat it.  Monotonic; exported as a counter.
         self.stat_window_rollovers = 0
+        # Dedup groups launched, all launches summed: what
+        # stat_window_rollovers is a share of (one device lane a
+        # group).  Monotonic; exported as a counter.
+        self.stat_groups_launched = 0
+        # The collector's periodic slot GC (dispatcher._collect_loop;
+        # not the one assign runs itself on an empty free list): runs,
+        # leases it freed, time it took — monotonic counters.  And the
+        # native table's arena as of the last launch or GC: rehashes
+        # since the table was made, bytes held (the Python table has
+        # no arena and reads 0).
+        self.stat_slot_gc_runs = 0
+        self.stat_slot_gc_freed = 0
+        self.stat_slot_gc_ns = 0
+        self.stat_arena_compactions = 0
+        self.stat_arena_bytes = 0
         # Kernel shapes — (bucket, readback dtype) and the like — one
         # of whose launches has been read back: compiled, loaded and
         # known to finish.  Only calls of these arm the kernel
@@ -556,7 +622,24 @@ class CounterEngine:
         return self.slot_table.assign(key, now, expiry)
 
     def gc(self, now: int) -> int:
-        return self.slot_table.gc(now)
+        t0 = time.monotonic_ns()
+        freed = self.slot_table.gc(now)
+        self.stat_slot_gc_runs += 1
+        self.stat_slot_gc_freed += freed
+        self._read_table_stats()
+        self.stat_slot_gc_ns += time.monotonic_ns() - t0
+        return freed
+
+    def _read_table_stats(self) -> None:
+        """The slot table's own numbers into the plain ints the scrape
+        side reads lock-free.  The engine has a single toucher (the
+        dispatcher collector owns it; inline mode serializes via
+        tpu_cache._inline_locks), and only it calls into the table."""
+        table = self.slot_table
+        self.stat_live_keys = len(table)  # tpu-lint: disable=shared-state -- collector-owned engine
+        self.stat_evictions = table.evictions  # tpu-lint: disable=shared-state -- collector-owned engine
+        self.stat_arena_compactions = table.compactions  # tpu-lint: disable=shared-state -- collector-owned engine
+        self.stat_arena_bytes = table.arena_bytes  # tpu-lint: disable=shared-state -- collector-owned engine
 
     # -- device step ----------------------------------------------------
 
@@ -610,14 +693,12 @@ class CounterEngine:
             chunks.append(
                 (afters_dev, start, count, dedup, reassemble, shape)
             )
-            # Engine stats are plain ints on purpose: the engine has a
-            # single toucher (the dispatcher collector owns it; inline
-            # mode serializes via tpu_cache._inline_locks) and the
-            # scrape side reads them lock-free as gauges.
+            # Engine stats are plain ints on purpose (see
+            # _read_table_stats): one toucher, lock-free readers.
             self.stat_window_rollovers += int(np.count_nonzero(dedup.fresh))  # tpu-lint: disable=shared-state -- collector-owned engine
-        self.stat_live_keys = len(self.slot_table)  # tpu-lint: disable=shared-state -- collector-owned engine
-        self.stat_evictions = self.slot_table.evictions  # tpu-lint: disable=shared-state -- collector-owned engine
+        self._read_table_stats()
         self.stat_dedup_groups = sum(len(c[3].uniq_slots) for c in chunks)  # tpu-lint: disable=shared-state -- collector-owned engine
+        self.stat_groups_launched += self.stat_dedup_groups  # tpu-lint: disable=shared-state -- collector-owned engine
         return (batch.hits, batch.limits, batch.shadow, chunks, now)
 
     def submit_packed(
@@ -736,11 +817,11 @@ class CounterEngine:
                 (afters_dev, start, count, dedup, reassemble, shape)
             )
             self.stat_window_rollovers += int(np.count_nonzero(dedup.fresh))
-        self.stat_live_keys = len(table)
-        self.stat_evictions = table.evictions
+        self._read_table_stats()
         self.stat_dedup_groups = sum(
             len(d.uniq_slots) for _, _, d in dedups
         )
+        self.stat_groups_launched += self.stat_dedup_groups
         return (hits, limits, shadow, chunks, now)
 
     def step_complete(

@@ -111,6 +111,8 @@ def _signatures(lib: ctypes.CDLL) -> None:
     lib.sk_evictions.argtypes = [vp]
     lib.sk_arena_bytes.restype = i64
     lib.sk_arena_bytes.argtypes = [vp]
+    lib.sk_compactions.restype = i64
+    lib.sk_compactions.argtypes = [vp]
     lib.sk_gc.restype = i64
     lib.sk_gc.argtypes = [vp, i64]
     lib.sk_begin_batch.restype = None
@@ -416,6 +418,12 @@ class NativeSlotTable:
     def arena_bytes(self) -> int:
         """Key-arena footprint incl. uncompacted tombstone bytes."""
         return int(self._lib.sk_arena_bytes(self._handle))
+
+    @property
+    def compactions(self) -> int:
+        """Rehashes of the key map since construction; each compacts
+        the arena (tombstones and erased keys' bytes go)."""
+        return int(self._lib.sk_compactions(self._handle))
 
     def gc(self, now: int) -> int:
         return int(self._lib.sk_gc(self._handle, int(now)))

@@ -95,6 +95,11 @@ class PackedEntries:
 
 
 class SlotTable:
+    # A dict owns its keys: no arena to compact (the native table's
+    # gauges, read by CounterEngine._read_table_stats).
+    arena_bytes = 0
+    compactions = 0
+
     def __init__(self, num_slots: int, refresh_expiry: bool = False):
         """``refresh_expiry=True`` extends a live key's expiry on every
         assign (to the max of old and new): stable-stem algorithms

@@ -60,7 +60,7 @@ from .dispatcher import (
     WorkItem,
     run_items,
 )
-from .engine import CounterEngine, HostDecisions
+from .engine import CounterEngine, HostDecisions, register_slot_stats
 
 # Device code -> api Code without an enum __call__ per lane.
 _CODE_BY_VALUE = {c.value: c for c in Code}
@@ -1450,45 +1450,17 @@ class TpuRateLimitCache:
             self.fault_domain.register_stats(store, scope + ".fault")
         for idx, engine in enumerate(self.engines()):
             base = f"{scope}.bank{idx}"
-            # Cached snapshots updated by the table-owning thread —
-            # never call into the (unsynchronized) native table from
-            # observer threads.  Closures resolve the engine BY INDEX
-            # per scrape (self._engine_at): a supervised warm restart
-            # replaces the engine object, and the gauges must follow.
-            store.gauge_fn(
-                base + ".live_keys",
-                lambda i=idx: self._engine_at(i).stat_live_keys,
-            )
-            # Evictions are monotonic — a counter (paired with the
-            # num_slots capacity gauge below, so "about to exhaust
-            # TPU_NUM_SLOTS" is a dashboard alert, not a runtime
-            # error surprise).  Window rollovers likewise count fresh
-            # slot sightings (a new window's first batch appearance).
-            store.counter_fn(
-                base + ".evictions",
-                lambda i=idx: self._engine_at(i).stat_evictions,
-            )
-            store.counter_fn(
-                base + ".window_rollovers",
-                lambda i=idx: self._engine_at(i).stat_window_rollovers,
+            # Closures resolve the engine BY INDEX per scrape
+            # (self._engine_at): a supervised warm restart replaces
+            # the engine object, and the gauges must follow.
+            register_slot_stats(
+                store, base, lambda i=idx: self._engine_at(i)
             )
             # Over ratelimit.tpu.launch.rate: the share of launches
             # whose device trip the hand-off hid (engine.py).
             store.counter_fn(
                 base + ".readback_ready",
                 lambda i=idx: self._engine_at(i).stat_readback_ready,
-            )
-            store.gauge_fn(
-                base + ".num_slots",
-                lambda i=idx: self._engine_at(i).model.num_slots,
-            )
-            store.gauge_fn(
-                base + ".slot_fill_pct",
-                lambda i=idx: (
-                    100
-                    * self._engine_at(i).stat_live_keys
-                    // max(1, self._engine_at(i).model.num_slots)
-                ),
             )
             d = self._dispatchers.get(id(engine))
             if d is not None:

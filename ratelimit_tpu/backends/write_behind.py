@@ -66,7 +66,7 @@ from ..utils.time import (
     window_start,
 )
 from .dispatcher import LANE_DTYPE, BatchDispatcher, LanePack, WorkItem
-from .engine import CounterEngine
+from .engine import CounterEngine, register_slot_stats
 from .tpu_cache import _CODE_BY_VALUE
 
 # Prune the host view of expired windows every N reconciled batches.
@@ -399,26 +399,8 @@ class WriteBehindRateLimitCache:
 
     def register_stats(self, store, scope: str = "ratelimit.tpu") -> None:
         base = scope + ".bank0"
-        store.gauge_fn(base + ".live_keys", lambda: self.engine.stat_live_keys)
-        # Counter + capacity gauge pair (same surface as tpu_cache):
-        # slot exhaustion becomes a dashboard trend, not a surprise.
-        store.counter_fn(
-            base + ".evictions", lambda: self.engine.stat_evictions
-        )
-        store.counter_fn(
-            base + ".window_rollovers",
-            lambda: self.engine.stat_window_rollovers,
-        )
-        store.gauge_fn(
-            base + ".num_slots", lambda: self.engine.model.num_slots
-        )
-        store.gauge_fn(
-            base + ".slot_fill_pct",
-            lambda: (
-                100 * self.engine.stat_live_keys
-                // max(1, self.engine.model.num_slots)
-            ),
-        )
+        # The same slot-table surface as tpu_cache's banks.
+        register_slot_stats(store, base, lambda: self.engine)
         store.gauge_fn(
             base + ".dispatch_queue", lambda: self._dispatcher.queue_depth()
         )

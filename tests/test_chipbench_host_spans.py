@@ -53,6 +53,12 @@ PR27 = (
     "slot_fill_share.paced", "slot_evictions.paced", "gc_pause_ms.paced", "snapshot_hold_ms.paced",
     "snapshot_timeouts.paced", "device_submit_p99_us.paced", "readback_p99_us.paced",
 )
+# PR 33 added the cell `uniform-10k-persecond.paced`: its name appended
+# to every metric's cells, and four slot-churn metrics after them: three
+# that every cell reports, and the arena's rehashes, which only its own
+# traffic moves (0 in the other two on the chip).
+PR33 = ("rollover_share.paced", "slot_gc_us.paced", "slot_gc_freed.paced", "arena_compactions.paced")
+PACED = ["tenants-zipf.paced", "mixed-1m.paced", "uniform-10k-persecond.paced"]
 H = "ratelimit_server.ShouldRateLimit."
 
 
@@ -132,17 +138,23 @@ def test_pr24_and_pr26_entries_are_appended_and_the_rest_wait_outside_the_benchm
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     names = [m["name"] for m in bench["per_layer"]]
+    assert names[-len(PR33):] == list(PR33)
+    for m in bench["per_layer"][-len(PR33):]:
+        mine = m["name"] == "arena_compactions.paced"
+        assert m["moves"] == "p50_ms" and m["workloads"] == (PACED[2:] if mine else PACED)
+    before33 = bench["per_layer"][:-len(PR33)]
+    names = names[:-len(PR33)]
     n24, n26, n27 = len(PR24), len(PR26), len(PR27)
     assert names[-n24 - n26 - n27:-n26 - n27] == [n + ".paced" for n in PR24]
     assert names[-n26 - n27:-n27] == list(PR26)
     assert names[-n27:] == list(PR27)
     waiting = {n for n in LAUNCH_LEGS} | {"incident_stall_ms", "incident_stall_ms.paced"}
     assert not waiting & set(names)
-    for m in bench["per_layer"][-n24 - n26 - n27:-n27]:
+    for m in before33[-n24 - n26 - n27:-n27]:
         assert m["moves"] == "p50_ms"
-        assert m["workloads"] == ["tenants-zipf.paced", "mixed-1m.paced"]
-    for m in bench["per_layer"][-n27:]:
-        assert m["moves"] == "p50_ms" and m["workloads"] == ["mixed-1m.paced"]
+        assert m["workloads"] == PACED
+    for m in before33[-n27:]:
+        assert m["moves"] == "p50_ms" and m["workloads"] == PACED[1:]
     by_name = {m["name"]: m for m in bench["per_layer"]}
     for n in PR26[:-1]:
         m = by_name[n]

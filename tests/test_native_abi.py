@@ -180,6 +180,7 @@ EXPORTS = {
     "sk_len",
     "sk_evictions",
     "sk_arena_bytes",
+    "sk_compactions",
     "sk_gc",
     "sk_begin_batch",
     "sk_end_batch",
