@@ -19,6 +19,8 @@ FROM python:3.12-slim
 
 ARG JAX_EXTRA=jax
 # curl: the baked-in integration-test scripts drive the live surfaces.
+# pyyaml: the wheel's bundled libyaml is what config/loader.py parses rule
+# files with (yaml.CSafeLoader); no libyaml package is installed for it.
 RUN apt-get update && apt-get install -y --no-install-recommends curl \
     && rm -rf /var/lib/apt/lists/* \
     && pip install --no-cache-dir ${JAX_EXTRA} numpy pyyaml grpcio protobuf

@@ -24,6 +24,7 @@ operators migrating from the reference see familiar diagnostics.
 from __future__ import annotations
 
 import itertools
+import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence
 
@@ -32,6 +33,32 @@ import yaml
 from ..api import Descriptor, RateLimit, Unit, UNIT_VALUES
 from ..models.registry import ALGORITHM_NAMES, DEFAULT_ALGORITHM
 from ..stats.manager import Manager, RateLimitStats
+
+# Rule files go through libyaml's scanner and parser where PyYAML was
+# built with it (the wheels bundle it): the pure-Python scanner reads
+# ~0.3 MB/s, which at 100,000 rules was most of a start or a reload.
+# CSafeLoader is the C parser under the SAME Python SafeConstructor and
+# Resolver classes as SafeLoader, so the tree — scalars, key order,
+# duplicate keys, alias sharing — is the one safe_load built
+# (tests/test_config_parsers.py).  Chosen once, from what the
+# installation has; no setting.
+C_PARSER = bool(yaml.__with_libyaml__)
+
+
+def _parse_yaml(content):
+    """One rule document -> its tree.  A document libyaml refuses is
+    parsed again in Python, so the yaml.YAMLError raised is the Python
+    parser's either way: libyaml words syntax errors differently and
+    quotes no snippet, and operators grep these texts."""
+    if C_PARSER:
+        try:
+            return yaml.load(content, Loader=yaml.CSafeLoader)
+        except (yaml.YAMLError, UnicodeError):
+            # UnicodeError: the C parser encodes a str before it reads
+            # it, and a lone surrogate fails there, not in its reader.
+            pass
+    return yaml.load(content, Loader=yaml.SafeLoader)
+
 
 # Whitelisted YAML keys (reference config_impl.go:49-59; `algorithm`
 # and `shadow` are the pluggable-limiter extension — see
@@ -189,16 +216,22 @@ class RateLimitConfig:
         # ladder; overload/controller.py).  Every loaded domain has an
         # entry — explicit ``priority:`` or DEFAULT_DOMAIN_PRIORITY.
         self.priorities: Dict[str, int] = {}
+        # What the load cost and built, for the service's config_*
+        # gauges: seconds inside the YAML parser, rules in the trie.
+        self.parse_s = 0.0
+        self.n_rules = 0
 
     # -- loading ---------------------------------------------------------
 
     def load_file(self, file: ConfigFile) -> None:
         """Parse + validate one YAML file into the trie
         (reference loadConfig, config_impl.go:200-232)."""
+        t = time.perf_counter()
         try:
-            raw = yaml.safe_load(file.content)
+            raw = _parse_yaml(file.content)
         except yaml.YAMLError as e:
             raise _error(file, f"error loading config file: {e}") from None
+        self.parse_s += time.perf_counter() - t
 
         if raw is None:
             raw = {}
@@ -318,6 +351,7 @@ class RateLimitConfig:
                     algorithm=algorithm,
                     algo_shadow=algo_shadow,
                 )
+                self.n_rules += 1
 
             child = _Node()
             child.rule = rule

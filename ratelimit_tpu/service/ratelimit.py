@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import logging
 import threading
+import time
 from typing import Callable, List, Optional
 
 from ..api import (
@@ -25,7 +26,13 @@ from ..api import (
     RateLimitRequest,
     RateLimitResponse,
 )
-from ..config.loader import ConfigError, ConfigFile, RateLimitConfig, load_config
+from ..config.loader import (
+    C_PARSER,
+    ConfigError,
+    ConfigFile,
+    RateLimitConfig,
+    load_config,
+)
 from ..observability import TRACER
 from ..stats.manager import Manager
 from ..utils.time import RealTimeSource, TimeSource, calculate_reset
@@ -113,6 +120,7 @@ class RateLimitService:
         self.reload_config()
 
     def reload_config(self) -> None:
+        t_load = time.perf_counter()
         try:
             files: List[ConfigFile] = []
             snapshot = self.runtime.snapshot()
@@ -127,6 +135,10 @@ class RateLimitService:
             logger.error("error loading new configuration from runtime: %s", e)
             return
         self.stats.config_load_success.inc()
+        self.stats.config_load_ms.set(1e3 * (time.perf_counter() - t_load))
+        self.stats.config_parse_ms.set(1e3 * new_config.parse_s)
+        self.stats.config_rules.set(new_config.n_rules)
+        self.stats.config_c_parser.set(C_PARSER)
         if self.slo is not None:
             # Adopt the new configured domain set BEFORE the swap so a
             # request racing the reload finds its domain interned.

@@ -396,6 +396,10 @@ class ServiceStats:
     __slots__ = (
         "config_load_success",
         "config_load_error",
+        "config_load_ms",
+        "config_parse_ms",
+        "config_rules",
+        "config_c_parser",
         "should_rate_limit",
         "global_shadow_mode",
     )
@@ -403,6 +407,13 @@ class ServiceStats:
     def __init__(self, scope: str, store: StatsStore):
         self.config_load_success = store.counter(scope + ".config_load_success")
         self.config_load_error = store.counter(scope + ".config_load_error")
+        # Gauges of the last successful load (service.reload_config):
+        # its wall time, the share of it inside the YAML parser, the
+        # rules it built, and which parser ran (1 = libyaml's).
+        self.config_load_ms = store.gauge(scope + ".config_load_ms")
+        self.config_parse_ms = store.gauge(scope + ".config_parse_ms")
+        self.config_rules = store.gauge(scope + ".config_rules")
+        self.config_c_parser = store.gauge(scope + ".config_c_parser")
         self.should_rate_limit = ShouldRateLimitStats(
             scope + ".call.should_rate_limit", store
         )
