@@ -433,6 +433,7 @@ _SLOT_COUNTERS = (
     ("evictions", "stat_evictions"),
     ("window_rollovers", "stat_window_rollovers"),
     ("dedup_groups", "stat_groups_launched"),
+    ("padded_lanes", "stat_padded_lanes"),
     ("slot_gc.runs", "stat_slot_gc_runs"),
     ("slot_gc.freed", "stat_slot_gc_freed"),
     ("arena.compactions", "stat_arena_compactions"),
@@ -570,6 +571,11 @@ class CounterEngine:
         # stat_window_rollovers is a share of (one device lane a
         # group).  Monotonic; exported as a counter.
         self.stat_groups_launched = 0
+        # The bucket each device step ran at, all launches summed:
+        # stat_groups_launched over it is the share of device lanes
+        # that carried a group, the rest being padding.  Monotonic;
+        # exported as a counter.
+        self.stat_padded_lanes = 0
         # The collector's periodic slot GC (dispatcher._collect_loop;
         # not the one assign runs itself on an empty free list): runs,
         # leases it freed, time it took — monotonic counters.  And the
@@ -696,6 +702,7 @@ class CounterEngine:
             # Engine stats are plain ints on purpose (see
             # _read_table_stats): one toucher, lock-free readers.
             self.stat_window_rollovers += int(np.count_nonzero(dedup.fresh))  # tpu-lint: disable=shared-state -- collector-owned engine
+            self.stat_padded_lanes += shape[0]  # tpu-lint: disable=shared-state -- collector-owned engine
         self._read_table_stats()
         self.stat_dedup_groups = sum(len(c[3].uniq_slots) for c in chunks)  # tpu-lint: disable=shared-state -- collector-owned engine
         self.stat_groups_launched += self.stat_dedup_groups  # tpu-lint: disable=shared-state -- collector-owned engine
@@ -817,6 +824,7 @@ class CounterEngine:
                 (afters_dev, start, count, dedup, reassemble, shape)
             )
             self.stat_window_rollovers += int(np.count_nonzero(dedup.fresh))
+            self.stat_padded_lanes += shape[0]
         self._read_table_stats()
         self.stat_dedup_groups = sum(
             len(d.uniq_slots) for _, _, d in dedups

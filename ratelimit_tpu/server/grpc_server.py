@@ -59,6 +59,9 @@ class ServerReporter:
         self._prepare = store.histogram(base + ".prepare_ms")
         self._wake = store.histogram(base + ".wake_ms")
         self._apply = store.histogram(base + ".apply_ms")
+        # Descriptors of the requests answered: what the per-request
+        # histogram totals above divide by for a cost per descriptor.
+        self.descriptors = store.counter(base + ".descriptors")
 
     def observe(self, method: str, elapsed_s: float) -> None:
         base = f"{self.scope}.{method}"
@@ -248,6 +251,7 @@ def _ratelimit_handler(
                         submitted_ns, entry_ns, service_in_ns,
                         service_out_ns, request.legs,
                     )
+                    reporter.descriptors.add(len(request.descriptors))
                 # Decision flight recorder + per-domain SLO rollups,
                 # stamped HERE next to the per-phase histogram sink:
                 # everything is already on hand (domain, code, total

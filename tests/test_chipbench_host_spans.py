@@ -58,10 +58,17 @@ PR27 = (
 # that every cell reports, and the arena's rehashes, which only its own
 # traffic moves (0 in the other two on the chip).
 PR33 = ("rollover_share.paced", "slot_gc_us.paced", "slot_gc_freed.paced", "arena_compactions.paced")
-PACED = ["tenants-zipf.paced", "mixed-1m.paced", "uniform-10k-persecond.paced"]
+PACED = ["tenants-zipf.paced", "mixed-1m.paced", "uniform-10k-persecond.paced", "bulk-recipients.paced"]
 # PR 35 appended two readings of the rule load: they move `setup_s`, in
 # the one cell whose load is large enough to read.
 PR35 = ("config_load_us_per_rule.paced", "config_parse_share.paced")
+# PR 36 added the cell `bulk-recipients.paced` (64 descriptors a
+# request): its name appended to every metric that moves `p50_ms`, and
+# six readings of the per-descriptor path after PR 35's two, in all four cells.
+PR36 = (
+    "lanes_per_launch.paced", "lane_fill_share.paced", "prepare_us_per_descriptor.paced",
+    "apply_us_per_descriptor.paced", "decode_us.paced", "serialize_us.paced",
+)
 H = "ratelimit_server.ShouldRateLimit."
 
 
@@ -140,6 +147,10 @@ def test_pr26_entry_reads_on_pr24s_records_and_on_the_change(name):
 def test_pr24_and_pr26_entries_are_appended_and_the_rest_wait_outside_the_benchmark():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
+    assert [m["name"] for m in bench["per_layer"][-len(PR36):]] == list(PR36)
+    for m in bench["per_layer"][-len(PR36):]:
+        assert (m["moves"], m["workloads"]) == ("p50_ms", PACED)
+    bench["per_layer"] = bench["per_layer"][:-len(PR36)]
     assert [m["name"] for m in bench["per_layer"][-len(PR35):]] == list(PR35)
     for m in bench["per_layer"][-len(PR35):]:
         assert (m["moves"], m["layer"], m["workloads"]) == ("setup_s", "whole server", PACED[:1])

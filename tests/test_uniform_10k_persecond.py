@@ -214,16 +214,19 @@ def test_the_cell_is_in_the_manifest_and_reports_every_paced_metric():
     (cell,) = [w for w in bench["workloads"] if w["name"] == CELL]
     assert (cell["config"], cell["traffic"], cell["chips"]) == (CONFIG, MIX, 1)
     assert f"{load_json('traffic', MIX)['rate_rps']} requests/s" in cell["why"]
-    assert bench["workloads"][-1] is cell and bench["configs"][-1] is entry
+    # (PR 36 appended `bulk-recipients` after it.)
+    assert bench["workloads"][2] is cell and bench["configs"][2] is entry
     p50 = next(m for m in bench["end_to_end"] if m["name"] == "p50_ms")
-    assert p50["workloads"][-1] == CELL
+    assert p50["workloads"][2] == CELL
     # Every metric of the served path; the start-up metrics (they move
     # `setup_s`, PR 35) list only the cell whose rule load they read.
     paced = [m for m in bench["per_layer"] if m["moves"] == "p50_ms"]
     for m in paced:
         assert CELL in m["workloads"], m["name"]
-    assert [m["name"] for m in paced[-len(NEW_METRICS):]] == NEW_METRICS
-    for m in paced[-len(NEW_METRICS):]:
+    first = [m["name"] for m in paced].index(NEW_METRICS[0])
+    mine = paced[first : first + len(NEW_METRICS)]  # PR 36's six come after them
+    assert [m["name"] for m in mine] == NEW_METRICS and first == len(paced) - len(NEW_METRICS) - 6
+    for m in mine:
         assert (m["source"], m["layer"]) == ("program_counter", "engine (host)")
 
 
