@@ -28,6 +28,7 @@
 
 #include <cstdint>
 #include <cmath>
+#include <ctime>
 #include <vector>
 
 namespace {
@@ -51,7 +52,9 @@ extern "C" {
 //
 // Outputs (all length n): codes, limit_remaining, befores, afters,
 // over_limit, near_limit, within_limit, shadow_mode stat deltas, and
-// the set-local-cache marker.
+// the set-local-cache marker; out_done_ns (null: not wanted) takes
+// CLOCK_MONOTONIC ns as the call's last act — see
+// sk_assign_dedup_batch: the GIL's return, measured from outside it.
 void sk_decide_reconstruct(
     const uint32_t* afters_g, const uint64_t* totals, int64_t g,
     const int32_t* inv, const uint64_t* prefix, const uint32_t* hits,
@@ -59,7 +62,8 @@ void sk_decide_reconstruct(
     float near_ratio, int32_t ok_code, int32_t over_code,
     int32_t* out_codes, int64_t* out_remaining, int64_t* out_befores,
     int64_t* out_afters, int64_t* out_over, int64_t* out_near,
-    int64_t* out_within, int64_t* out_shadow, uint8_t* out_set_lc) {
+    int64_t* out_within, int64_t* out_shadow, uint8_t* out_set_lc,
+    int64_t* out_done_ns) {
   // Per-group 'before' once (engine.py _decide_host): saturated groups
   // pin before at u32 max so every lane lands fully-over.
   std::vector<uint64_t> before_g(static_cast<size_t>(g));
@@ -114,6 +118,11 @@ void sk_decide_reconstruct(
     out_within[i] = within_d;
     out_shadow[i] = shadow_d;
     out_set_lc[i] = set_lc;
+  }
+  if (out_done_ns) {
+    timespec ts;
+    clock_gettime(CLOCK_MONOTONIC, &ts);
+    *out_done_ns = static_cast<int64_t>(ts.tv_sec) * 1000000000 + ts.tv_nsec;
   }
 }
 

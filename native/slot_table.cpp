@@ -36,6 +36,7 @@
 #include <algorithm>
 #include <cstdint>
 #include <cstring>
+#include <ctime>
 #include <numeric>
 #include <queue>
 #include <random>
@@ -432,6 +433,11 @@ int64_t sk_assign_batch(void* tp, const uint8_t* key_blob,
 //                   batch order (Redis pipeline-order semantics)
 //   out_freshg[g]   group had a freshly-assigned slot
 //   out_limitmax[g] max limit across the group's lanes
+//   out_done_ns     CLOCK_MONOTONIC ns, written as the call's LAST act
+//                   (null: not wanted).  ctypes takes the GIL back
+//                   before Python runs again: time.monotonic_ns() the
+//                   moment the call returns, minus this, is how long
+//                   the calling thread waited for it.
 // Returns g (number of groups), or -1 on table exhaustion.
 int64_t sk_assign_dedup_batch(void* tp, const uint8_t* key_blob,
                               const int64_t* key_lens, int64_t n, int64_t now,
@@ -439,7 +445,7 @@ int64_t sk_assign_dedup_batch(void* tp, const uint8_t* key_blob,
                               const uint32_t* limits, int32_t* out_group,
                               int32_t* out_uniq, uint64_t* out_totals,
                               uint64_t* out_prefix, uint8_t* out_freshg,
-                              uint32_t* out_limitmax) {
+                              uint32_t* out_limitmax, int64_t* out_done_ns) {
   SlotTable* t = static_cast<SlotTable*>(tp);
   t->begin_call_pins();
 
@@ -500,6 +506,11 @@ int64_t sk_assign_dedup_batch(void* tp, const uint8_t* key_blob,
     out_limitmax[k] = g_limit[order[k]];
   }
   for (int64_t i = 0; i < n; ++i) out_group[i] = rank[lane_gid[i]];
+  if (out_done_ns) {
+    timespec ts;
+    clock_gettime(CLOCK_MONOTONIC, &ts);
+    *out_done_ns = static_cast<int64_t>(ts.tv_sec) * 1000000000 + ts.tv_nsec;
+  }
   return g;
 }
 

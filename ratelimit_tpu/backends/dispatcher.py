@@ -416,6 +416,7 @@ class BatchDispatcher:
         on_state=None,
         eager_idle: bool = True,
         stamp_clock=None,
+        cpu_clock: bool = False,
     ):
         """`on_state(healthy: bool, reason: str)` is the backend-health
         seam (the Redis pool active-connection health analog,
@@ -493,8 +494,10 @@ class BatchDispatcher:
         # `stamp_clock` is the injectable MonotonicClock seam so
         # hang-detection tests run on synthetic time.
         self._stamp_now = (stamp_clock or REAL_MONOTONIC).now
-        self._launch_watch = CallWatch(self._stamp_now)
-        self._complete_watch = CallWatch(self._stamp_now)
+        # `cpu_clock` (DEBUG_PROFILING=1, the traced run): the two
+        # threads read their CPU clock at both ends of a device call.
+        self._launch_watch = CallWatch(self._stamp_now, cpu_clock)
+        self._complete_watch = CallWatch(self._stamp_now, cpu_clock)
         # Intake is a plain list + condition variable, drained by the
         # collector in ONE swap per wakeup: queue.Queue pays a lock
         # acquisition per get (~0.8 ms per 1024-item batch on the
@@ -612,14 +615,26 @@ class BatchDispatcher:
                 age = open_s
         return age
 
-    def watch_report(self, now: Optional[float] = None) -> List[dict]:
+    def watch_report(
+        self,
+        now: Optional[float] = None,
+        watchdog_late_ms: Optional[float] = None,
+    ) -> List[dict]:
         """Both dispatcher threads as a hang fault records them: how
         long each has been inside a device call (the watchdog's own
         stamps), and the last leg each COMPLETED — its span name, how
         long it took, how long ago it ended.  A device call that ended
         normally milliseconds ago on one thread, while the other has
         been "in" one for seconds, reads very differently from two
-        threads that both stopped at the same instant."""
+        threads that both stopped at the same instant.  A thread with a
+        bracket open also shows what that bracket is made of so far
+        (CallWatch.ledger: ``wall_ms``, and of the ``observed_ms`` since
+        the watchdog first saw it open ``on_cpu_ms`` + ``off_cpu_ms``,
+        its ``last_gil_return_us``, and `watchdog_late_ms`, how late
+        the watchdog's own last tick ran): a call late while its thread
+        was on the CPU was the host's; one all off the CPU with the GIL
+        back in microseconds just before was the runtime's or the
+        device's.  The reads are this (the watchdog's) thread's."""
         if now is None:
             now = self._stamp_now()
         now_ns = time.monotonic_ns()
@@ -638,8 +653,15 @@ class BatchDispatcher:
                 row["last_leg_ended_s_ago"] = round(
                     (now_ns - leg[1]) / 1e9, 3
                 )
+            row.update(watch.ledger(watchdog_late_ms))
             report.append(row)
         return report
+
+    def glance(self) -> None:
+        """The watchdog's look at both threads' open brackets, at a
+        tick it already makes (CallWatch.glance)."""
+        self._launch_watch.glance()
+        self._complete_watch.glance()
 
     def kill(self, exc: BaseException) -> None:
         """Abandon this dispatcher WITHOUT joining its threads: mark
@@ -799,6 +821,12 @@ class BatchDispatcher:
                     launch_id,
                     int(getattr(engine, "stat_assign_ns", 0)),
                     int(getattr(engine, "stat_device_submit_ns", 0)),
+                    device_submit_cpu_ns=int(
+                        getattr(engine, "stat_device_submit_cpu_ns", -1)
+                    ),
+                    assign_gil_ns=int(
+                        getattr(engine, "stat_assign_gil_ns", -1)
+                    ),
                 )
             self._note_step(False)
         elif token is not None:
@@ -810,7 +838,9 @@ class BatchDispatcher:
                 # FIFO, so entry k always meets its own batch.  The
                 # last field is the instant the step was in flight
                 # (submit_items' own stamp): the completer's start
-                # minus it is handoff_ns.
+                # minus it is handoff_ns.  Then what the engine
+                # measured inside its device-call bracket and after
+                # its native assign.
                 t_launched = stamps.launched_ns
                 self._launch_meta.append(  # tpu-lint: disable=shared-state -- deque append/popleft are GIL-atomic; one FIFO producer (collector) and one FIFO consumer (completer)
                     (
@@ -824,6 +854,8 @@ class BatchDispatcher:
                         int(getattr(engine, "stat_assign_ns", 0)),
                         int(getattr(engine, "stat_device_submit_ns", 0)),
                         t_launched,
+                        int(getattr(engine, "stat_device_submit_cpu_ns", -1)),
+                        int(getattr(engine, "stat_assign_gil_ns", -1)),
                     )
                 )
             with self._state_lock:
@@ -924,6 +956,7 @@ class BatchDispatcher:
 
     def _collect_loop(self) -> None:
         try:
+            self._launch_watch.bind()
             while True:
                 batch, tokens, stopping = self._collect()
                 if batch:
@@ -965,6 +998,7 @@ class BatchDispatcher:
     def _complete_loop(self) -> None:
         try:
             span = SPANS.span
+            self._complete_watch.bind()
             while True:
                 with span(_spans.COMPLETE_IDLE, self.launch_bank):
                     kind, payload, token = self._completion_q.get()
@@ -986,7 +1020,10 @@ class BatchDispatcher:
                             # Recorder attached between this batch's
                             # launch and its completion: no front-half
                             # measurements, still one record.
-                            meta = (0, len(payload), 0, 0, 0, 0, 0, 0, 0, t0)
+                            meta = (
+                                0, len(payload), 0, 0, 0, 0, 0, 0, 0, t0,
+                                -1, -1,
+                            )
                         lr.record(
                             self.launch_bank,
                             self.launch_algo,
@@ -1004,6 +1041,10 @@ class BatchDispatcher:
                             t0 - meta[9],
                             int(getattr(engine, "stat_readback_ns", 0)),
                             int(getattr(engine, "stat_decide_ns", 0)),
+                            meta[10],
+                            int(getattr(engine, "stat_readback_cpu_ns", -1)),
+                            meta[11],
+                            int(getattr(engine, "stat_decide_gil_ns", -1)),
                         )
                     with self._state_lock:
                         self._inflight -= 1

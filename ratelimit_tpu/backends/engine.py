@@ -20,6 +20,7 @@ import numpy as np
 from ..models.fixed_window import DeviceBatch, FixedWindowModel
 from ..observability import spans as _spans
 from ..observability.spans import SPANS
+from ..utils.threads import ThreadClock
 from .slot_table import PackedEntries
 
 logger = logging.getLogger("ratelimit.engine")
@@ -81,24 +82,106 @@ class CallWatch:
     ``last_leg`` is the thread's last COMPLETED leg — (span name,
     monotonic_ns it ended, its duration in ns) — which a hang fault
     copies beside the open background work: what the thread had just
-    done when its device call stopped returning."""
+    done when its device call stopped returning.
 
-    __slots__ = ("since", "gc_ns", "last_leg", "_now")
+    The bracket's other clocks stand beside it: ``wall0_ns``
+    (``time.monotonic_ns`` as the call began; 0 = no bracket open,
+    armed or not), ``clock``, the thread's ThreadClock once it has
+    called ``bind()``, and ``last_gil_ns``, the GIL-return time of the
+    thread's last native call (-1: none).  From them a hang fault reads
+    what the open bracket is made of (``ledger``): a call late while
+    its thread was on the CPU was the host's; one that is all off the
+    CPU, with the GIL back in microseconds just before, was the
+    runtime's or the device's.  They judge nothing: ``age`` is the
+    deadline's one clock.
 
-    def __init__(self, now: Callable[[], float]):
+    The thread itself reads its CPU clock (``cpu0_ns``, for the
+    bracket's on-CPU sums: CounterEngine._device_call) only where
+    ``cpu_clock`` is set — the traced run, DEBUG_PROFILING=1 — and why
+    not always: on the chip's host (gVisor) one read is a trap into the
+    sentry, 6 us in a tight loop and some 40 us between a served
+    launch's other work — four a launch cost +4% of ``p50_ms`` in 12 of
+    12 same-seed pairs (PERF.md section 6, PR 41).  The witness does
+    not need it: the watchdog looks once (``glance``) at a bracket it
+    finds open at a tick it already makes — one bracket in a hundred,
+    off the request's path — and reads the thread's clock itself."""
+
+    __slots__ = (
+        "since", "gc_ns", "last_leg", "_now", "cpu_clock",
+        "wall0_ns", "cpu0_ns", "clock", "last_gil_ns", "_seen",
+    )
+
+    def __init__(self, now: Callable[[], float], cpu_clock: bool = False):
         self.since: Optional[float] = None
         self.gc_ns = 0  # SPANS.gc_pause_ns() when the open call began
         self.last_leg: Optional[tuple] = None
         self._now = now
+        self.cpu_clock = bool(cpu_clock)
+        self.wall0_ns = 0
+        self.cpu0_ns = 0
+        self.clock: Optional[ThreadClock] = None
+        self.last_gil_ns = -1
+        # (wall0_ns of the bracket looked at, monotonic_ns, the thread's
+        # CPU clock then): the watchdog's glance.
+        self._seen = (0, 0, 0)
+
+    def bind(self) -> None:
+        """Called once by the thread this watch belongs to."""
+        self.clock = ThreadClock()  # tpu-lint: disable=shared-state -- one CallWatch per dispatcher thread, bound once by that thread
 
     def begin(self, armed: bool) -> None:
-        # gc_ns before since: a reader that sees this call's `since`
-        # sees its gc_ns too.
+        # gc_ns (and the ledger's two) before since: a reader that sees
+        # this call's `since` sees them too.
         self.gc_ns = SPANS.gc_pause_ns()  # tpu-lint: disable=shared-state -- one CallWatch per dispatcher thread: single writer, lock-free readers
+        if self.cpu_clock:
+            self.cpu0_ns = time.thread_time_ns()  # tpu-lint: disable=shared-state -- same single-writer stamp
+        self.wall0_ns = time.monotonic_ns()  # tpu-lint: disable=shared-state -- same single-writer stamp
         self.since = self._now() if armed else None  # tpu-lint: disable=shared-state -- same single-writer stamp
 
     def end(self) -> None:
         self.since = None  # tpu-lint: disable=shared-state -- same single-writer stamp
+        self.wall0_ns = 0  # tpu-lint: disable=shared-state -- same single-writer stamp
+
+    def glance(self) -> None:
+        """The watchdog's, at a tick it already makes: a bracket it
+        finds open and has not looked at yet gets ONE reading of its
+        thread's CPU clock — the baseline ``ledger`` subtracts should
+        the call turn out stuck.  A healthy bracket lasts under a
+        millisecond and a tick comes 8 times a second: one bracket in a
+        hundred is ever looked at, by the watchdog's thread, off the
+        request's path; a call stuck for a deadline has had a tick land
+        in it."""
+        wall0, clock = self.wall0_ns, self.clock
+        if wall0 and clock is not None and self._seen[0] != wall0:
+            cpu = clock.ns()
+            if cpu is not None:
+                self._seen = (wall0, time.monotonic_ns(), cpu)  # tpu-lint: disable=shared-state -- the watchdog thread's own slot
+
+    def ledger(self, watchdog_late_ms: Optional[float] = None) -> dict:
+        """The open bracket so far, read from OUTSIDE the thread (the
+        watchdog, inside record_fault only), in ms: ``wall_ms`` since
+        the call began, and of the ``observed_ms`` since the watchdog's
+        glance at this bracket, ``on_cpu_ms`` (what the stuck thread's
+        CPU clock gained) + ``off_cpu_ms`` (the rest: blocked, or
+        runnable and not running — this host's kernel does not tell the
+        two apart).  {} with no bracket open or no bound thread."""
+        wall0, clock = self.wall0_ns, self.clock
+        if not wall0 or clock is None:
+            return {}
+        now_ns = time.monotonic_ns()
+        out = {"wall_ms": round((now_ns - wall0) / 1e6, 3)}
+        seen, cpu_now = self._seen, clock.ns()
+        if seen[0] == wall0 and cpu_now is not None:
+            span = (now_ns - seen[1]) / 1e6
+            on_cpu = max(0, cpu_now - seen[2]) / 1e6
+            out["observed_ms"] = round(span, 3)
+            out["on_cpu_ms"] = round(on_cpu, 3)
+            out["off_cpu_ms"] = round(max(0.0, span - on_cpu), 3)
+        if self.last_gil_ns >= 0:
+            out["last_gil_return_us"] = round(self.last_gil_ns / 1e3, 1)
+        if watchdog_late_ms is not None:
+            out["watchdog_late_ms"] = watchdog_late_ms
+        return out
 
     def age(self, now: float) -> tuple:
         """(seconds the open call has been running, seconds of them
@@ -270,8 +353,11 @@ def _decide_host(
     shadow: np.ndarray,
     near_ratio: float,
     dedup: Optional["_Dedup"] = None,
+    stamp=None,
 ) -> HostDecisions:
-    """Threshold state machine on host numpy, from device `afters`.
+    """Threshold state machine on host numpy, from device `afters`
+    (`stamp`: the completing thread's native_slot_table.ReturnStamp,
+    which the native pass leaves its GIL-return time in).
 
     The device returned one (possibly saturated) `after` per UNIQUE
     slot; per-lane values are rebuilt as
@@ -319,6 +405,7 @@ def _decide_host(
                 near_ratio,
                 int(Code.OK),
                 int(Code.OVER_LIMIT),
+                stamp,
             )
             return HostDecisions(
                 codes=codes,
@@ -442,17 +529,41 @@ _SLOT_GAUGES = (
     ("live_keys", "stat_live_keys"),
     ("arena.bytes", "stat_arena_bytes"),
 )
+# What each device-call bracket was made of, all launches summed (ns):
+# the bracket's wall time beside the on-CPU time of the thread that
+# stood in it (wall - cpu: off the CPU; both sums advance only in the
+# traced run, CallWatch.cpu_clock, together or not at all) — once over
+# every launch, once over the readbacks of the launches readback_ready
+# counts, whose copy had arrived: what those waited for was not the
+# device — and the GIL-return times of the one native call a launch
+# makes on each thread (native_slot_table.ReturnStamp; count 0 on the
+# Python table).  Plain sums: where the CPU clock ticks coarsely (10 ms
+# under gVisor) one launch's reading is 0 or a whole tick, and a reader
+# subtracts deltas of sums, never one launch's two readings.
+_LEG_COUNTERS = (
+    ("device_submit.wall_ns", "total_submit_wall_ns"),
+    ("device_submit.cpu_ns", "total_submit_cpu_ns"),
+    ("readback.wall_ns", "total_readback_wall_ns"),
+    ("readback.cpu_ns", "total_readback_cpu_ns"),
+    ("readback_ready.wall_ns", "total_ready_wall_ns"),
+    ("readback_ready.cpu_ns", "total_ready_cpu_ns"),
+    ("assign_gil.total_ns", "total_assign_gil_ns"),
+    ("assign_gil.count", "count_assign_gil"),
+    ("decide_gil.total_ns", "total_decide_gil_ns"),
+    ("decide_gil.count", "count_decide_gil"),
+)
 
 
 def register_slot_stats(store, base: str, engine_of: Callable) -> None:
     """Register a bank's slot-table family under `base`
     (ratelimit.tpu.bank<i>): occupancy and capacity, evictions, window
     rollovers over dedup groups launched, the collector's slot GC, the
-    native table's arena.  `engine_of()` gives the bank's engine at
+    native table's arena, and what the device-call brackets were made
+    of (_LEG_COUNTERS).  `engine_of()` gives the bank's engine at
     each scrape (a warm restart replaces the object).  The values are
     snapshots written by the table-owning thread: observers never call
     into the (unsynchronized) native table."""
-    for name, attr in _SLOT_COUNTERS:
+    for name, attr in _SLOT_COUNTERS + _LEG_COUNTERS:
         store.counter_fn(
             base + "." + name, lambda a=attr: getattr(engine_of(), a)
         )
@@ -556,6 +667,29 @@ class CounterEngine:
         self.stat_device_submit_ns = 0
         self.stat_readback_ns = 0
         self.stat_decide_ns = 0
+        # Beside them, same writers, same readers: the on-CPU time of
+        # the thread inside each device-call bracket
+        # (time.thread_time_ns; -1 unless the watch's cpu_clock is set:
+        # the traced run) and the GIL-return time of the launch's
+        # native assign / decide (-1: the Python table, no native
+        # pass) — the record's device_submit_cpu_ns / readback_cpu_ns /
+        # assign_gil_ns / decide_gil_ns.  Then their sums over all
+        # launches, exported as counters (_LEG_COUNTERS).
+        self.stat_device_submit_cpu_ns = -1
+        self.stat_readback_cpu_ns = -1
+        self.stat_assign_gil_ns = -1
+        self.stat_decide_gil_ns = -1
+        self.total_submit_wall_ns = 0
+        self.total_submit_cpu_ns = 0
+        self.total_readback_wall_ns = 0
+        self.total_readback_cpu_ns = 0
+        self.total_ready_wall_ns = 0
+        self.total_ready_cpu_ns = 0
+        self.total_assign_gil_ns = 0
+        self.count_assign_gil = 0
+        self.total_decide_gil_ns = 0
+        self.count_decide_gil = 0
+        self._decide_stamp = None  # the completing thread's ReturnStamp
         # Launches whose device step had finished (is_ready(), asked
         # once before the wait) when the completer took them up: over
         # the launch count, the share of launches whose device trip
@@ -760,6 +894,8 @@ class CounterEngine:
         # after partial commits would double-count on client retry).
         dedups: List[tuple] = []
         self.stat_device_submit_ns = 0  # tpu-lint: disable=shared-state -- collector-owned engine
+        self.stat_device_submit_cpu_ns = -1  # tpu-lint: disable=shared-state -- collector-owned engine
+        assign_gil = -1
         t_assign = time.monotonic_ns()
         try:
             with SPANS.span(_spans.LAUNCH_ASSIGN):
@@ -782,6 +918,10 @@ class CounterEngine:
                                 limits[start:end],
                             )
                         )
+                        gil = table.returned.gil_ns
+                        assign_gil = max(assign_gil, 0) + gil
+                        self.total_assign_gil_ns += gil  # tpu-lint: disable=shared-state -- collector-owned engine
+                        self.count_assign_gil += 1  # tpu-lint: disable=shared-state -- collector-owned engine
                         dedup = _Dedup(
                             uniq_slots=uniq,
                             inv=inv,
@@ -811,10 +951,12 @@ class CounterEngine:
                 table.end_batch()
         t_assigned = time.monotonic_ns()
         self.stat_assign_ns = t_assigned - t_assign  # tpu-lint: disable=shared-state -- collector-owned engine
+        self.stat_assign_gil_ns = assign_gil  # tpu-lint: disable=shared-state -- collector-owned engine
         if watch is not None:
             watch.last_leg = (
                 _spans.LAUNCH_ASSIGN, t_assigned, t_assigned - t_assign
             )
+            watch.last_gil_ns = assign_gil
         # Phase 2 — launch the device step per chunk.
         for start, count, dedup in dedups:
             afters_dev, reassemble, shape = self._device_submit(
@@ -841,7 +983,14 @@ class CounterEngine:
         grows).  `watch` sees each readback wait begin and end."""
         hits, limits, shadow, chunks, now = token
         self.stat_readback_ns = 0  # tpu-lint: disable=shared-state -- one completing thread per engine; the submitting thread never touches these two
+        self.stat_readback_cpu_ns = -1  # tpu-lint: disable=shared-state -- same single completing thread
         decide_ns = 0
+        decide_gil = -1
+        stamp = self._decide_stamp
+        if stamp is None and not self._generic and _native_decide_fn():
+            from .native_slot_table import ReturnStamp
+
+            stamp = self._decide_stamp = ReturnStamp()  # tpu-lint: disable=shared-state -- same single completing thread
         if not chunks:
             self.stat_decide_ns = 0  # tpu-lint: disable=shared-state -- same single completing thread
             empty = np.zeros(0, dtype=np.int32)
@@ -877,7 +1026,13 @@ class CounterEngine:
                         shadow[start:end],
                         self.model.near_ratio,
                         dedup,
+                        stamp,
                     )
+                    if stamp is not None:
+                        gil = stamp.gil_ns
+                        decide_gil = max(decide_gil, 0) + gil
+                        self.total_decide_gil_ns += gil  # tpu-lint: disable=shared-state -- same single completing thread
+                        self.count_decide_gil += 1  # tpu-lint: disable=shared-state -- same single completing thread
             outs.append(out)
             t_decided = time.monotonic_ns()
             decide_ns += t_decided - t_decide
@@ -886,7 +1041,15 @@ class CounterEngine:
                     _spans.COMPLETE_DECIDE, t_decided, t_decided - t_decide
                 )
         self.stat_decide_ns = decide_ns  # tpu-lint: disable=shared-state -- same single completing thread
+        self.stat_decide_gil_ns = decide_gil  # tpu-lint: disable=shared-state -- same single completing thread
+        if watch is not None:
+            watch.last_gil_ns = decide_gil
+        # One launch, all of whose chunks had arrived: the count and,
+        # in the traced run, that launch's readback brackets whole.
         self.stat_readback_ready += ready  # tpu-lint: disable=shared-state -- same single completing thread
+        if ready and self.stat_readback_cpu_ns >= 0:
+            self.total_ready_wall_ns += self.stat_readback_ns  # tpu-lint: disable=shared-state -- same single completing thread
+            self.total_ready_cpu_ns += self.stat_readback_cpu_ns  # tpu-lint: disable=shared-state -- same single completing thread
         if len(outs) == 1:
             return outs[0]
         return HostDecisions(
@@ -910,22 +1073,43 @@ class CounterEngine:
         shape has completed before (see CallWatch).  The same bracket
         is the span of that name and the launch record's
         device_submit_ns / readback_ns: the watchdog's clock, the trace
-        and the record time one interval."""
-        t0 = time.monotonic_ns()
+        and the record time one interval.  Where the watch's
+        ``cpu_clock`` is set (the traced run) the thread's on-CPU clock
+        stands beside the wall stamp at each end (the record's
+        device_submit_cpu_ns / readback_cpu_ns, and the bank's
+        ``.wall_ns`` / ``.cpu_ns`` sums, which advance together or not
+        at all): two more clock reads a bracket, by the thread that
+        does the work.  They stand OUTSIDE the wall stamps (first
+        before t0, last after t1), so the bracket everything else reads
+        does not hold them; the on-CPU figure is high by about one
+        clock read a bracket for it."""
+        profile = watch is not None and watch.cpu_clock
         if watch is not None:
             watch.begin(shape in self._proven_shapes)
+            t0 = watch.wall0_ns
+        else:
+            t0 = time.monotonic_ns()
         try:
             with SPANS.span(leg):
                 yield
         finally:
             t1 = time.monotonic_ns()
+            cpu = time.thread_time_ns() - watch.cpu0_ns if profile else -1
             if watch is not None:
                 watch.end()
                 watch.last_leg = (leg, t1, t1 - t0)
             if leg is _spans.COMPLETE_READBACK:
                 self.stat_readback_ns += t1 - t0  # tpu-lint: disable=shared-state -- the completing thread's own field (step_complete)
+                if profile:
+                    self.stat_readback_cpu_ns = max(self.stat_readback_cpu_ns, 0) + cpu  # tpu-lint: disable=shared-state -- same
+                    self.total_readback_wall_ns += t1 - t0  # tpu-lint: disable=shared-state -- same
+                    self.total_readback_cpu_ns += cpu  # tpu-lint: disable=shared-state -- same
             else:
                 self.stat_device_submit_ns += t1 - t0  # tpu-lint: disable=shared-state -- the submitting thread's own field
+                if profile:
+                    self.stat_device_submit_cpu_ns = max(self.stat_device_submit_cpu_ns, 0) + cpu  # tpu-lint: disable=shared-state -- same
+                    self.total_submit_wall_ns += t1 - t0  # tpu-lint: disable=shared-state -- same
+                    self.total_submit_cpu_ns += cpu  # tpu-lint: disable=shared-state -- same
 
     def _decide_generic(
         self,

@@ -49,6 +49,7 @@ only flips NOT_SERVING when no fallback can answer.
 
 from __future__ import annotations
 
+import json
 import logging
 import threading
 import time
@@ -57,7 +58,12 @@ from typing import Callable, List, Optional
 import numpy as np
 
 from ..observability.launches import OUTCOME_FALLBACK
-from ..observability.spans import BG_SNAPSHOT, BG_WATCHDOG_TICK, SPANS
+from ..observability.spans import (
+    BG_SNAPSHOT,
+    BG_WATCHDOG_TICK,
+    SLOW_TICK_NS,
+    SPANS,
+)
 from ..utils.time import REAL_MONOTONIC, MonotonicClock
 from .host_engine import STATIC_ALLOW, STATIC_DENY, HostEngine
 
@@ -252,6 +258,14 @@ class DeviceFaultDomain:
         self.stat_snapshots = 0
         self.stat_snapshot_timeouts = 0  # tokens the collector did not serve in time
         self.stat_gc_excused = 0  # ticks a call was past the deadline only by a collection
+        # The watchdog's own lateness (_loop): how late each wake-up
+        # it already makes ran against interval_s, summed; how many
+        # ran SLOW_TICK_NS or more late; the last one's.  A process
+        # that stood still — every thread, this one too — shows here
+        # and in no span.
+        self.stat_tick_late_ns = 0
+        self.stat_ticks_late = 0
+        self.last_tick_late_ns = 0
         # Lifecycle event journal (observability/events.py), wired by
         # the runner when EVENT_JOURNAL_SIZE > 0: quarantine entry,
         # first fallback decision of an episode, half-open probes and
@@ -359,7 +373,11 @@ class DeviceFaultDomain:
             during = {"background": SPANS.open_work(at_ns)}
             d = self.cache._dispatchers.get(id(engine))
             if d is not None:
-                during["threads"] = d.watch_report()
+                # With each open bracket's ledger: what the scheduler
+                # did to its thread so far, read here from outside it.
+                during["threads"] = d.watch_report(
+                    watchdog_late_ms=round(self.last_tick_late_ns / 1e6, 3)
+                )
         with self._lock:
             if rec.state != "closed":
                 return
@@ -419,13 +437,16 @@ class DeviceFaultDomain:
         self._report_health()
         logger.error(
             "device bank %d (%s) quarantined: %s fault (%s); failure "
-            "mode %s, restart in %.1fs",
+            "mode %s, restart in %.1fs%s",
             bank,
             rec.role,
             kind,
             rec.fault_error,
             self.failure_mode,
             rec.backoff_s,
+            # The witness goes to the log too: a run that ends before
+            # anyone asks /debug/faults keeps its ledger there.
+            f"; during {json.dumps(during)}" if during is not None else "",
         )
 
     def snapshot_entries(self) -> int:
@@ -486,6 +507,7 @@ class DeviceFaultDomain:
         if d.dead is not None:
             self.record_fault(bank, classify_fault(d.dead), d.dead)
             return
+        d.glance()
         stuck = d.stuck_age(now)
         if stuck > self.kernel_deadline_s:
             self.record_fault(bank, FAULT_HANG, self.hang_error(stuck))
@@ -750,12 +772,20 @@ class DeviceFaultDomain:
             t.join(timeout=5)
 
     def _loop(self) -> None:
+        interval_ns = int(self.interval_s * 1e9)
+        due_ns = time.monotonic_ns() + interval_ns
         while not self._stop.wait(self.interval_s):
+            late_ns = max(0, time.monotonic_ns() - due_ns)
+            self.last_tick_late_ns = late_ns  # tpu-lint: disable=shared-state -- GIL-atomic stats, single supervisor writer
+            self.stat_tick_late_ns += late_ns  # tpu-lint: disable=shared-state -- same
+            if late_ns >= SLOW_TICK_NS:
+                self.stat_ticks_late += 1  # tpu-lint: disable=shared-state -- same
             try:
                 with SPANS.background(BG_WATCHDOG_TICK):
                     self.tick()
             except Exception:
                 logger.exception("device-supervisor tick failed")
+            due_ns = time.monotonic_ns() + interval_ns
 
     def register_stats(self, store, scope: str = "ratelimit.tpu.fault"):
         """The bounded fault family: per-kind fault counters, fallback
@@ -779,6 +809,11 @@ class DeviceFaultDomain:
         )
         store.gauge_fn(scope + ".snapshot_entries", self.snapshot_entries)
         store.counter_fn(scope + ".gc_excused", lambda: self.stat_gc_excused)
+        store.counter_fn(
+            scope + ".tick_late_ms",
+            lambda: self.stat_tick_late_ns // 1_000_000,
+        )
+        store.counter_fn(scope + ".ticks_late", lambda: self.stat_ticks_late)
         store.gauge_fn(
             scope + ".quarantined_banks", lambda: self.quarantined_count()
         )
@@ -827,6 +862,8 @@ class DeviceFaultDomain:
             "snapshot_timeouts": self.stat_snapshot_timeouts,
             "snapshot_entries": self.snapshot_entries(),
             "gc_excused": self.stat_gc_excused,
+            "tick_late_ms": round(self.stat_tick_late_ns / 1e6, 3),
+            "ticks_late": self.stat_ticks_late,
             "quarantined_banks": self.quarantined_count(),
             # What runs beside serving (observability/spans.py): open
             # now, and per activity the time and count since start.

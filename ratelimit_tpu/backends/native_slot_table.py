@@ -19,6 +19,7 @@ import logging
 import os
 import subprocess
 import threading
+import time
 from typing import List, Optional, Tuple
 
 import numpy as np
@@ -125,6 +126,7 @@ def _signatures(lib: ctypes.CDLL) -> None:
     lib.sk_assign_dedup_batch.argtypes = [
         vp, vp, vp, i64, i64, vp, vp, vp,
         vp, vp, vp, vp, vp, vp,
+        vp,  # out_done_ns
     ]
     lib.sk_export_size.restype = i64
     lib.sk_export_size.argtypes = [vp, vp]
@@ -138,6 +140,7 @@ def _signatures(lib: ctypes.CDLL) -> None:
         vp, vp, vp, vp, vp, i64,  # inv, prefix, hits, limits, shadow, n
         ctypes.c_float, ctypes.c_int32, ctypes.c_int32,  # ratio, codes
         vp, vp, vp, vp, vp, vp, vp, vp, vp,  # outputs
+        vp,  # out_done_ns
     ]
 
 
@@ -309,6 +312,27 @@ def _ptr(a: np.ndarray) -> int:
     return a.ctypes.data
 
 
+class ReturnStamp:
+    """Where one thread's native calls leave CLOCK_MONOTONIC as their
+    last act (`out_done_ns`), and what the wrapper made of it.
+    ctypes.CDLL drops the GIL for the call and takes it back before
+    Python runs again, so ``time.monotonic_ns()`` read the moment the
+    call returns, minus the cell, is the time THIS thread waited to get
+    the GIL back, under the load of that moment — measured from outside
+    the GIL, for one clock read, and without ever taking it to time it.
+    ``gil_ns``: the last call's; -1 before the first."""
+
+    __slots__ = ("_cell", "addr", "gil_ns")
+
+    def __init__(self):
+        self._cell = ctypes.c_int64(0)
+        self.addr = ctypes.addressof(self._cell)
+        self.gil_ns = -1
+
+    def settle(self, back_ns: int) -> None:
+        self.gil_ns = max(0, back_ns - self._cell.value)  # tpu-lint: disable=shared-state -- one ReturnStamp per calling thread (the table's owner; the completer)
+
+
 def decide_reconstruct(
     afters_g: np.ndarray,
     totals: np.ndarray,
@@ -320,6 +344,7 @@ def decide_reconstruct(
     near_ratio: float,
     ok_code: int,
     over_code: int,
+    stamp: Optional[ReturnStamp] = None,
 ):
     """One C pass over a deduped chunk: per-lane before/after
     reconstruction from per-group device afters + the threshold state
@@ -328,7 +353,8 @@ def decide_reconstruct(
 
     Returns (codes i32, remaining i64, befores i64, afters i64,
     over i64, near i64, within i64, shadow i64, set_lc bool), all
-    length n.  Raises RuntimeError if the native lib is unavailable
+    length n.  `stamp`, the calling thread's ReturnStamp, takes the
+    GIL-return time of this call.  Raises RuntimeError if the native lib is unavailable
     (callers normally gate on available() first).
     """
     lib = _get_lib()
@@ -376,7 +402,10 @@ def decide_reconstruct(
         base + 5 * row,  # within
         base + 6 * row,  # shadow
         _ptr(out_set_lc),
+        None if stamp is None else stamp.addr,
     )
+    if stamp is not None:
+        stamp.settle(time.monotonic_ns())
     return (
         out_codes,
         out_i64[0],
@@ -400,6 +429,9 @@ class NativeSlotTable:
         self._lib = lib
         self.num_slots = int(num_slots)
         self._handle = lib.sk_create(self.num_slots)
+        # The GIL-return time of the last assign_dedup_packed, by the
+        # table's one owner (the collector): `.gil_ns`.
+        self.returned = ReturnStamp()
 
     def __del__(self):
         handle = getattr(self, "_handle", None)
@@ -523,7 +555,9 @@ class NativeSlotTable:
             _ptr(out_prefix),
             _ptr(out_freshg),
             _ptr(out_limitmax),
+            self.returned.addr,
         )
+        self.returned.settle(time.monotonic_ns())
         if g < 0:
             raise RuntimeError(
                 "slot table exhausted: batch holds more live keys than "

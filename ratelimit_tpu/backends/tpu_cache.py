@@ -152,6 +152,7 @@ class TpuRateLimitCache:
         fault_probe_timeout_s: Optional[float] = None,
         fault_restart_warmup: bool = True,
         engine_factory=None,
+        thread_clocks: bool = False,
     ):
         """`engine` may be a LIST of engines: N independent host LANES,
         each with its own slot table, dispatcher thread pair, and
@@ -316,6 +317,9 @@ class TpuRateLimitCache:
         self._pipeline_depth = pipeline_depth
         self._unhealthy_after = unhealthy_after
         self._stamp_clock = fault_clock
+        # DEBUG_PROFILING=1: the dispatcher threads read their CPU
+        # clock at both ends of a device-call bracket (CallWatch.cpu_clock).
+        self._thread_clocks = bool(thread_clocks)
         self._dispatchers: dict = {}
         if batch_window_us > 0:
             for idx, lane in enumerate(self.lanes):
@@ -861,6 +865,7 @@ class TpuRateLimitCache:
             pipeline_depth=self._pipeline_depth,
             unhealthy_after=self._unhealthy_after,
             stamp_clock=self._stamp_clock,
+            cpu_clock=self._thread_clocks,
         )
 
     def attach_launch_recorder(self, recorder) -> None:

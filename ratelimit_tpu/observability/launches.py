@@ -25,7 +25,15 @@ phase durations —
 the legs inside and between them, each measured where it happens —
 ``assign_ns`` and ``device_submit_ns`` (inside launch_ns),
 ``handoff_ns`` (launched -> the completer takes it up), ``readback_ns``
-and ``decide_ns`` (inside complete_ns) — the dispatcher's own
+and ``decide_ns`` (inside complete_ns) — and, beside the wall stamps,
+what the two device-call brackets were made of:
+``device_submit_cpu_ns`` / ``readback_cpu_ns`` (the thread's on-CPU
+time in the bracket, ``time.thread_time_ns``; the rest of the bracket
+it stood off the CPU: asleep on the GIL, the runtime or the device, or
+runnable and not running) and ``assign_gil_ns`` / ``decide_gil_ns``
+(how long the thread waited to get the GIL back after the launch's one
+GIL-free native call, native_slot_table.ReturnStamp); -1 = not
+measured (an untraced run, the Python table) — the dispatcher's own
 ``launch_id`` (the stat of the ``rl.launch`` span, observability/
 spans.py), plus the outcome (ok / fault / fallback) and the correlation id of the
 SLOWEST (longest-queued) item, so one grep joins a slow launch to the
@@ -42,8 +50,12 @@ seq-window check at read time — a slot is live iff its seq lies in
 ``(hwm - size, hwm]``.  Stamping runs on the dispatcher's collector /
 completer threads (never the RPC threads) at most once per LAUNCH, so
 the per-request amortized cost is launch-cost / items-per-batch.  What
-one record costs on the chip's host: not measured (PERF.md section 5
-has what recorder, spans and leg counters cost together, end to end).
+these four cost on the chip's host (gVisor): always, 2 reads of
+``monotonic_ns`` a launch at 0.13 us; in the traced run 4 of the
+thread's on-CPU clock, each a trap into the sentry — 6.1 us in a tight
+loop, some 40 us in a served launch, +4% of ``p50_ms`` when they were
+always on, which is why they are not (my chip runs, PR 41; PERF.md
+section 6 has the pairs).
 
 ``LAUNCH_RECORDER_SIZE=0`` disables recording entirely: the runner
 builds no recorder, dispatchers keep ``launches=None``, and the
@@ -98,8 +110,20 @@ LAUNCH_DTYPE = np.dtype(
         ("handoff_ns", np.int64),  # launch done -> the completer takes it up
         ("readback_ns", np.int64),  # in the completer's device-call bracket
         ("decide_ns", np.int64),  # host threshold machine (completer)
+        # What the brackets were made of (-1 = not measured): the
+        # thread's on-CPU time inside the two device-call brackets, and
+        # how long it waited for the GIL after its one native call.
+        ("device_submit_cpu_ns", np.int64),
+        ("readback_cpu_ns", np.int64),
+        ("assign_gil_ns", np.int64),
+        ("decide_gil_ns", np.int64),
     ]
 )
+
+#: The ledger fields above, in record order: /debug/launches renders
+#: each as ``<name>_us`` where it was measured and leaves it out where
+#: it reads -1.
+_LEDGER_FIELDS = LAUNCH_DTYPE.names[-4:]
 
 #: Launch outcomes.  FAULT covers submit and complete failures (the
 #: fault domain's taxonomy has the details; the ring answers "when");
@@ -178,6 +202,10 @@ class LaunchRecorder:
             handoff_ns: int = 0,
             readback_ns: int = 0,
             decide_ns: int = 0,
+            device_submit_cpu_ns: int = -1,
+            readback_cpu_ns: int = -1,
+            assign_gil_ns: int = -1,
+            decide_gil_ns: int = -1,
         ) -> None:
             """Stamp one launch (collector / completer thread)."""
             i = next(counter)
@@ -202,6 +230,10 @@ class LaunchRecorder:
                 handoff_ns,
                 readback_ns,
                 decide_ns,
+                device_submit_cpu_ns,
+                readback_cpu_ns,
+                assign_gil_ns,
+                decide_gil_ns,
             )
             if algo in items_by_algo:
                 items_by_algo[algo] += items
@@ -250,7 +282,7 @@ class LaunchRecorder:
                 seq, ts_ns, bank, algo, lanes, items, dedup, queue_wait,
                 launch, complete, outcome, corr, launch_id, assign,
                 device_submit, handoff, readback, decide,
-            ) = rec
+            ) = rec[:-4]
             d = {
                 "seq": seq,
                 "ts_ns": ts_ns,
@@ -270,6 +302,9 @@ class LaunchRecorder:
                 "readback_us": round(readback / 1e3, 1),
                 "decide_us": round(decide / 1e3, 1),
             }
+            for name, ns in zip(_LEDGER_FIELDS, rec[-4:]):
+                if ns >= 0:
+                    d[name[:-2] + "us"] = round(ns / 1e3, 1)
             if corr:
                 # Longest-queued item's cross-hop id, hex16 like the
                 # flight ring and trace spans render it.

@@ -184,6 +184,7 @@ def create_limiter(s: Settings, stats_manager: Manager, local_cache, time_source
             device_failure_mode=s.device_failure_mode,
             fault_restart_backoff_s=s.device_restart_backoff_s,
             fault_snapshot_interval_s=s.tpu_checkpoint_interval_s,
+            thread_clocks=s.debug_profiling,
             fault_interval_s=(
                 s.device_watchdog_interval_s
                 if s.device_watchdog_interval_s > 0

@@ -11,13 +11,47 @@ background thread dies FAILS instead of passing silently.
 The hook CHAINS: the previous hook still runs, so stacking the
 recorder on top of the logger (or pytest's own machinery) loses
 nothing.
+
+Also here: :class:`ThreadClock`, one thread's on-CPU clock as any
+thread of the process can read it.
 """
 
 from __future__ import annotations
 
 import logging
 import threading
+import time
 from typing import Callable, List, Optional
+
+
+class ThreadClock:
+    """The CONSTRUCTING thread's on-CPU clock (CLOCK_THREAD_CPUTIME_ID:
+    ns the thread has run, not waited), kept by its clock id so that
+    ANOTHER thread can read it: ``ns()``.  The thread itself reads
+    ``time.thread_time_ns()``, the same clock.  What a wall-clock
+    bracket holds beyond its thread's on-CPU time the thread spent off
+    the CPU: asleep on the GIL, on the runtime or the device, or
+    runnable and not running.  Passive: a read is a clock read; nothing
+    here wakes, samples or locks.  ``ns()`` is None where the platform
+    has no per-thread clock id, or the thread is gone."""
+
+    __slots__ = ("_id",)
+
+    def __init__(self):
+        try:
+            self._id: Optional[int] = time.pthread_getcpuclockid(
+                threading.get_ident()
+            )
+        except (AttributeError, OSError):
+            self._id = None
+
+    def ns(self) -> Optional[int]:
+        if self._id is None:
+            return None
+        try:
+            return time.clock_gettime_ns(self._id)
+        except OSError:
+            return None
 
 
 class ThreadExceptionRecorder:

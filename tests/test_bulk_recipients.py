@@ -423,8 +423,11 @@ def test_the_cell_is_in_the_manifest_and_reports_every_paced_metric():
     paced = [m for m in bench["per_layer"] if m["moves"] == "p50_ms"]
     for m in paced:
         assert m["workloads"][-1] == CELL, m["name"]
-    assert [m["name"] for m in bench["per_layer"][-len(NEW_METRICS):]] == list(NEW_METRICS)
-    for m in bench["per_layer"][-len(NEW_METRICS):]:
+    # PR 36's six, where it appended them (later PRs append after).
+    first = [m["name"] for m in bench["per_layer"]].index(next(iter(NEW_METRICS)))
+    mine = bench["per_layer"][first:first + len(NEW_METRICS)]
+    assert [m["name"] for m in mine] == list(NEW_METRICS)
+    for m in mine:
         assert (m["unit"], m["better"], m["source"], m["layer"]) == NEW_METRICS[m["name"]]
         assert m["workloads"] == cells and m["moves"] == "p50_ms"
     for m in bench["per_layer"]:

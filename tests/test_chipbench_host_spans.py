@@ -69,6 +69,13 @@ PR36 = (
     "lanes_per_launch.paced", "lane_fill_share.paced", "prepare_us_per_descriptor.paced",
     "apply_us_per_descriptor.paced", "decode_us.paced", "serialize_us.paced",
 )
+# PR 41 appended six readings of what the device-call brackets are made
+# of (on-CPU, the GIL's return, the watchdog's lateness), in all four
+# cells.
+PR41 = (
+    "device_submit_cpu_share.paced", "readback_cpu_share.paced", "readback_ready_blocked_us.paced",
+    "collector_gil_return_us.paced", "completer_gil_return_us.paced", "watchdog_late_ms.paced",
+)
 H = "ratelimit_server.ShouldRateLimit."
 
 
@@ -147,6 +154,10 @@ def test_pr26_entry_reads_on_pr24s_records_and_on_the_change(name):
 def test_pr24_and_pr26_entries_are_appended_and_the_rest_wait_outside_the_benchmark():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
+    assert [m["name"] for m in bench["per_layer"][-len(PR41):]] == list(PR41)
+    for m in bench["per_layer"][-len(PR41):]:
+        assert (m["moves"], m["source"], m["workloads"]) == ("p50_ms", "program_counter", PACED)
+    bench["per_layer"] = bench["per_layer"][:-len(PR41)]
     assert [m["name"] for m in bench["per_layer"][-len(PR36):]] == list(PR36)
     for m in bench["per_layer"][-len(PR36):]:
         assert (m["moves"], m["workloads"]) == ("p50_ms", PACED)

@@ -177,6 +177,8 @@ _DEDUP_C_SIG = [
     ("out_prefix", "uint64_t*"),
     ("out_freshg", "uint8_t*"),
     ("out_limitmax", "uint32_t*"),
+    # CLOCK_MONOTONIC ns as the call's last act (ReturnStamp's cell).
+    ("out_done_ns", "int64_t*"),
 ]
 
 #: numpy dtype of each buffer the binding allocates/passes for the
@@ -192,6 +194,7 @@ _DEDUP_BUFFER_DTYPES = {
     "out_prefix": np.uint64,
     "out_freshg": np.uint8,
     "out_limitmax": np.uint32,
+    "out_done_ns": np.int64,  # ctypes.c_int64, same width
 }
 
 
@@ -233,7 +236,7 @@ def test_dedup_batch_live_argtypes_match_static():
     nst._signatures(lib)
     fn = _dedup_c_model()
     at = lib.sk_assign_dedup_batch.argtypes
-    assert len(at) == len(fn.params) == 14
+    assert len(at) == len(fn.params) == 15
     for ct, param in zip(at, fn.params):
         if param.ctype.is_pointer:
             assert ct is ctypes.c_void_p, param.name
@@ -257,3 +260,34 @@ def test_packed_transfer_u32_bit_views_are_lossless():
     # LANE_DTYPE's u32 counters are what those buffers are built from.
     assert LANE_DTYPE.fields["hits"][0] == np.dtype(np.uint32)
     assert LANE_DTYPE.fields["limits"][0] == np.dtype(np.uint32)
+
+
+# -- LAUNCH_DTYPE vs the launch recorder's pack format (ISSUE 41) -------------
+
+
+def test_launch_dtype_is_all_int64_and_its_pack_covers_every_field():
+    """Four ledger fields joined the launch record; the recorder still
+    stamps a whole row through ONE struct.pack_into of little-endian
+    int64s, so the dtype must stay uniform and the format the recorder
+    builds must cover it byte for byte, field i at offset 8 i."""
+    from ratelimit_tpu.observability.launches import (
+        LAUNCH_DTYPE,
+        OUTCOME_OK,
+        LaunchRecorder,
+    )
+
+    assert len(LAUNCH_DTYPE.names) == 22
+    fmt = "<%dq" % len(LAUNCH_DTYPE.names)
+    assert struct.calcsize(fmt) == LAUNCH_DTYPE.itemsize == 176
+    for i, name in enumerate(LAUNCH_DTYPE.names):
+        field_dtype, offset = LAUNCH_DTYPE.fields[name]
+        assert field_dtype == np.int64, name
+        assert offset == i * 8, name
+    # One row through the real writer, every argument a distinct value:
+    # each lands in the field of its name.
+    lr = LaunchRecorder(2)
+    args = list(range(101, 101 + len(LAUNCH_DTYPE.names) - 2))
+    args[8] = OUTCOME_OK  # outcome (after seq, ts_ns: the recorder's own)
+    lr.record(*args)
+    [row] = lr.snapshot()
+    assert [int(row[n]) for n in LAUNCH_DTYPE.names[2:]] == args

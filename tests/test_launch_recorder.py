@@ -224,3 +224,124 @@ def test_cache_attach_wires_recorder_and_decisions_unchanged(clock):
     assert d["algorithm"] == "fixed_window"
     assert d["outcome"] == "ok"
     assert lr.items_by_algo()["fixed_window"] == 30
+
+
+# -- the ledger beside each wall stamp (ISSUE 41) ------------------------
+
+LEDGER = (
+    "device_submit_cpu_ns", "readback_cpu_ns", "assign_gil_ns", "decide_gil_ns",
+)
+
+
+def test_the_ledger_fields_default_to_not_measured_and_are_left_out():
+    """A record stamped without them (the fallback path, an older
+    caller) reads -1 in every ledger field, and /debug/launches leaves
+    those out instead of printing a negative time."""
+    lr = LaunchRecorder(4)
+    lr.record(0, 0, 8, 1, 8, 100, 200, 300, OUTCOME_OK)
+    lr.record(
+        0, 0, 8, 1, 8, 100, 200, 300, OUTCOME_OK,
+        device_submit_cpu_ns=150_000, assign_gil_ns=2_500,
+        decide_gil_ns=0,
+    )
+    bare, some = lr.snapshot()
+    assert LAUNCH_DTYPE.names[-len(LEDGER):] == LEDGER
+    assert [int(bare[f]) for f in LEDGER] == [-1] * len(LEDGER)
+    d_bare, d_some = lr.snapshot_dicts()
+    assert not any(f[:-2] + "us" in d_bare for f in LEDGER)
+    assert d_some["device_submit_cpu_us"] == 150.0
+    assert d_some["assign_gil_us"] == 2.5
+    assert d_some["decide_gil_us"] == 0.0
+    assert "readback_cpu_us" not in d_some
+    # What was there reads as before.
+    assert d_bare["launch_us"] == d_some["launch_us"] == 0.2
+
+
+def test_without_the_traced_runs_switch_no_cpu_clock_is_read():
+    """DEBUG_PROFILING unset: the dispatcher threads read no CPU clock
+    (on the chip's host one read is a trap into gVisor's sentry) — the
+    record's CPU fields say "not measured", the bank's wall / cpu sums
+    stand still together; the GIL's return, a vDSO read, is always on."""
+    from ratelimit_tpu.backends import native_slot_table
+
+    engine = CounterEngine(num_slots=64)
+    d = BatchDispatcher(engine, batch_window_us=100, batch_limit=4096)
+    lr = make_launch_recorder(8)
+    d.launches = lr
+    try:
+        it = WorkItem(
+            now=0,
+            lanes=[Lane(key="k", expiry=60, limit=10, shadow=False, hits=1)],
+            apply=lambda dec: None,
+        )
+        d.submit(it)
+        it.wait(10.0)
+        d.flush()
+    finally:
+        d.stop()
+    (rec,) = lr.snapshot()
+    assert rec["device_submit_cpu_ns"] == rec["readback_cpu_ns"] == -1
+    assert engine.total_submit_wall_ns == engine.total_submit_cpu_ns == 0
+    assert engine.total_readback_wall_ns == engine.total_ready_wall_ns == 0
+    assert engine.total_readback_cpu_ns == engine.total_ready_cpu_ns == 0
+    assert rec["device_submit_ns"] > 0 and rec["readback_ns"] > 0
+    if native_slot_table.available():
+        assert rec["assign_gil_ns"] >= 0 and engine.count_decide_gil == 1
+
+
+def test_a_real_launch_fills_the_ledger_and_the_engines_counters():
+    """Through a real BatchDispatcher with the traced run's switch
+    (cpu_clock): every launch record carries the on-CPU time of both
+    device-call brackets (never more than the bracket plus a clock's
+    grain) and the GIL-return time of both native calls on the native
+    table; the same sums stand in the engine's counters, and the
+    arrived-copy pair holds the readbacks of exactly the launches
+    readback_ready counted."""
+    from ratelimit_tpu.backends import native_slot_table
+
+    engine = CounterEngine(num_slots=64)
+    d = BatchDispatcher(
+        engine, batch_window_us=100, batch_limit=4096, cpu_clock=True
+    )
+    lr = make_launch_recorder(64)
+    d.launches = lr
+    try:
+        for i in range(6):
+            it = WorkItem(
+                now=0,
+                lanes=[Lane(key=f"k{i}", expiry=60, limit=10, shadow=False, hits=1)],
+                apply=lambda dec: None,
+            )
+            d.submit(it)
+            it.wait(10.0)
+        d.flush()
+    finally:
+        d.stop()
+    ok = lr.snapshot()
+    ok = ok[ok["outcome"] == OUTCOME_OK]
+    assert len(ok) == 6
+    grain = 1_000_000  # a scheduler tick's worth of slack on CPU clocks
+    assert (ok["device_submit_cpu_ns"] >= 0).all()
+    assert (ok["device_submit_cpu_ns"] <= ok["device_submit_ns"] + grain).all()
+    assert (ok["readback_cpu_ns"] >= 0).all()
+    assert (ok["readback_cpu_ns"] <= ok["readback_ns"] + grain).all()
+    if native_slot_table.available():
+        assert (ok["assign_gil_ns"] >= 0).all()
+        assert (ok["decide_gil_ns"] >= 0).all()
+        assert engine.count_assign_gil == engine.count_decide_gil == 6
+        assert engine.total_assign_gil_ns == int(ok["assign_gil_ns"].sum())
+        assert engine.total_decide_gil_ns == int(ok["decide_gil_ns"].sum())
+    else:
+        assert (ok["assign_gil_ns"] == -1).all()
+    assert engine.total_submit_wall_ns == int(ok["device_submit_ns"].sum())
+    assert engine.total_submit_cpu_ns == int(ok["device_submit_cpu_ns"].sum())
+    assert engine.total_readback_wall_ns == int(ok["readback_ns"].sum())
+    assert engine.total_readback_cpu_ns == int(ok["readback_cpu_ns"].sum())
+    # A launch whose copy had not arrived adds to neither side.
+    assert 0 <= engine.stat_readback_ready <= 6
+    assert 0 <= engine.total_ready_wall_ns <= engine.total_readback_wall_ns
+    assert 0 <= engine.total_ready_cpu_ns <= engine.total_readback_cpu_ns
+    assert (engine.total_ready_wall_ns > 0) == (engine.stat_readback_ready > 0)
+    if engine.stat_readback_ready == 6:
+        assert engine.total_ready_wall_ns == engine.total_readback_wall_ns
+        assert engine.total_ready_cpu_ns == engine.total_readback_cpu_ns

@@ -199,8 +199,8 @@ def test_real_sources_discovered_and_fully_parsed():
     model = parse_sources(srcs)
     assert set(model.functions) == EXPORTS
     assert model.functions["sk_create"].ret.describe() == "void*"
-    assert len(model.functions["sk_assign_dedup_batch"].params) == 14
-    assert len(model.functions["sk_decide_reconstruct"].params) == 21
+    assert len(model.functions["sk_assign_dedup_batch"].params) == 15
+    assert len(model.functions["sk_decide_reconstruct"].params) == 22
     # no parameter on the real surface defeats the lexer
     for fn in model.functions.values():
         for p in fn.params:
