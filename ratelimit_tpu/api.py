@@ -94,13 +94,16 @@ class RateLimitRequest:
     completer signalled the launch the answer waited for; the waiting
     thread ran again) and the transport turns them into the per-request
     leg histograms (server/grpc_server.py ``observe_legs``).  None from
-    a backend that stamps nothing."""
+    a backend that stamps nothing.  Beside them ``launched``: whether
+    the request queued any work item — False is the request the
+    backend's ``requests_no_launch`` counts."""
 
     domain: str
     descriptors: Sequence[Descriptor]
     hits_addend: int = 0
     deadline: Optional[float] = None
     legs: Optional[tuple] = None
+    launched: Optional[bool] = None
 
 
 @dataclass(frozen=True, slots=True)

