@@ -76,13 +76,18 @@ def main() -> None:
         for b in batches:
             engine.step(b)
         engine.reset()
+        lanes_before = np.array(engine.stat_chip_lanes)
         t0 = time.perf_counter()
         for i, b in enumerate(batches):
             token = engine.step_submit(b)
             # token = (hits, limits, shadow, chunks); chunks[0][0] is
             # the routed (num_banks, cap) device afters handle.
             widths.append(token[3][0][0].shape[1])  # routed cap
-            bank_counts.append(engine.stat_bank_lane_counts)
+            # Real lanes each bank received in this step: the delta of
+            # the engine's cumulative per-chip counters.
+            lanes_so_far = np.array(engine.stat_chip_lanes)
+            bank_counts.append(lanes_so_far - lanes_before)
+            lanes_before = lanes_so_far
             d = engine.step_complete(token)
             np.testing.assert_array_equal(
                 d.codes, ref_decisions[i].codes, err_msg=f"mesh {nd}"

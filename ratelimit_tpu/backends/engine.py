@@ -525,6 +525,15 @@ _SLOT_COUNTERS = (
     ("slot_gc.freed", "stat_slot_gc_freed"),
     ("arena.compactions", "stat_arena_compactions"),
 )
+# A mesh bank's routing family (parallel.ShardedCounterEngine keeps
+# them; a one-chip bank has none and exports none), beside a counter a
+# chip, ratelimit.tpu.bank<i>.chip<j>.lanes.
+_ROUTED_COUNTERS = (
+    ("routed_launches", "stat_routed_launches"),
+    ("route_ns", "stat_route_ns"),
+    ("unroute_ns", "stat_unroute_ns"),
+    ("routed_busiest_lanes", "stat_routed_busiest_lanes"),
+)
 _SLOT_GAUGES = (
     ("live_keys", "stat_live_keys"),
     ("arena.bytes", "stat_arena_bytes"),
@@ -559,11 +568,22 @@ def register_slot_stats(store, base: str, engine_of: Callable) -> None:
     (ratelimit.tpu.bank<i>): occupancy and capacity, evictions, window
     rollovers over dedup groups launched, the collector's slot GC, the
     native table's arena, and what the device-call brackets were made
-    of (_LEG_COUNTERS).  `engine_of()` gives the bank's engine at
-    each scrape (a warm restart replaces the object).  The values are
-    snapshots written by the table-owning thread: observers never call
-    into the (unsynchronized) native table."""
-    for name, attr in _SLOT_COUNTERS + _LEG_COUNTERS:
+    of (_LEG_COUNTERS), and for a bank striped over a mesh what its
+    routing cost and how it spread (_ROUTED_COUNTERS, chip<j>.lanes).
+    `engine_of()` gives the bank's engine at each scrape (a warm
+    restart replaces the object, by one of the same kind).  The values
+    are snapshots written by the table-owning thread: observers never
+    call into the (unsynchronized) native table."""
+    counters = _SLOT_COUNTERS + _LEG_COUNTERS
+    chips = len(getattr(engine_of(), "stat_chip_lanes", ()))
+    if chips:
+        counters += _ROUTED_COUNTERS
+    for chip in range(chips):
+        store.counter_fn(  # tpu-lint: disable=metrics-discipline -- one name a chip of the mesh: bounded by the device count, like bank<i>
+            f"{base}.chip{chip}.lanes",
+            lambda c=chip: engine_of().stat_chip_lanes[c],
+        )
+    for name, attr in counters:
         store.counter_fn(
             base + "." + name, lambda a=attr: getattr(engine_of(), a)
         )
@@ -705,7 +725,8 @@ class CounterEngine:
         # stat_window_rollovers is a share of (one device lane a
         # group).  Monotonic; exported as a counter.
         self.stat_groups_launched = 0
-        # The bucket each device step ran at, all launches summed:
+        # The lanes each device step ran at — its bucket; on a mesh
+        # bank chips x the routed width — all launches summed:
         # stat_groups_launched over it is the share of device lanes
         # that carried a group, the rest being padding.  Monotonic;
         # exported as a counter.
@@ -1277,10 +1298,15 @@ class CounterEngine:
     def export_state(self) -> dict:
         """Named copy of the per-slot device state.  Fixed-window:
         ``{"counts": uint32[num_slots]}``; generic models expose one
-        row per ``model.state_rows`` name."""
-        arr = np.asarray(jax.device_get(self._counts))
+        row per ``model.state_rows`` name.  The counts go out through
+        export_counts as import_state takes them in through
+        import_counts: a mesh bank overrides the pair to speak GLOBAL
+        slot order — the slot table's — not its devices' layout."""
         rows = getattr(self.model, "state_rows", None)
-        if rows is None or arr.ndim == 1:
+        if rows is None:
+            return {"counts": self.export_counts()}
+        arr = np.asarray(jax.device_get(self._counts))
+        if arr.ndim == 1:
             return {"counts": arr.reshape(-1)}
         return {name: arr[i].copy() for i, name in enumerate(rows)}
 

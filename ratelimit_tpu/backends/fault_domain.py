@@ -117,13 +117,21 @@ def classify_fault(exc: BaseException) -> str:
 def default_engine_factory(bank: int, old_engine):
     """Rebuild a bank's engine from its predecessor's shape: same
     algorithm model (fresh state), same slot budget, buckets and
-    device.  Covers single-chip CounterEngine banks; mesh topologies
-    (parallel.ShardedCounterEngine) need an operator-supplied factory
-    — without one their restart attempt fails closed (the bank stays
-    quarantined on the fallback, still serving)."""
+    device — or, for a bank striped over a mesh
+    (parallel.ShardedCounterEngine), the same mesh."""
     from ..models.registry import get_algorithm
     from .engine import CounterEngine
 
+    mesh = getattr(old_engine.model, "mesh", None)
+    if mesh is not None:
+        from ..parallel import ShardedCounterEngine
+
+        return ShardedCounterEngine(
+            mesh,
+            num_slots=old_engine.model.num_slots,
+            near_ratio=old_engine.model.near_ratio,
+            buckets=tuple(old_engine.buckets),
+        )
     algo = getattr(old_engine, "algorithm", "fixed_window")
     model = get_algorithm(algo).make_model(
         old_engine.model.num_slots, old_engine.model.near_ratio

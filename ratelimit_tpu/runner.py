@@ -606,24 +606,32 @@ class Runner:
 
     def _where_it_runs(self) -> str:
         """Start-line suffix saying where the counters REALLY live:
-        the platform JAX initialised (not the BACKEND_TYPE setting) and
-        each bank's slot-table implementation.  Empty for the host-only
-        memory backend."""
+        the platform JAX initialised (not the BACKEND_TYPE setting),
+        each bank's slot-table implementation and, for banks striped
+        over a mesh (tpu-sharded), how many devices each spans.  Empty
+        for the host-only memory backend."""
         if not hasattr(self.cache, "engines"):
             return ""
         from .backends.checkpoint import bank_roles
         from .backends.engine import device_report
 
         dev = device_report()
-        tables = ",".join(
-            f"{role}:{engine.placement()['slot_table']}"
+        placed = [
+            (role, engine.placement())
             for role, engine in zip(
                 bank_roles(self.cache), self.cache.engines()
             )
+        ]
+        tables = ",".join(f"{role}:{p['slot_table']}" for role, p in placed)
+        meshes = ",".join(
+            f"{role}:{p['mesh_devices']}"
+            for role, p in placed
+            if "mesh_devices" in p
         )
         return (
             f" platform={dev['platform']} device_kind={dev['device_kind']!r}"
             f" devices={dev['device_count']} slot_tables={tables}"
+            + (f" mesh_devices={meshes}" if meshes else "")
         )
 
     def run(self) -> None:
