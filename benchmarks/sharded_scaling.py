@@ -1,12 +1,16 @@
-"""Virtual-mesh scaling check: per-chip work must SHRINK with banks.
+"""Virtual-mesh check of the bank-sharded engine: what the striping
+divides and what it does not.
 
-Round-1's sharded engine replicated the full batch to every chip
-(VERDICT weak #4); the round-2 routed design gives each chip only its
-~1/num_banks share.  On a virtual CPU mesh wall-clock is not chip
-wall-clock, so this reports the structural quantity that determines
-real scaling — per-chip lanes processed per step (the routed device
-batch width) — plus bit-identity against the single-chip engine and
-virtual-mesh step timings as a sanity signal.
+The TABLE divides: each chip holds 1/num_banks of the counters, and
+modulo striping spreads a launch's slots evenly over the banks (the
+per-bank OWNED lanes below, counted from the slot ids).  The LANES do
+not: since PR 50 ownership is decided on the device, every chip runs
+the launch's whole bucket and the host packs one launch as on one chip
+(routing the lanes on the host, rounds 2-5 and PR 49, cost a served
+launch ~0.5 ms on four real chips to spare idle chips 0.4 us: PERF.md
+section 5).  On a virtual CPU mesh wall-clock is not chip wall-clock,
+so this reports structure only — owned lanes per bank and bit-identity
+against the single-chip engine — and no timing.
 
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python benchmarks/sharded_scaling.py
@@ -17,7 +21,6 @@ from __future__ import annotations
 import json
 import os
 import sys
-import time
 
 import numpy as np
 
@@ -65,60 +68,38 @@ def main() -> None:
     rows = []
     for nd in (1, 2, 4, 8):
         engine = ShardedCounterEngine(make_mesh(nd), num_slots=NUM_SLOTS)
-        widths = []
         bank_counts = []
-        # Warmup isolation (r4 VERDICT weak #3): the routed cap varies
-        # per batch, so a single warmup step leaves some (bucket,
-        # dtype) shapes uncompiled and XLA compilation lands inside
-        # the timed loop (the old 2-bank row's 9.73ms spike).  Run the
-        # WHOLE sequence once untimed so every shape the timed pass
-        # uses is compiled.
-        for b in batches:
-            engine.step(b)
-        engine.reset()
-        lanes_before = np.array(engine.stat_chip_lanes)
-        t0 = time.perf_counter()
         for i, b in enumerate(batches):
-            token = engine.step_submit(b)
-            # token = (hits, limits, shadow, chunks); chunks[0][0] is
-            # the routed (num_banks, cap) device afters handle.
-            widths.append(token[3][0][0].shape[1])  # routed cap
-            # Real lanes each bank received in this step: the delta of
-            # the engine's cumulative per-chip counters.
-            lanes_so_far = np.array(engine.stat_chip_lanes)
-            bank_counts.append(lanes_so_far - lanes_before)
-            lanes_before = lanes_so_far
-            d = engine.step_complete(token)
+            # Lanes each bank OWNS in this step, from the slot ids.
+            bank_counts.append(
+                np.bincount(np.unique(b.slots) % nd, minlength=nd)
+            )
+            d = engine.step(b)
             np.testing.assert_array_equal(
                 d.codes, ref_decisions[i].codes, err_msg=f"mesh {nd}"
             )
             np.testing.assert_array_equal(
                 d.afters, ref_decisions[i].afters, err_msg=f"mesh {nd}"
             )
-        elapsed = time.perf_counter() - t0
         np.testing.assert_array_equal(
             engine.export_counts(), ref.export_counts()
         )
-        # Per-bank REAL lane counts (not the padded cap): the scaling
-        # evidence the r3 verdict asked for — each bank's share must
-        # shrink ~1/n and stay balanced (modulo striping).
+        # Per-bank OWNED lane counts: each bank's share of the table's
+        # traffic shrinks ~1/n and stays balanced (modulo striping).
         bc = np.asarray(bank_counts)  # (steps, nd)
         rows.append(
             {
                 "banks": nd,
-                "per_chip_lanes": int(np.mean(widths)),
-                "per_bank_real_lanes_mean": [
+                "per_bank_owned_lanes_mean": [
                     round(float(x), 1) for x in bc.mean(axis=0)
                 ],
-                "per_bank_real_lanes_max": [
+                "per_bank_owned_lanes_max": [
                     int(x) for x in bc.max(axis=0)
                 ],
                 "bank_imbalance_max_over_mean": round(
                     float(bc.max() / max(bc.mean(), 1e-9)), 3
                 ),
                 "full_batch": BATCH,
-                "work_fraction": round(float(np.mean(widths)) / BATCH, 3),
-                "virtual_mesh_ms_per_step": round(elapsed / STEPS * 1e3, 2),
             }
         )
         print(rows[-1], flush=True)

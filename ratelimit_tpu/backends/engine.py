@@ -525,15 +525,6 @@ _SLOT_COUNTERS = (
     ("slot_gc.freed", "stat_slot_gc_freed"),
     ("arena.compactions", "stat_arena_compactions"),
 )
-# A mesh bank's routing family (parallel.ShardedCounterEngine keeps
-# them; a one-chip bank has none and exports none), beside a counter a
-# chip, ratelimit.tpu.bank<i>.chip<j>.lanes.
-_ROUTED_COUNTERS = (
-    ("routed_launches", "stat_routed_launches"),
-    ("route_ns", "stat_route_ns"),
-    ("unroute_ns", "stat_unroute_ns"),
-    ("routed_busiest_lanes", "stat_routed_busiest_lanes"),
-)
 _SLOT_GAUGES = (
     ("live_keys", "stat_live_keys"),
     ("arena.bytes", "stat_arena_bytes"),
@@ -568,22 +559,12 @@ def register_slot_stats(store, base: str, engine_of: Callable) -> None:
     (ratelimit.tpu.bank<i>): occupancy and capacity, evictions, window
     rollovers over dedup groups launched, the collector's slot GC, the
     native table's arena, and what the device-call brackets were made
-    of (_LEG_COUNTERS), and for a bank striped over a mesh what its
-    routing cost and how it spread (_ROUTED_COUNTERS, chip<j>.lanes).
-    `engine_of()` gives the bank's engine at each scrape (a warm
-    restart replaces the object, by one of the same kind).  The values
-    are snapshots written by the table-owning thread: observers never
-    call into the (unsynchronized) native table."""
-    counters = _SLOT_COUNTERS + _LEG_COUNTERS
-    chips = len(getattr(engine_of(), "stat_chip_lanes", ()))
-    if chips:
-        counters += _ROUTED_COUNTERS
-    for chip in range(chips):
-        store.counter_fn(  # tpu-lint: disable=metrics-discipline -- one name a chip of the mesh: bounded by the device count, like bank<i>
-            f"{base}.chip{chip}.lanes",
-            lambda c=chip: engine_of().stat_chip_lanes[c],
-        )
-    for name, attr in counters:
+    of (_LEG_COUNTERS).  `engine_of()` gives the bank's engine at
+    each scrape (a warm restart replaces the object, by one of the same
+    kind).  The values are snapshots written by the table-owning
+    thread: observers never call into the (unsynchronized) native
+    table."""
+    for name, attr in _SLOT_COUNTERS + _LEG_COUNTERS:
         store.counter_fn(
             base + "." + name, lambda a=attr: getattr(engine_of(), a)
         )
@@ -627,8 +608,8 @@ class CounterEngine:
         ``lane_counts(out, dedup, hits, limits, now)`` on host — the
         engine then dispatches through the generic path and runs the
         shared threshold state machine (limiter.base.decide_batch).
-        For mesh models use parallel.ShardedCounterEngine, which
-        overrides the device submit with its routed path.
+        For mesh models use parallel.ShardedCounterEngine, whose model
+        brings the packed serving path over the mesh.
         `native_table`: None = use the C++ slot table when it
         builds/loads, True = require it, False = pure Python; generic
         models with stable-stem keys (windowed_keys=False) always get
@@ -725,8 +706,7 @@ class CounterEngine:
         # stat_window_rollovers is a share of (one device lane a
         # group).  Monotonic; exported as a counter.
         self.stat_groups_launched = 0
-        # The lanes each device step ran at — its bucket; on a mesh
-        # bank chips x the routed width — all launches summed:
+        # The bucket each device step ran at, all launches summed:
         # stat_groups_launched over it is the share of device lanes
         # that carried a group, the rest being padding.  Monotonic;
         # exported as a counter.
@@ -1151,8 +1131,8 @@ class CounterEngine:
         """Launch the device step for one deduped chunk; returns
         (device afters handle, reassemble-fn or None, shape).
         `reassemble`, when set, maps the fetched device array to one
-        (possibly saturated) `after` per unique slot — the sharded
-        engine uses it to unroute per-bank results.  `shape` names the
+        (possibly saturated) `after` per unique slot — no engine in
+        the tree sets it (ROADMAP D-queue).  `shape` names the
         compiled program the chunk ran: its bucket plus whatever else
         selects one (_device_call's key)."""
         g = len(dedup.uniq_slots)

@@ -82,10 +82,8 @@ def warmup_engine(engine) -> None:
     with inert batches — DISTINCT IN-TABLE slots with hits=0 and
     fresh=False, which scatter-add zero (or set a counter to its own
     value on the unique path), so counter state and the slot table are
-    untouched.  In-table slots matter for the sharded engine: its
-    routed path drops out-of-table lanes before bank routing, so
-    out-of-table probes would collapse every bucket to the smallest
-    routed shape and serving would still pay compiles.
+    untouched.  A mesh bank's shapes are the same (bucket, dtype) set:
+    its step takes the whole bucket on every chip.
 
     Module-level so the fault-domain supervisor can warm a freshly
     rebuilt engine OFF the serving path before probing/re-admitting it
@@ -96,14 +94,8 @@ def warmup_engine(engine) -> None:
     for bucket in engine.buckets:
         # One probe per readback dtype (u8 / u16 / u32 caps).
         # Distinct in-table slots so the engine's dedup pass keeps all
-        # `bucket` lanes; the engine supplies the slots that compile
-        # its WORST-case routed width for this bucket (the sharded
-        # engine's all-one-bank skew probe — see
-        # ShardedCounterEngine.warmup_probe_slots).
+        # `bucket` lanes (CounterEngine.warmup_probe_slots).
         probe_slots = engine.warmup_probe_slots(bucket)
-        # Companion arrays sized from the probe, not the bucket: the
-        # sharded engine clamps probe width to slots_per_bank on small
-        # tables.
         width = len(probe_slots)
         for probe_limit in (100, 60_000, 3_000_000_000):
             batch = HostBatch(

@@ -69,14 +69,12 @@ COLLECT_WINDOW = "rl.collect.window"  # waiting out the batch window
 LAUNCH = "rl.launch"  # all of _launch; stats: bank, launch_id
 LAUNCH_ASSIGN = "rl.launch.assign"  # native slot assign + dedup
 LAUNCH_PACK = "rl.launch.pack"  # numpy pack of the device batch
-LAUNCH_ROUTE = "rl.launch.route"  # inside pack, mesh banks only: unique slots -> the chip that owns each
 LAUNCH_DEVICE_CALL = "rl.launch.device_call"  # the dispatch: carries the packed lanes, asks for the readback copy
 GC = "rl.gc"  # slot-table gc
 CALL_TOKEN = "rl.call_token"  # run_on_thread: snapshot / checkpoint grabs
 # completer thread (_complete_loop / complete_items / step_complete)
 COMPLETE_IDLE = "rl.complete.idle"  # blocked on the completion queue
 COMPLETE_READBACK = "rl.complete.readback"  # the wait for the copy the launch asked for
-COMPLETE_UNROUTE = "rl.complete.unroute"  # mesh banks only: the chips' result rows back into group order
 COMPLETE_DECIDE = "rl.complete.decide"  # host threshold machine
 COMPLETE_SIGNAL = "rl.complete.signal"  # scatter + event.set() loop
 # background threads
@@ -108,12 +106,10 @@ SPAN_NAMES = (
     LAUNCH,
     LAUNCH_ASSIGN,
     LAUNCH_PACK,
-    LAUNCH_ROUTE,
     LAUNCH_DEVICE_CALL,
     GC,
     COMPLETE_IDLE,
     COMPLETE_READBACK,
-    COMPLETE_UNROUTE,
     COMPLETE_DECIDE,
     COMPLETE_SIGNAL,
     BG_CHECKPOINT_GRAB,

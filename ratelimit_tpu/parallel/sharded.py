@@ -14,11 +14,16 @@ Design (TPU-first, not a translation of the reference's Redis cluster):
   round-3 sharded-server test: 40 keys, one bank).
 - A batch is replicated to every chip.  Under ``shard_map`` each chip
   masks the batch to the slots it owns, runs the same branch-free
-  fixed-window decision body as the single-chip model
-  (models/fixed_window.py), and zeroes every lane it does not own.
+  fixed-window body as the single-chip model (models/fixed_window.py),
+  and zeroes every lane it does not own.
 - One ``psum`` over ``banks`` (rides ICI) recombines the per-lane
-  decisions: each lane is owned by exactly one chip, so the sum is a
+  answers: each lane is owned by exactly one chip, so the sum is a
   select.  No gather/scatter collectives, no host round trips.
+- The serving step (``step_counters_unique_packed``) is the one-chip
+  model's, entry for entry and body for body (each chip runs
+  ``FixedWindowModel.update_unique`` on its bank): the host packs one
+  ``int32[4, bucket]`` launch exactly as for one chip and never asks
+  which chip owns a slot (why: ShardedCounterEngine's docstring).
 
 This is the Redis-cluster key-slot analog (reference
 src/redis/driver_impl.go:108-126: radix cluster routes each key by hash
@@ -30,7 +35,6 @@ host-side SlotTable needs no changes.
 
 from __future__ import annotations
 
-import time
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -41,9 +45,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax import shard_map
 
 from ..backends.engine import CounterEngine
-from ..models.fixed_window import DeviceBatch, DeviceDecisions, decision_block
-from ..observability import spans as _spans
-from ..observability.spans import SPANS
+from ..models.fixed_window import (
+    DeviceBatch,
+    DeviceDecisions,
+    FixedWindowModel,
+    decision_block,
+)
 from ..ops.prefix import per_slot_inclusive_prefix
 
 
@@ -74,13 +81,16 @@ class ShardedFixedWindowModel:
         self.slots_per_bank = -(-int(num_slots) // self.num_banks)
         self.num_slots = self.slots_per_bank * self.num_banks
         self.near_ratio = float(near_ratio)
+        # One chip's bank as the one-chip model sees a table: the
+        # serving step runs ITS unique-slot body on local slot ids.
+        self._bank_model = FixedWindowModel(self.slots_per_bank, near_ratio)
 
         counts_spec = NamedSharding(mesh, P(self.axis, None))
         repl = NamedSharding(mesh, P())
         self._step = self._build(self._bank_step)
         self._step_counters = self._build(self._bank_update)
         self._compact_fns: dict = {}
-        self._routed_packed_fns: dict = {}
+        self._unique_packed_fns: dict = {}
         self._counts_sharding = counts_spec
         self._batch_sharding = repl
 
@@ -140,97 +150,65 @@ class ShardedFixedWindowModel:
             )
         return fn(counts, batch)
 
-    # -- routed unique fast path (divides work across banks) ------------
+    # -- unique fast path: the serving step ------------------------------
 
-    def step_counters_unique_routed_packed(
+    def step_counters_unique_packed(
         self, counts: jax.Array, out_dtype: str, packed: jax.Array
     ) -> Tuple[jax.Array, jax.Array]:
-        """Routed unique fast path fed by ONE packed int32[nb, 4, cap]
-        transfer (see FixedWindowModel.step_counters_unique_packed for
-        why packing: each host->device array copy costs ~hundreds of us
-        of dispatch overhead).  Rows per bank: local slots, hits (u32
-        bit-pattern), limits (u32 bit-pattern), fresh 0/1; sharded over
-        the mesh axis so each chip receives only its bank's rows.  The
-        per-chip program carries this method's name, so a device trace
-        shows the serving step as ``jit_step_counters_unique_routed_
-        packed`` on every chip's plane (the one-chip engine's is
-        ``jit_step_counters_unique_packed``)."""
-        fn = self._routed_packed_fns.get(out_dtype)
+        """FixedWindowModel.step_counters_unique_packed over the mesh:
+        the same packed int32[4, N] launch (GLOBAL slot ids, hits and
+        limits as uint32 bit-patterns, fresh 0/1; padding = distinct
+        out-of-table ids) goes to every chip, each chip updates the
+        lanes whose slots it owns, and one psum of the uint32 afters
+        makes the answer whole on every chip — so the host reads it
+        back from one.  Bit for bit the one-chip step's answers and
+        table.  The per-chip program carries this method's name, as
+        the one-chip engine's does (``jit_step_counters_unique_packed``
+        on every chip's plane of a device trace)."""
+        fn = self._unique_packed_fns.get(out_dtype)
         if fn is None:
 
-            def step_counters_unique_routed_packed(
-                counts, packed, _dt=out_dtype
-            ):
-                p = packed[0]  # (4, cap): this bank's rows
-                hits = jax.lax.bitcast_convert_type(p[1], jnp.uint32)
-                limits = jax.lax.bitcast_convert_type(p[2], jnp.uint32)
-                batch = DeviceBatch(
-                    slots=p[0][None, :],
-                    hits=hits[None, :],
-                    limits=limits[None, :],
-                    fresh=(p[3] != 0)[None, :],
-                    shadow=(p[3] != 0)[None, :],  # unused on device
+            def step_counters_unique_packed(counts, packed, _dt=out_dtype):
+                hits = jax.lax.bitcast_convert_type(packed[1], jnp.uint32)
+                limits = jax.lax.bitcast_convert_type(packed[2], jnp.uint32)
+                counts, afters = self._bank_unique(
+                    counts, packed[0], hits, packed[3] != 0
                 )
-                counts, afters = self._bank_unique(counts, batch)
+                afters = jax.lax.psum(afters, self.axis)
                 if _dt:
-                    cap = batch.limits + batch.hits
-                    afters = jnp.minimum(afters, cap).astype(jnp.dtype(_dt))
+                    afters = jnp.minimum(afters, limits + hits).astype(
+                        jnp.dtype(_dt)
+                    )
                 return counts, afters
 
-            counts_spec = NamedSharding(self.mesh, P(self.axis, None))
-            packed_spec = NamedSharding(self.mesh, P(self.axis, None, None))
-            out_routed = NamedSharding(self.mesh, P(self.axis, None))
-            fn = self._routed_packed_fns[out_dtype] = jax.jit(
-                shard_map(
-                    step_counters_unique_routed_packed,
-                    mesh=self.mesh,
-                    in_specs=(P(self.axis, None), P(self.axis, None, None)),
-                    out_specs=(P(self.axis, None), P(self.axis, None)),
-                ),
-                in_shardings=(counts_spec, packed_spec),
-                out_shardings=(counts_spec, out_routed),
-                donate_argnums=0,
-            )
+            fn = self._build(step_counters_unique_packed)
+            self._unique_packed_fns[out_dtype] = fn  # tpu-lint: disable=shared-state -- one GIL-atomic dict set a dtype, by the thread that owns the engine (CounterEngine._device_submit)
         return fn(counts, packed)
 
-    def _bank_unique(self, counts, batch: DeviceBatch):
-        """Unique-slot update for THIS bank's routed sub-batch (LOCAL
-        slot ids; padding = spb + lane index, distinct and inert).
-        Mirrors FixedWindowModel.update_unique."""
-        spb = self.slots_per_bank
-        row = counts[0]
-        slots = batch.slots[0]
-        hits = batch.hits[0].astype(jnp.uint32)
-        fresh = batch.fresh[0]
-
-        if spb % 128 == 0:
-            rows = slots >> 7
-            lanes = slots & 127
-            rowvals = (
-                row.reshape(-1, 128).at[rows].get(mode="fill", fill_value=0)
-            )
-            onehot = (
-                jax.lax.broadcasted_iota(jnp.int32, rowvals.shape, 1)
-                == lanes[:, None]
-            )
-            before = jnp.sum(
-                jnp.where(onehot, rowvals, jnp.uint32(0)),
-                axis=1,
-                dtype=jnp.uint32,
-            )
-        else:
-            before = row.at[slots].get(mode="fill", fill_value=0)
-
-        before = jnp.where(fresh, jnp.uint32(0), before)
-        # Saturating add, mirroring FixedWindowModel.update_unique
-        # (u32-native wrap detect; a modular wrap would reset
-        # enforcement for lapped keys).
-        afters = before + hits
-        afters = jnp.where(
-            afters < before, jnp.uint32(0xFFFFFFFF), afters
+    def _bank_unique(self, counts, slots, hits, fresh):
+        """Unique-slot update of THIS chip's bank from the whole
+        launch; returns (counts, afters) with `afters` 0 on every lane
+        another chip answers.  FixedWindowModel.update_unique on the
+        bank, at local positions (bank = slot % num_banks, local =
+        slot // num_banks); every lane this chip does not own goes to
+        a distinct out-of-bank index (spb + lane), so it reads a
+        virtual zero, scatters nowhere and the unique-scatter promise
+        holds.  Chip 0 answers out-of-table lanes (padding, probes)
+        with `after = hits`, as the one-chip step does."""
+        nb = jnp.int32(self.num_banks)
+        bank = jax.lax.axis_index(self.axis)
+        in_table = (slots >= 0) & (slots < self.num_slots)
+        owns = in_table & (slots % nb == bank)
+        lane = jax.lax.broadcasted_iota(jnp.int32, slots.shape, 0)
+        local = jnp.where(owns, slots // nb, self.slots_per_bank + lane)
+        row, afters = self._bank_model.update_unique(
+            counts[0],
+            DeviceBatch(
+                slots=local, hits=hits, limits=hits, fresh=fresh, shadow=fresh
+            ),  # limits / shadow: unused by the update
         )
-        row = row.at[slots].set(afters, mode="drop", unique_indices=True)
-        return row[None, :], afters[None, :]
+        answers = owns | (~in_table & (bank == 0))
+        return row[None, :], jnp.where(answers, afters, jnp.uint32(0))
 
     # -- per-bank SPMD bodies (run on every chip under shard_map) -------
 
@@ -295,14 +273,19 @@ class ShardedFixedWindowModel:
 class ShardedCounterEngine(CounterEngine):
     """CounterEngine over a bank-sharded model.
 
-    Host orchestration (slot table, dedup, host-side decide) is
-    inherited; the device step is the ROUTED unique fast path: unique
-    slots are routed host-side to their owning bank (the Redis-cluster
-    key-slot analog, driver_impl.go:108-126), each chip processes only
-    its ~1/num_banks share of the batch under shard_map, and results
-    are unrouted on readback — per-chip work SHRINKS with mesh size
-    (round-1 VERDICT weak #4: the replicated design did full-batch
-    work on every chip)."""
+    Everything a launch runs is inherited — slot table, dedup, the one
+    packed launch (CounterEngine._device_submit), the readback, the
+    host-side decide: the model's step decides on the device which chip
+    owns a lane, so the host never routes, `shape` is (bucket, dtype)
+    as on one chip, and the replicated answer is copied to the host
+    from one chip.  What the striping is for is the TABLE: each chip
+    holds 1/num_banks of the counters (the Redis-cluster key-slot
+    analog, driver_impl.go:108-126), so a table grows with the mesh.
+    The lanes are not divided: every chip sees the launch's whole
+    bucket, because 16x the lanes cost a chip +0.4 us while dividing
+    them on the host cost the launch ~0.5 ms (PERF.md section 5,
+    PR 49 -> 50).  This class adds the layout: where the bank lives
+    and the global slot order of its checkpoint surface."""
 
     def __init__(
         self,
@@ -315,112 +298,11 @@ class ShardedCounterEngine(CounterEngine):
             buckets=buckets,
             model=ShardedFixedWindowModel(num_slots, mesh, near_ratio),
         )
-        # What routing costs and how evenly it spreads, all launches
-        # summed — monotonic, exported as counters beside the bank's
-        # padded_lanes (engine.register_slot_stats), plain ints with
-        # one writer each like the engine's other stats: device steps
-        # launched, host time spent routing them (the submitting
-        # thread's) and unrouting their results (the completing
-        # thread's), chips x the busiest chip's real lanes (over
-        # dedup_groups: 1 = an even spread, chips = all on one chip;
-        # the busiest chip sets the width every chip runs at), and the
-        # real lanes each chip received.
-        self.stat_routed_launches = 0
-        self.stat_route_ns = 0
-        self.stat_unroute_ns = 0
-        self.stat_routed_busiest_lanes = 0
-        self.stat_chip_lanes = [0] * self.model.num_banks
 
     def placement(self) -> dict:
         """The bank's placement plus the size of the mesh its table is
         striped over (runner start line, /debug/faults)."""
         return {**super().placement(), "mesh_devices": self.model.num_banks}
-
-    def _device_submit(self, dedup, now, watch):
-        """One routed device step on the launch protocol of
-        CounterEngine._device_submit: route and pack on the host
-        (rl.launch.pack, the routing itself rl.launch.route inside it),
-        hand the packed numpy to the jitted step as it is, ask for the
-        readback copy inside the device-call bracket; `reassemble`
-        unroutes the fetched rows (rl.complete.unroute).  `shape` is
-        (lanes shipped = chips x routed width, readback dtype).
-        `now` is the generic-algorithm batch clock; the sharded engine
-        serves fixed-window only (see CounterEngine)."""
-        m = self.model
-        spb = m.slots_per_bank
-        nb = m.num_banks
-        uniq = dedup.uniq_slots
-        g = len(uniq)
-        with SPANS.span(_spans.LAUNCH_PACK):
-            t_route = time.monotonic_ns()
-            with SPANS.span(_spans.LAUNCH_ROUTE):
-                valid = (uniq >= 0) & (uniq < m.num_slots)
-                vi = np.nonzero(valid)[0]
-                banks_u = (uniq[vi] % nb).astype(np.int64)
-                # Modulo-striped ownership: sorted uniq is NOT
-                # bank-grouped, so order lanes by bank (stable) before
-                # computing per-bank positions.
-                order = np.argsort(banks_u, kind="stable")
-                vi = vi[order]
-                banks = banks_u[order]
-                counts_pb = np.bincount(banks, minlength=nb)
-                starts = np.concatenate([[0], np.cumsum(counts_pb)])
-                pos = np.arange(len(vi)) - starts[banks]
-                busiest = int(counts_pb.max(initial=0))
-                cap = self._bucket(max(busiest, 1))
-            self.stat_route_ns += time.monotonic_ns() - t_route  # tpu-lint: disable=shared-state -- collector-owned engine
-            self.stat_routed_launches += 1  # tpu-lint: disable=shared-state -- collector-owned engine
-            self.stat_routed_busiest_lanes += nb * busiest  # tpu-lint: disable=shared-state -- collector-owned engine
-            chip_lanes = self.stat_chip_lanes
-            for bank, lanes in enumerate(counts_pb.tolist()):
-                chip_lanes[bank] += lanes
-
-            # Clamp (not wrap) into the saturating u32 counter domain.
-            totals32 = dedup.totals_u32()
-            # ONE packed int32[nb, 4, cap] routed transfer (vs five
-            # routed arrays; see CounterEngine._device_submit), handed
-            # to the jitted step as numpy: uncommitted, so the dispatch
-            # places each chip's rows per the routed sharding without a
-            # device_put of its own or a cross-device reshard.  Padding
-            # slots are distinct out-of-bank ids so the unique-scatter
-            # promise holds.
-            pk = np.empty((nb, 4, cap), dtype=np.int32)
-            pk[:, 0, :] = spb + np.arange(cap, dtype=np.int32)
-            pk[:, 1, :] = 0
-            pk[:, 2, :] = 1
-            pk[:, 3, :] = 0
-            pk[banks, 0, pos] = (uniq[vi] // nb).astype(np.int32)
-            pk[banks, 1, pos] = totals32[vi].view(np.int32)
-            pk[banks, 2, pos] = dedup.limit_max[vi].view(np.int32)
-            pk[banks, 3, pos] = dedup.fresh[vi]
-
-            # Unwrapped uint64 totals for the dtype choice (see
-            # CounterEngine._device_submit): clamped-total groups take
-            # the raw uint32 path, never the narrow readback.
-            cap_val = int(dedup.totals[vi].max(initial=0)) + int(
-                dedup.limit_max[vi].max(initial=1)
-            )
-            dt = "uint8" if cap_val <= 0xFF else ("uint16" if cap_val <= 0xFFFF else "")
-        shape = (nb * cap, dt)
-        with self._device_call(watch, shape):
-            self._counts, afters_dev = m.step_counters_unique_routed_packed(
-                self._counts, dt, pk
-            )
-            afters_dev.copy_to_host_async()
-
-        def reassemble(fetched: np.ndarray) -> np.ndarray:
-            t_unroute = time.monotonic_ns()
-            with SPANS.span(_spans.COMPLETE_UNROUTE):
-                out = np.zeros(g, dtype=np.uint32)
-                out[vi] = fetched[banks, pos]
-                # Out-of-table slots (warmup probes) behave like the
-                # single-chip path: before=0, after=hits (never
-                # saturated — totals <= cap_val by dtype choice).
-                out[~valid] = totals32[~valid]
-            self.stat_unroute_ns += time.monotonic_ns() - t_unroute  # tpu-lint: disable=shared-state -- one completing thread per engine
-            return out
-
-        return afters_dev, reassemble, shape
 
     def export_counts(self) -> np.ndarray:
         """Flat uint32 copy in GLOBAL slot order: bank b's local
@@ -431,19 +313,6 @@ class ShardedCounterEngine(CounterEngine):
             m.num_banks, m.slots_per_bank
         )
         return arr.T.reshape(-1)
-
-    def warmup_probe_slots(self, bucket: int) -> np.ndarray:
-        """All-one-bank probes: under modulo striping, slots
-        k*num_banks land on bank 0, so this probe's routed cap is the
-        worst (skew) width this engine can ever serve for a
-        `bucket`-lane batch — min(bucket, slots_per_bank), since one
-        bank physically holds at most slots_per_bank distinct slots.
-        The clamp keeps the slots distinct and in-table on small
-        tables/large meshes (bucket > spb)."""
-        m = self.model
-        width = min(int(bucket), m.slots_per_bank)
-        slots = np.arange(width, dtype=np.int64) * m.num_banks
-        return slots.astype(np.int32)
 
     def import_counts(self, counts) -> None:
         arr = np.asarray(counts, dtype=np.uint32).reshape(-1)

@@ -51,12 +51,10 @@ CHECKS = [
         lambda d: round(d["per_lane_cost_flatness_worst_over_base"], 2),
     ),
     (
-        "sharded 2-bank step time",
-        r"2-bank step time ([0-9.]+)ms",
+        "sharded owned-lane imbalance",
+        r"imbalance <= ([0-9.]+), bit-identical",
         "sharded_scaling.json",
-        lambda d: next(r for r in d if r["banks"] == 2)[
-            "virtual_mesh_ms_per_step"
-        ],
+        lambda d: round(max(r["bank_imbalance_max_over_mean"] for r in d), 2),
     ),
     (
         "write-behind p50",

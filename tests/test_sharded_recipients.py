@@ -10,29 +10,33 @@ small size on the 8-device virtual CPU mesh of tests/conftest.py —
       backends/memory_cache.py AND against chipbench/reference.py's
       `Ledger`: keys crossing their cap of 5, a key several times
       inside one request, a DAY boundary;
-  (b) a request all of whose slots one chip owns (the worst routed
-      width) and one spread evenly over the chips, with what the new
-      counters say of each (`padded_lanes` = chips x width,
-      `routed_busiest_lanes`, `chip<i>.lanes`);
-  (c) the shares add up: for seeded batches the unrouted result of
-      meshes of 1, 2, 4 and 8 equals the one-chip `CounterEngine` bit
-      for bit, the counters on the devices included;
+  (b) a request all of whose slots one chip owns and one spread
+      evenly over the chips: the same answers, the same `shape` and
+      `padded_lanes` — the bucket, as on one chip — whatever the skew,
+      and no routing counter on any bank;
+  (c) the shares add up: for seeded batches (duplicate slots, fresh
+      lanes, a saturating total, out-of-table probes, every readback
+      dtype) meshes of 1, 2, 4 and 8 equal the one-chip `CounterEngine`
+      bit for bit, the counters on the devices included;
   (d) an injected device fault on a sharded bank -> quarantine ->
       restart through the DEFAULT engine factory -> counters restored,
       later answers right;
-  (e) the launch protocol: the readback copy is asked for inside the
-      device-call bracket, `rl.launch.route` stands inside
-      `rl.launch.pack`, `rl.complete.unroute` on the completer, and the
-      routed program carries a name a device trace can show;
-  (f) BENCHMARK.json's new entries find their files, the cell's
-      rehearsal is `correct` and both controls are not, and the three
-      new metrics read the change and are silent on the parent and on
-      an unsharded bank."""
+  (e) the launch protocol: the one-chip engine's — one `rl.launch.pack`
+      with nothing inside it, the readback copy asked for inside the
+      device-call bracket and from ONE chip, nothing between readback
+      and decide — and the program carries the one-chip program's
+      name, compiles for a described `v5e:2x2`, donates the counters
+      and holds exactly one all-reduce;
+  (f) BENCHMARK.json's entries find their files, the cell's rehearsal
+      is `correct` and both controls are not, and PR 49's three
+      routing metrics read the PR 49 program's counters, read nothing
+      on this tree and raise nothing."""
 
 import contextlib
 import glob
 import json
 import os
+import re
 import subprocess
 import sys
 import threading
@@ -71,7 +75,6 @@ DAY = 86_400
 LIMIT = 5
 CHIPS = 8  # the virtual mesh of tests/conftest.py
 BANK = "ratelimit.tpu.bank0."
-ROUTED = ("routed_launches", "route_ns", "unroute_ns", "routed_busiest_lanes")
 
 pytestmark = pytest.mark.skipif(
     "xla_force_host_platform_device_count=8" not in os.environ.get("XLA_FLAGS", ""),
@@ -167,10 +170,6 @@ class Served:
     def stat(self, name: str) -> int:
         return self.store.snapshot()[BANK + name]
 
-    def chip_lanes(self) -> np.ndarray:
-        snap = self.store.snapshot()
-        return np.array([snap[f"{BANK}chip{i}.lanes"] for i in range(CHIPS)])
-
     def close(self) -> None:
         self.channel.close()
         self.runner.stop()
@@ -240,7 +239,7 @@ def test_the_served_sharded_path_matches_memory_cache_and_the_reference(served, 
     first_day = {k for (k, w) in s.ledger.hits if w == T0 // DAY}
     second_day = {k for (k, w) in s.ledger.hits if w == day2 // DAY}
     assert len(first_day & second_day) > 100
-    assert s.stat("dedup_groups") == int(s.chip_lanes().sum())
+    assert s.stat("dedup_groups") == s.engine.stat_groups_launched <= s.stat("padded_lanes")
 
 
 # -- (b) one chip's slots, and an even spread ---------------------------------
@@ -248,41 +247,40 @@ def test_the_served_sharded_path_matches_memory_cache_and_the_reference(served, 
 
 def test_a_request_on_one_chip_and_one_spread_evenly_are_exact_and_counted(served):
     """The slot table hands out slots densely in arrival order, so the
-    i-th new key sits on chip i % 8.  64 keys of one chip run at the
-    worst routed width (64 lanes on every chip for 64 groups); 8 keys
-    of each chip run at width 8."""
+    i-th new key sits on chip i % 8.  64 keys of one chip and 8 keys of
+    each chip are the same launch to the host: 64 groups in a bucket of
+    64, whichever chips own them."""
     s = served(SEEDS[0], keys=1024)
     load = np.arange(1024)
     for lo in range(0, 1024, 256):
         assert s.ask(load[lo : lo + 256]) == [(OK, LIMIT - 1, LIMIT)] * 256
-    before = {n: s.stat(n) for n in ROUTED + ("padded_lanes", "dedup_groups")}
-    lanes_before = s.chip_lanes()
-    assert lanes_before.tolist() == [128] * CHIPS  # the load itself spreads evenly
+    before = {n: s.stat(n) for n in ("padded_lanes", "dedup_groups")}
+    assert before == {"padded_lanes": 1024, "dedup_groups": 1024}
 
     chip = 5
     one_chip = load[chip::CHIPS][:64]
     assert s.ask(one_chip) == [(OK, LIMIT - 2, LIMIT)] * 64
     after = {n: s.stat(n) for n in before}
-    lanes = s.chip_lanes() - lanes_before
-    assert lanes.tolist() == [64 * (i == chip) for i in range(CHIPS)]
-    assert after["routed_launches"] - before["routed_launches"] == 1
     assert after["dedup_groups"] - before["dedup_groups"] == 64
-    assert after["padded_lanes"] - before["padded_lanes"] == CHIPS * 64
-    assert after["routed_busiest_lanes"] - before["routed_busiest_lanes"] == CHIPS * 64
+    assert after["padded_lanes"] - before["padded_lanes"] == 64
 
     even = np.concatenate([load[c::CHIPS][64:72] for c in range(CHIPS)])
     np.random.default_rng(7).shuffle(even)
     assert s.ask(even) == [(OK, LIMIT - 2, LIMIT)] * 64
     last = {n: s.stat(n) for n in before}
-    assert (s.chip_lanes() - lanes_before - lanes).tolist() == [8] * CHIPS
-    assert last["padded_lanes"] - after["padded_lanes"] == CHIPS * 8
-    assert last["routed_busiest_lanes"] - after["routed_busiest_lanes"] == 64 == CHIPS * 8
-    assert last["route_ns"] > after["route_ns"] > before["route_ns"] > 0
-    assert last["unroute_ns"] > after["unroute_ns"] > before["unroute_ns"] > 0
+    assert last["dedup_groups"] - after["dedup_groups"] == 64
+    assert last["padded_lanes"] - after["padded_lanes"] == 64
+    # Two shapes in all: the load's and the two requests' one.
+    assert s.engine._proven_shapes == {(256, "uint8"), (64, "uint8")}
 
     assert s.mismatches == 0 and s.differ_from_memory == 0, s.first
-    # lane_fill_share.paced's two counters: never over 100%.
-    assert last["dedup_groups"] <= last["routed_busiest_lanes"] <= last["padded_lanes"]
+    # The hits are on the chips that own the slots: one_chip's 64 on
+    # chip 5 alone, even's 8 on each.
+    counts = np.asarray(s.engine._counts)
+    assert counts.shape == (CHIPS, (1 << 14) // CHIPS)
+    assert (counts == 2).sum(axis=1).tolist() == [8 + 64 * (i == chip) for i in range(CHIPS)]
+    # A mesh bank exports a one-chip bank's counters and no other.
+    assert not [n for n in s.store.snapshot() if n.startswith(BANK) and ("rout" in n or ".chip" in n)]
     # Where it runs is said where an operator looks: the start line and
     # /debug/faults.
     assert s.runner._where_it_runs().endswith(f" mesh_devices=lane0of1:{CHIPS}")
@@ -295,9 +293,11 @@ def test_a_request_on_one_chip_and_one_spread_evenly_are_exact_and_counted(serve
 
 
 def seeded_batches(seed: int, num_slots: int, steps: int = 6):
-    """Batches with duplicate slots, fresh lanes, every readback dtype
-    (uint8 / uint16 / raw uint32), a saturating total and an
-    out-of-table lane."""
+    """Batches with duplicate slots, fresh lanes (a tenth, some on
+    counters already counted up: the reset shows), every readback
+    dtype (uint8 / uint16 / raw uint32), a saturating total — once
+    clamped on the host (a group's total past u32), once on the device
+    (a counter near u32 max hit again) — and an out-of-table lane."""
     rng = np.random.default_rng([seed, 49])
     out = []
     for step in range(steps):
@@ -306,13 +306,15 @@ def seeded_batches(seed: int, num_slots: int, steps: int = 6):
         slots[rng.integers(0, n, n // 4)] = slots[rng.integers(0, n, n // 4)]  # duplicates
         limits = rng.integers(1, (200, 60_000, 3_000_000_000)[step % 3], n).astype(np.uint32)
         hits = rng.integers(1, 4, n).astype(np.uint32)
-        if step == steps - 1:
+        fresh = rng.random(n) < 0.1
+        if step >= steps - 2:
             hits[:3] = 0xFFFFFFF0  # totals past u32: clamped, saturating
-            slots[:3] = slots[0]
+            slots[:3] = 7 % num_slots
+            hits[3] = 0xFFFFFF00  # its slot's counter + this wraps u32 on the device: pinned at max
+            slots[3] = 11 % num_slots
+            fresh &= (slots != slots[0]) & (slots != slots[3])  # they stay: the second batch adds onto u32 max
             slots[-1] = num_slots + 5  # out of the table
-        out.append(
-            HostBatch(slots=slots, hits=hits, limits=limits, fresh=rng.random(n) < 0.1, shadow=rng.random(n) < 0.1)
-        )
+        out.append(HostBatch(slots=slots, hits=hits, limits=limits, fresh=fresh, shadow=rng.random(n) < 0.1))
     return out
 
 
@@ -331,34 +333,83 @@ def test_a_mesh_of_1_2_4_8_equals_the_one_chip_engine_bit_for_bit(chips, seed):
         groups += len(np.unique(batch.slots))
     np.testing.assert_array_equal(mesh.export_counts(), one.export_counts())
     assert one.export_counts().sum() > 0
+    assert one.export_counts()[[7, 11]].tolist() == [0xFFFFFFFF] * 2  # saturated, not wrapped
     # What a snapshot, a checkpoint or a handoff takes (export_state)
     # is in the slot table's order on a mesh too, and goes back as it came.
     np.testing.assert_array_equal(mesh.export_state()["counts"], one.export_state()["counts"])
     mesh.import_state(mesh.export_state())
     np.testing.assert_array_equal(mesh.export_counts(), one.export_counts())
-    # What the routing counted: every group on exactly one chip, the
-    # busiest chip never below the mean, chips x width lanes shipped.
+    # What the host shipped and the watchdog proved: the one-chip
+    # engine's, launch for launch — every readback dtype among them.
     assert mesh.stat_groups_launched == one.stat_groups_launched == groups
-    assert sum(mesh.stat_chip_lanes) == groups - 1  # the out-of-table lane rides no chip
-    assert mesh.stat_routed_launches == 6
-    assert groups - 1 <= mesh.stat_routed_busiest_lanes <= mesh.stat_padded_lanes
-    assert mesh.stat_padded_lanes % (chips * 8) == 0
-    if chips == 1:
-        assert mesh.stat_routed_busiest_lanes == groups - 1
+    assert mesh.stat_padded_lanes == one.stat_padded_lanes
+    assert mesh._proven_shapes == one._proven_shapes
+    assert {dt for _, dt in mesh._proven_shapes} == {"uint8", "uint16", ""}
+    # Warm-up probes out of the table, all of them: chip 0 answers
+    # each `after = hits`, and no chip's bank moves.
+    probes = HostBatch(
+        slots=np.arange(num_slots, num_slots + 40, dtype=np.int32), hits=np.arange(40, dtype=np.uint32),
+        limits=np.full(40, 100, np.uint32), fresh=np.zeros(40, bool), shadow=np.zeros(40, bool),
+    )
+    want, got = one.step(probes), mesh.step(probes)
+    np.testing.assert_array_equal(got.afters, np.arange(40))
+    np.testing.assert_array_equal(got.codes, want.codes)
+    np.testing.assert_array_equal(mesh.export_counts(), one.export_counts())
+
+
+def test_a_mesh_bank_exports_a_one_chip_banks_counters_and_no_routing_counter():
+    def names(engine):
+        manager = Manager()
+        cache = TpuRateLimitCache(engine, time_source=PinnedTimeSource(T0))
+        try:
+            cache.register_stats(manager.store)
+            return {n for n in manager.store.snapshot() if n.startswith(BANK)}
+        finally:
+            cache.close()
+
+    one = names(CounterEngine(num_slots=256, buckets=(8,)))
+    mesh = names(ShardedCounterEngine(make_mesh(4), num_slots=256, buckets=(8,)))
+    assert BANK + "padded_lanes" in one and mesh == one
+    assert not [n for n in mesh if "rout" in n or ".chip" in n]
+
+
+@pytest.mark.parametrize("skew", ["one_chip", "even", "random"])
+def test_a_mesh_launch_has_the_one_chip_engines_shape_whatever_the_skew(skew):
+    """`shape` — the compiled program a launch runs, the watchdog's
+    key — and `padded_lanes` depend on how many groups a launch holds
+    and not on which chips own them: the warm-up proves every shape
+    serving can meet, on a mesh as on one chip."""
+    from ratelimit_tpu.backends.tpu_cache import warmup_engine
+
+    chips, num_slots, buckets = 4, 1 << 10, (8, 32, 64)
+    one = CounterEngine(num_slots=num_slots, buckets=buckets)
+    mesh = ShardedCounterEngine(make_mesh(chips), num_slots=num_slots, buckets=buckets)
+    warmup_engine(one)
+    warmup_engine(mesh)
+    proven = {(b, dt) for b in buckets for dt in ("uint8", "uint16", "")}
+    assert mesh._proven_shapes == one._proven_shapes == proven
+    assert mesh.stat_padded_lanes == one.stat_padded_lanes == 3 * sum(buckets)
+    rng = np.random.default_rng(50)
+    for n in (3, 8, 9, 30, 33, 64):
+        slots = {
+            "one_chip": np.arange(n) * chips + 1,  # all on chip 1
+            "even": np.arange(n),  # chip i % 4
+            "random": rng.choice(num_slots, n, replace=False),
+        }[skew].astype(np.int32)
+        batch = HostBatch(
+            slots=slots, hits=np.ones(n, np.uint32), limits=np.full(n, 5, np.uint32),
+            fresh=np.zeros(n, bool), shadow=np.zeros(n, bool),
+        )
+        tokens = [engine.step_submit(batch) for engine in (one, mesh)]
+        (_, _, _, _, lift_one, shape_one), = tokens[0][3]
+        (afters, _, _, _, lift_mesh, shape_mesh), = tokens[1][3]
+        assert shape_mesh == shape_one == (one._bucket(n), "uint8") and shape_mesh in proven
+        assert lift_one is None and lift_mesh is None and afters.is_fully_replicated
+        want, got = one.step_complete(tokens[0]), mesh.step_complete(tokens[1])
+        np.testing.assert_array_equal(got.afters, want.afters)
         assert mesh.stat_padded_lanes == one.stat_padded_lanes
-    assert not hasattr(one, "stat_chip_lanes")
-
-
-def test_an_unsharded_bank_exports_none_of_the_routed_counters():
-    manager = Manager()
-    cache = TpuRateLimitCache(CounterEngine(num_slots=256, buckets=(8,)), time_source=PinnedTimeSource(T0))
-    try:
-        cache.register_stats(manager.store)
-        names = set(manager.store.snapshot())
-        assert BANK + "padded_lanes" in names
-        assert not [n for n in names if "rout" in n or ".chip" in n]
-    finally:
-        cache.close()
+    assert mesh._proven_shapes == proven
+    np.testing.assert_array_equal(mesh.export_counts(), one.export_counts())
 
 
 # -- (d) fault -> quarantine -> restart through the default factory ----------
@@ -430,8 +481,8 @@ def test_a_faulted_mesh_bank_restarts_through_the_default_factory_with_its_count
         assert sorted(counts[counts > 1].tolist()) == [25] * len(keys)
         # The bank's counters follow the restart: they read the new engine.
         snap = manager.store.snapshot()
-        assert snap[BANK + "routed_launches"] == rebuilt.stat_routed_launches > 0
-        assert sum(snap[f"{BANK}chip{i}.lanes"] for i in range(4)) == snap[BANK + "dedup_groups"]
+        assert snap[BANK + "dedup_groups"] == rebuilt.stat_groups_launched > 0
+        assert snap[BANK + "padded_lanes"] == rebuilt.stat_padded_lanes >= snap[BANK + "dedup_groups"]
         (bank,) = fd.summary()["banks"]
         assert (bank["state"], bank["restarts"], bank["mesh_devices"]) == ("closed", 1, 4)
     finally:
@@ -443,13 +494,17 @@ def test_a_faulted_mesh_bank_restarts_through_the_default_factory_with_its_count
 
 
 def test_the_readback_copy_is_asked_for_inside_the_device_call_bracket(monkeypatch):
-    """One round trip a launch, as every other engine (PR 26): the
+    """One round trip a launch, as every other engine (PR 26) and by
+    the same code (CounterEngine._device_submit, inherited whole): the
     packed numpy goes to the jitted step as it is, and
     copy_to_host_async() is called on its result before the bracket
-    closes — the completer then finds the copy on its way."""
+    closes — the completer then finds the copy on its way.  The result
+    is whole on every chip, so one chip's copy is the answer."""
+    assert ShardedCounterEngine._device_submit is CounterEngine._device_submit
+    assert ShardedCounterEngine.warmup_probe_slots is CounterEngine.warmup_probe_slots
     engine = ShardedCounterEngine(make_mesh(4), num_slots=1 << 10, buckets=(8, 32))
     events = []
-    real_call, real_step = engine._device_call, engine.model.step_counters_unique_routed_packed
+    real_call, real_step = engine._device_call, engine.model.step_counters_unique_packed
 
     class Result:
         def __init__(self, array):
@@ -466,9 +521,11 @@ def test_the_readback_copy_is_asked_for_inside_the_device_call_bracket(monkeypat
             return np.asarray(self.array)
 
     def step(counts, dt, packed):
-        assert type(packed) is np.ndarray and packed.shape == (4, 4, 8) and packed.dtype == np.int32
+        assert type(packed) is np.ndarray and packed.shape == (4, 32) and packed.dtype == np.int32
+        assert packed[0].tolist() == list(range(20)) + list(range(1 << 10, (1 << 10) + 12))  # global ids, as on one chip
         events.append("step")
         counts, afters = real_step(counts, dt, packed)
+        assert afters.shape == (32,) and afters.is_fully_replicated and len(afters.sharding.device_set) == 4
         return counts, Result(afters)
 
     @contextlib.contextmanager
@@ -479,25 +536,27 @@ def test_the_readback_copy_is_asked_for_inside_the_device_call_bracket(monkeypat
         events.append(("close", shape, *leg))
 
     monkeypatch.setattr(engine, "_device_call", bracket)
-    monkeypatch.setattr(engine.model, "step_counters_unique_routed_packed", step)
+    monkeypatch.setattr(engine.model, "step_counters_unique_packed", step)
     batch = HostBatch(
         slots=np.arange(20, dtype=np.int32), hits=np.ones(20, np.uint32), limits=np.full(20, 5, np.uint32),
         fresh=np.zeros(20, bool), shadow=np.zeros(20, bool),
     )
     decisions = engine.step(batch)
     assert decisions.afters.tolist() == [1] * 20
-    shape = (4 * 8, "uint8")  # 20 slots over 4 chips: 5 each, width 8; lanes shipped 32
+    shape = (32, "uint8")  # 20 slots: the bucket of 32, as on one chip
     assert events == [
         ("open", shape), "step", "copy_to_host_async", ("close", shape),
         ("open", shape, span_names.COMPLETE_READBACK), ("close", shape, span_names.COMPLETE_READBACK),
     ]
-    assert engine.stat_padded_lanes == 32 and engine.stat_chip_lanes == [5, 5, 5, 5]
+    assert engine.stat_padded_lanes == 32 and engine.stat_groups_launched == 20
+    assert np.asarray(engine._counts)[:, :5].tolist() == [[1] * 5] * 4  # 5 slots on each chip's bank
 
 
-def test_the_routed_program_has_a_name_a_device_trace_can_show():
-    """A device plane names a program `jit_<function>`: the routed
-    step's is jit_step_counters_unique_routed_packed on every chip,
-    which kernel_step_us.paced's pattern `step_counters` finds."""
+def test_the_mesh_program_has_the_one_chip_programs_name_in_a_device_trace():
+    """A device plane names a program `jit_<function>`: the mesh step's
+    is jit_step_counters_unique_packed on every chip, the one-chip
+    engine's own name, which kernel_step_us.paced's pattern
+    `step_counters` finds."""
     engine = ShardedCounterEngine(make_mesh(4), num_slots=1 << 10, buckets=(8,))
     engine.step(
         HostBatch(
@@ -505,13 +564,17 @@ def test_the_routed_program_has_a_name_a_device_trace_can_show():
             fresh=np.zeros(4, bool), shadow=np.zeros(4, bool),
         )
     )
-    (fn,) = engine.model._routed_packed_fns.values()
-    packed = jax.ShapeDtypeStruct((4, 4, 8), np.int32)
+    (fn,) = engine.model._unique_packed_fns.values()
+    packed = jax.ShapeDtypeStruct((4, 8), np.int32)
     counts = jax.ShapeDtypeStruct(engine._counts.shape, engine._counts.dtype)
     text = fn.lower(counts, packed).as_text()
-    assert "jit_step_counters_unique_routed_packed" in text and "jit_body" not in text
+    assert "jit_step_counters_unique_packed" in text and "jit_body" not in text and "routed" not in text
+    one = CounterEngine(num_slots=1 << 10, buckets=(8,)).model
+    assert "jit_step_counters_unique_packed" in type(one).step_counters_unique_packed.lower(
+        one, jax.ShapeDtypeStruct((1 << 10,), np.uint32), "uint8", packed
+    ).as_text()
     spec = load_json("layer_metrics", "kernel_step_us.paced")["reader"]
-    trace = {"modules": [["jit_step_counters_unique_routed_packed", 84e-6, 4]], "device_planes": 4}
+    trace = {"modules": [["jit_step_counters_unique_packed", 84e-6, 4]], "device_planes": 4}
     assert layers.read(spec, {"trace": trace}) == pytest.approx(21.0)
 
 
@@ -538,11 +601,12 @@ def v5e_2x2():
 
 
 @pytest.mark.parametrize("width, dtype", [(8, "uint8"), (32, "uint8"), (32, "uint16"), (4096, "")])
-def test_the_routed_step_compiles_for_four_real_chips_with_no_collective(v5e_2x2, width, dtype):
+def test_the_mesh_step_compiles_for_four_real_chips_with_exactly_one_all_reduce(v5e_2x2, width, dtype):
     """The cell's program at its real size — 2^20 slots striped over a
-    2x2 of v5e, 2^18 a chip — as the TPU's compiler takes it: one
-    program a chip under the routed step's own name, the counters
-    donated in place, and nothing that crosses chips."""
+    2x2 of v5e, 2^18 a chip, the launch's whole bucket on every chip —
+    as the TPU's compiler takes it: one program a chip under a
+    `step_counters` name, the counters donated in place, and ONE thing
+    that crosses chips: the all-reduce that makes the answer whole."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from ratelimit_tpu.parallel import ShardedFixedWindowModel
 
@@ -550,14 +614,20 @@ def test_the_routed_step_compiles_for_four_real_chips_with_no_collective(v5e_2x2
     model = ShardedFixedWindowModel(1 << 20, mesh)
     assert (model.num_banks, model.slots_per_bank) == (4, 1 << 18)
     with pytest.raises(Exception):  # builds the jitted step; there is nothing to run it on
-        model.step_counters_unique_routed_packed(None, dtype, None)
+        model.step_counters_unique_packed(None, dtype, None)
     counts = jax.ShapeDtypeStruct((4, 1 << 18), np.uint32, sharding=NamedSharding(mesh, P("banks", None)))
-    packed = jax.ShapeDtypeStruct((4, 4, width), np.int32, sharding=NamedSharding(mesh, P("banks", None, None)))
-    text = model._routed_packed_fns[dtype].lower(counts, packed).compile().as_text()
-    assert text.startswith("HloModule jit_step_counters_unique_routed_packed")
+    packed = jax.ShapeDtypeStruct((4, width), np.int32, sharding=NamedSharding(mesh, P()))
+    compiled = model._unique_packed_fns[dtype].lower(counts, packed).compile()
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_step_counters_unique_packed")
     assert "input_output_alias" in text.splitlines()[0]
-    for collective in ("all-reduce", "all-gather", "all-to-all", "collective-permute"):
-        assert collective not in text
+    collectives = re.findall(
+        r" = \S+ ((?:all-reduce|all-gather|all-to-all|collective-permute|reduce-scatter|collective-broadcast)[a-z-]*)\(",
+        text,
+    )
+    assert collectives == ["all-reduce"], collectives
+    (afters,) = [o for o in compiled.output_shardings if o.is_fully_replicated]
+    assert afters.spec == P()
 
 
 def _read_spans(trace_dir) -> dict:
@@ -578,10 +648,14 @@ def _read_spans(trace_dir) -> dict:
     return out
 
 
-def test_a_capture_holds_route_inside_pack_and_unroute_on_the_completer(tmp_path):
+def test_a_capture_holds_one_pack_and_no_route_or_unroute_span(tmp_path):
+    """A mesh bank's launch in a real capture is a one-chip bank's:
+    rl.launch ⊃ assign, pack, device_call on the collector; readback,
+    then decide, on the completer — and no span speaks of routing."""
     from ratelimit_tpu.backends.dispatcher import BatchDispatcher, Lane, WorkItem
 
-    assert {span_names.LAUNCH_ROUTE, span_names.COMPLETE_UNROUTE} <= set(span_names.SPAN_NAMES)
+    assert not [n for n in span_names.SPAN_NAMES if "route" in n]
+    assert not hasattr(span_names, "LAUNCH_ROUTE") and not hasattr(span_names, "COMPLETE_UNROUTE")
     engine = ShardedCounterEngine(make_mesh(4), num_slots=1 << 10, buckets=(8,))
     d = BatchDispatcher(engine, batch_window_us=100)
     got = []
@@ -608,15 +682,21 @@ def test_a_capture_holds_route_inside_pack_and_unroute_on_the_completer(tmp_path
         capture.join(60)
         d.stop()
     spans = _read_spans(str(tmp_path))
-    (pack,), (route,) = spans["rl.launch.pack"], spans["rl.launch.route"]
+    assert not [n for n in spans if "route" in n]
+    (pack,), (assign,) = spans["rl.launch.pack"], spans["rl.launch.assign"]
     (call,), (launch,) = spans["rl.launch.device_call"], spans["rl.launch"]
-    assert route[0] == pack[0] == launch[0] and pack[1] <= route[1] and route[2] <= pack[2]
+    assert assign[0] == pack[0] == call[0] == launch[0]
+    assert launch[1] <= assign[1] and assign[2] <= pack[1] and call[2] <= launch[2]
     assert pack[2] <= call[1]  # packing is over before the device call opens
-    (unroute,), (readback,), (decide,) = (
-        spans["rl.complete.unroute"], spans["rl.complete.readback"], spans["rl.complete.decide"]
-    )
-    assert unroute[0] == readback[0] != launch[0]
-    assert readback[2] <= unroute[1] and unroute[2] <= decide[1]
+    (readback,), (decide,) = spans["rl.complete.readback"], spans["rl.complete.decide"]
+    assert readback[0] == decide[0] != launch[0]
+    assert call[1] <= readback[2] <= decide[1]
+    # Nothing stands between the readback and the decide on the completer.
+    between = [
+        n for n, events in spans.items() for line, start, end in events
+        if line == readback[0] and readback[2] <= start and end <= decide[1]
+    ]
+    assert between == []
 
 
 # -- (f) the manifest, the cell's rehearsal, the new metrics -----------------
@@ -719,13 +799,14 @@ def test_the_cells_rehearsal_is_correct_and_its_controls_are_not(control, correc
     assert all(v == "0" for v in checks.values()) is correct
 
 
-def _obs(change: bool) -> dict:
-    """The two /stats.json fetches of a traced run: from this change on
-    a mesh bank, or from the parent / an unsharded bank (the bank's
-    older counters alone)."""
+def _obs(pr49: bool) -> dict:
+    """The two /stats.json fetches of a traced run: from PR 49's
+    program on a mesh bank (the parent side of this PR's check: its
+    routing counters beside the bank's older ones), or from a program
+    that keeps none — this tree, PR 49's parent, an unsharded bank."""
     def stats(n):
         flat = {BANK + "dedup_groups": 670 * n, BANK + "padded_lanes": 1280 * n}
-        if change:
+        if pr49:
             flat.update({
                 BANK + "routed_launches": 10 * n, BANK + "route_ns": 400_000 * n,
                 BANK + "unroute_ns": 150_000 * n, BANK + "routed_busiest_lanes": 880 * n,
@@ -735,15 +816,44 @@ def _obs(change: bool) -> dict:
     return {"stats_a": stats(1), "stats_b": stats(3)}
 
 
+@pytest.fixture(scope="module")
+def this_trees_mesh_bank():
+    """What a mesh bank of this tree really exports: two fetches, ten
+    launches of 67 groups between them."""
+    manager = Manager()
+    cache = TpuRateLimitCache(
+        ShardedCounterEngine(make_mesh(4), num_slots=1 << 10, buckets=(8, 128)), time_source=PinnedTimeSource(T0)
+    )
+    try:
+        cache.register_stats(manager.store)
+        fetches = [{"stats": dict(manager.store.snapshot()), "histograms": {}}]
+        batch = HostBatch(
+            slots=np.arange(67, dtype=np.int32), hits=np.ones(67, np.uint32), limits=np.full(67, 5, np.uint32),
+            fresh=np.zeros(67, bool), shadow=np.zeros(67, bool),
+        )
+        for _ in range(10):
+            cache.engine.step(batch)
+        fetches.append({"stats": dict(manager.store.snapshot()), "histograms": {}})
+    finally:
+        cache.close()
+    return {"stats_a": fetches[0], "stats_b": fetches[1]}
+
+
 @pytest.mark.parametrize(
-    "name, on_change",
+    "name, on_pr49",
     [("route_us.paced", 40.0), ("unroute_us.paced", 15.0), ("routed_balance_share.paced", 100 * 670 / 880)],
 )
-def test_new_metric_reads_the_change_and_raises_nothing_on_the_parent(name, on_change):
+def test_pr49s_routing_metric_reads_nothing_on_this_tree_and_raises_nothing(name, on_pr49, this_trees_mesh_bank):
+    """The three readers stay in the benchmark (a `benchmark` PR takes
+    them out: PERF.md section 7): they read the parent's counters in
+    this PR's check and find nothing to read here."""
     spec = load_json("layer_metrics", name)
     assert set(spec) == {"what", "reader"} and spec["reader"]["kind"] == "ratio"
-    assert layers.read(spec["reader"], _obs(change=True)) == pytest.approx(on_change)
-    assert layers.read(spec["reader"], _obs(change=False)) is None
+    assert layers.read(spec["reader"], _obs(pr49=True)) == pytest.approx(on_pr49)
+    assert layers.read(spec["reader"], _obs(pr49=False)) is None
+    assert layers.read(spec["reader"], this_trees_mesh_bank) is None
     assert layers.read(spec["reader"], {}) is None  # nothing gathered: nothing read, nothing raised
+    # lane_fill_share.paced still reads, on both: groups over the lanes shipped.
     fill = load_json("layer_metrics", "lane_fill_share.paced")["reader"]
-    assert layers.read(fill, _obs(change=True)) == pytest.approx(100 * 670 / 1280)
+    assert layers.read(fill, _obs(pr49=True)) == pytest.approx(100 * 670 / 1280)
+    assert layers.read(fill, this_trees_mesh_bank) == pytest.approx(100 * 67 / 128)

@@ -4,7 +4,7 @@ virtual CPU mesh — the reference's topology-matrix analog
 the 'cluster' is the bank-sharded engine over 8 virtual devices).
 
 Covers what tests/test_sharded.py (engine level) cannot: the Runner's
-backend_type="tpu-sharded" wiring, routed warmup through the cache,
+backend_type="tpu-sharded" wiring, the warm-up through the cache,
 the dispatcher over a sharded engine, and wire-exact decisions."""
 
 import grpc
